@@ -1022,4 +1022,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()   # command-line runs only, not in-process calls
     main()

@@ -1,131 +1,23 @@
-"""Version portability shims for jax APIs that moved between releases.
+"""Thin wrappers over the jax sharding calls the model and join code make.
 
-The repo targets the current jax (``jax.shard_map`` with ``check_vma`` /
-``axis_names``, ``jax.sharding.AxisType``) but must also run on the 0.4.x
-line this container ships, where shard_map lives in ``jax.experimental``
-with the (check_rep, auto) spelling. Everything here is a thin argument
-translation -- semantics are identical.
-
-Mesh construction portability lives in ``repro.launch.mesh.make_mesh_compat``
-(it is launch-flavored and must not import jax device state early).
+Call sites import ``shard_map`` / ``sharding_constraint`` from here so one
+module owns the spelling of these APIs.
 """
 from __future__ import annotations
-
-import contextlib
-import threading
 
 import jax
 from jax.sharding import PartitionSpec
 
-# Axis names that are MANUAL in the enclosing shard_map body because the
-# 0.4.x lowering below promoted a partial-manual map to fully manual. A
-# with_sharding_constraint naming such an axis is legal on new jax (the
-# axis is still GSPMD-auto there) but raises at lowering time on 0.4.x
-# ("Axis ... is also found in manual_axes"); constraint sites consult
-# ``sharding_constraint`` so those entries are dropped only where -- and
-# only on the jax line where -- they became manual.
-_MANUAL_AXES = threading.local()
-
-
-def manual_axes_in_effect() -> frozenset:
-    """Mesh axes the current trace context made manual via the 0.4.x
-    fully-manual lowering (empty on new jax and outside shard_map)."""
-    return getattr(_MANUAL_AXES, "axes", frozenset())
-
-
-@contextlib.contextmanager
-def _manual_axes_ctx(axes: frozenset):
-    prev = manual_axes_in_effect()
-    _MANUAL_AXES.axes = prev | axes
-    try:
-        yield
-    finally:
-        _MANUAL_AXES.axes = prev
-
-
-def strip_manual_axes(spec: PartitionSpec) -> PartitionSpec:
-    """Drop PartitionSpec entries that name currently-manual axes."""
-    manual = manual_axes_in_effect()
-    if not manual:
-        return spec
-
-    def one(entry):
-        if entry is None:
-            return None
-        if isinstance(entry, (tuple, list)):
-            kept = tuple(a for a in entry if a not in manual)
-            return kept if kept else None
-        return None if entry in manual else entry
-
-    return PartitionSpec(*(one(e) for e in spec))
-
 
 def sharding_constraint(x, spec: PartitionSpec):
-    """``with_sharding_constraint`` portable into 0.4.x fully-manual bodies.
-
-    Entries over axes the compat lowering made manual are stripped (the
-    data is already per-device there); if that leaves no named axes, the
-    constraint is skipped entirely rather than lowered as an empty
-    constraint inside a manual context. Outside such bodies the call
-    passes through UNCHANGED -- an all-None spec still lowers an explicit
-    replicate constraint, exactly as the raw jax call would.
-    """
-    if manual_axes_in_effect():
-        spec = strip_manual_axes(spec)
-        if all(e is None for e in spec):
-            return x
+    """``jax.lax.with_sharding_constraint`` of ``x`` to ``spec``."""
     return jax.lax.with_sharding_constraint(x, spec)
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-                  axis_names=None):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-                  axis_names=None):
-        # new-jax axis_names lists the MANUAL axes; old-jax `auto` lists the
-        # complement. check_vma maps to check_rep (default True, like both
-        # jax spellings).
-        #
-        # Partial-manual (axis_names a strict subset of the mesh axes) is
-        # NOT forwarded as a non-empty `auto` set here: the 0.4.x SPMD
-        # partitioner crashes on that composition (spmd_partitioner.cc
-        # "Check failed: target.IsManualSubgroup() == sharding()
-        # .IsManualSubgroup()" -- the partial-manual subgroup sharding of a
-        # shard_map operand meets a non-subgroup target sharding). Instead
-        # the map is lowered FULLY manual: the specs already mention only
-        # the manual axes, so the unmentioned axes simply replicate their
-        # block per device and every collective the body runs (psum/pmean/
-        # all_gather over its explicit axis names) is unchanged. Semantics
-        # are identical -- the auto axes lose compiler-chosen sharding
-        # inside the body (they compute their block redundantly), which is
-        # a performance trade on the 0.4.x line only; new jax keeps true
-        # partial-manual above. check_rep must be off in this mode: specs
-        # of a partial-manual caller make no replication claims about the
-        # now-manual axes.
-        partial_manual = (axis_names is not None
-                          and frozenset(mesh.axis_names)
-                          != frozenset(axis_names))
-        if partial_manual:
-            inner, all_axes = f, frozenset(mesh.axis_names)
-
-            def f(*args):
-                # announce the promoted axes so sharding_constraint can
-                # strip spec entries that would now name a manual axis
-                with _manual_axes_ctx(all_axes):
-                    return inner(*args)
-
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs,
-                          check_rep=check_vma and not partial_manual,
-                          auto=frozenset())
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
+              axis_names=None):
+    """``jax.shard_map``; ``axis_names`` lists the manual axes (all of the
+    mesh's when None)."""
+    kwargs = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)

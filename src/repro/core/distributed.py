@@ -57,7 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import grid as grid_lib
 from repro.core.grid import (build_grid_with_geometry,
                              build_grid_with_geometry_jit, device_key_dtype,
-                             host_grid_geometry, row_major_strides)
+                             host_grid_geometry, offset_deltas)
 from repro.core.selfjoin import _distance_hits_jnp, _gather_batch, _neighbor_ranks_for_delta
 from repro.core.stencil import stencil_offsets
 
@@ -292,7 +292,7 @@ def make_distributed_count_step(mesh: Mesh, cfg: DistJoinConfig):
         gid_sorted = cand_gids[index.order]
         cell_overflow = index.max_per_cell > C
 
-        deltas = offsets @ row_major_strides(dims)
+        deltas = offset_deltas(offsets, dims)
         n_cand = P_loc + n_halo
 
         def body(total, xs):
@@ -687,6 +687,9 @@ def distributed_self_join(
     dims_dev = jnp.asarray(dims)
     eps_dev = jnp.asarray(eps, pts.dtype)
 
+    # each slab builds and joins on ITS mesh device: the slab axis is the
+    # mesh's first, so row k of the device grid holds slab k
+    slab_devices = np.asarray(mesh.devices).reshape(n_slabs, -1)[:, 0]
     chunks = []
     total = 0
     for k in range(n_slabs):
@@ -696,22 +699,24 @@ def distributed_self_join(
             continue
         cc = cand_c[k].copy()
         cc[~v] = far
-        index = build_grid_with_geometry_jit(
-            jnp.asarray(cc), eps_dev, gmin_dev, dims_dev, jnp.asarray(v),
-            key_dtype=slab_kd)
-        order = np.asarray(index.order)
-        gid_sorted = cand_g[k][order]
-        owned_sorted = o[order]
-        if return_pairs:
-            chunks.append(_self_join_fused(
-                index, unicomp=unicomp, sort_result=False, method=method,
-                emit=emit, bucketed=bucketed, merged=merged,
-                row_ok=owned_sorted, ids=gid_sorted, gid_pairs=True))
-        else:
-            total += _self_join_count_fused(
-                index, unicomp=unicomp, method=method, bucketed=bucketed,
-                merged=merged, row_ok=owned_sorted, ids=gid_sorted,
-                gid_pairs=True).total_pairs
+        with jax.default_device(slab_devices[k]):
+            index = build_grid_with_geometry_jit(
+                jnp.asarray(cc), eps_dev, gmin_dev, dims_dev,
+                jnp.asarray(v), key_dtype=slab_kd)
+            order = np.asarray(index.order)
+            gid_sorted = cand_g[k][order]
+            owned_sorted = o[order]
+            if return_pairs:
+                chunks.append(_self_join_fused(
+                    index, unicomp=unicomp, sort_result=False,
+                    method=method, emit=emit, bucketed=bucketed,
+                    merged=merged, row_ok=owned_sorted, ids=gid_sorted,
+                    gid_pairs=True))
+            else:
+                total += _self_join_count_fused(
+                    index, unicomp=unicomp, method=method,
+                    bucketed=bucketed, merged=merged, row_ok=owned_sorted,
+                    ids=gid_sorted, gid_pairs=True).total_pairs
     if not return_pairs:
         return total
     out = np.concatenate(chunks, axis=0) if chunks else empty

@@ -212,6 +212,12 @@ def row_major_strides(dims: jax.Array) -> jax.Array:
     return jnp.concatenate([rev[-2::-1], jnp.ones((1,), dims.dtype)])
 
 
+def offset_deltas(offsets, dims: jax.Array) -> jax.Array:
+    """Key delta ``o @ s`` of each (n_off, n) stencil offset row, as an
+    elementwise multiply-sum: XLA's TPU backend has no int64 dot."""
+    return (jnp.asarray(offsets) * row_major_strides(dims)).sum(axis=-1)
+
+
 def grid_geometry(points: jax.Array, eps) -> tuple[jax.Array, jax.Array]:
     """grid_min (g_j^min) and dims (|g_j|) per paper SIV-B.
 

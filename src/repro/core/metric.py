@@ -116,33 +116,38 @@ def device_refine_scalar(metric: str, eps, dtype) -> jax.Array:
     return jnp.reshape(s, (1, 1))
 
 
-def tile_refine_hits(metric: str, qrow, window, scalar, *, n_real: int,
+def tile_refine_hits(metric: str, qrow, window_t, scalar, *, n_real: int,
                      n_feat: int):
     """Fused-kernel refine: one query row against its candidate window.
 
-    ``qrow`` is (1, L), ``window`` is (C, L) with L the padded lane count
-    (geometry lanes [0, n_real), feature lanes [n_real, n_real+n_feat)),
-    ``scalar`` the ``device_refine_scalar`` value. Returns a (C,) bool.
+    ``qrow`` is (1, L) and ``window_t`` the TRANSPOSED window (L, C), with
+    L the padded lane count (geometry lanes [0, n_real), feature lanes
+    [n_real, n_real+n_feat)) and candidates along lanes; ``scalar`` is the
+    (1, 1) ``device_refine_scalar`` value. Returns a (1, C) bool. Every
+    operand stays 2-D (Mosaic has no 1-D vector layout), and the
+    arithmetic is ``plane_refine_hits``' lane by lane, in the same order.
     """
     if metric == "jaccard":
         # Sizes from the geometry lane, NOT bitmap popcounts: a query
         # packed against a smaller index vocabulary keeps its TRUE size
         # (out-of-vocabulary tokens can never intersect indexed sets, so
         # the intersection is exact and the union needs the true size).
-        sq = qrow[0, 0]
-        sc = window[:, 0]
-        inter = jnp.zeros(window.shape[:1], jnp.int32)
+        sq = qrow[:, 0:1]
+        sc = window_t[0:1, :]
+        inter = jnp.zeros(sc.shape, jnp.int32)
         for k in range(n_feat):
-            qw = qrow[0, n_real + k].astype(jnp.int32)
-            cw = window[:, n_real + k].astype(jnp.int32)
+            lane = n_real + k
+            qw = qrow[:, lane:lane + 1].astype(jnp.int32)
+            cw = window_t[lane:lane + 1, :].astype(jnp.int32)
             inter = inter + jax.lax.population_count(qw & cw)
-        inter = inter.astype(window.dtype)
+        inter = inter.astype(window_t.dtype)
         union = sq + sc - inter
         return (union > 0) & (inter >= scalar * union)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, window.shape[1]), 1)
-    lane_w = (lane < n_real).astype(window.dtype)
-    d = (window - qrow) * lane_w
-    return l2_sq_hits_presquared(jnp.sum(d * d, axis=-1), scalar)
+    d2 = jnp.zeros((1, window_t.shape[1]), window_t.dtype)
+    for dim in range(n_real):
+        d = qrow[:, dim:dim + 1] - window_t[dim:dim + 1, :]
+        d2 = d2 + d * d
+    return l2_sq_hits_presquared(d2, scalar)
 
 
 def plane_refine_hits(metric: str, points_pad, q_batch, cand_pos, scalar, *,

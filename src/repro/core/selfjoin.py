@@ -91,10 +91,10 @@ class JoinStats:
 
 def _offset_tables(index: GridIndex, unicomp: bool):
     """Static offset list -> (deltas (n_off,), is_zero (n_off,)) device arrays."""
-    from repro.core.grid import row_major_strides
+    from repro.core.grid import offset_deltas
 
     offs = stencil_offsets(index.n_dims, unicomp)          # (n_off, n) np
-    deltas = jnp.asarray(offs) @ row_major_strides(index.dims)  # (n_off,)
+    deltas = offset_deltas(offs, index.dims)               # (n_off,)
     is_zero = jnp.asarray(np.all(offs == 0, axis=1))
     return deltas, is_zero
 
@@ -108,11 +108,11 @@ def _merged_offset_tables(index: GridIndex, unicomp: bool):
     offset spans [0, +1]). Packed as one array so the jitted descriptor
     preps keep a single traced-operand signature for both sweep modes.
     """
-    from repro.core.grid import row_major_strides
+    from repro.core.grid import offset_deltas
     from repro.core.stencil import merged_stencil_offsets
 
     reduced, lo, hi = merged_stencil_offsets(index.n_dims, unicomp)
-    deltas = jnp.asarray(reduced) @ row_major_strides(index.dims)
+    deltas = offset_deltas(reduced, index.dims)
     dtab = jnp.stack([deltas, jnp.asarray(lo), jnp.asarray(hi)])
     is_zero = jnp.asarray(np.all(reduced == 0, axis=1))
     return dtab, is_zero
@@ -876,6 +876,7 @@ def _self_join_count_fused(index: GridIndex, *, unicomp: bool,
     traffic avoided vs one window per row (``dma_bytes_saved``).
     """
     from repro.core.grid import global_window_cap
+    from repro.kernels.fused_join import LANES
     from repro.kernels.ops import _kernel_dtype
 
     if merged:
@@ -904,7 +905,7 @@ def _self_join_count_fused(index: GridIndex, *, unicomp: bool,
             row_ok=row_ok, gid=gid, feats=feats)
     total = cells = cands = 0
     dma_windows = dma_saved = 0
-    np_pad = int(points_pad.shape[1])
+    np_pad = LANES                 # each window DMA moves full rows
     dtype_bytes = np.dtype(_kernel_dtype(points_pad.dtype)).itemsize
     for sel, q_start, q_size, qp, cap, tile in launches:
         plan = (_launch_run_plan(index, sel, q_start, qp=qp, tile=tile)
@@ -955,6 +956,7 @@ def dma_window_stats(index: GridIndex, *, unicomp: bool = True,
     the reduction should track. The bench writes this into
     BENCH_selfjoin.json's "dma" section and the CI smoke gates on it.
     """
+    from repro.kernels.fused_join import LANES
     from repro.kernels.ops import _kernel_dtype
 
     if merged:
@@ -965,7 +967,7 @@ def dma_window_stats(index: GridIndex, *, unicomp: bool = True,
         n_off = int(deltas.shape[0])
     launches, points_pad, _ = _fused_launches(
         index, n_batches=1, bucketed=bucketed, merged=merged)
-    np_pad = int(points_pad.shape[1])
+    np_pad = LANES                 # each window DMA moves full rows
     dtype_bytes = np.dtype(_kernel_dtype(points_pad.dtype)).itemsize
     rows = runs = saved = 0
     hist: dict = {}

@@ -5,9 +5,9 @@ materializes a ``(B, C, n)`` gathered-candidate tensor in HBM per stencil
 offset and then evaluates distances over it -- the dominant cost is HBM
 traffic the paper's shared-memory refine never pays. This kernel removes the
 intermediate: the *positions* of each query's candidate window (``win_start``
-/ ``win_count`` from ``core.grid.window_descriptors``) arrive via scalar
-prefetch (``pltpu.PrefetchScalarGridSpec``), and the kernel performs the
-HBM->VMEM candidate gather itself with a dynamic slice of ``points_sorted``,
+/ ``win_count`` from ``core.grid.window_descriptors``) arrive in SMEM, one
+query tile's worth per grid step, and the kernel performs the HBM->VMEM
+candidate gather itself with a dynamic slice of ``points_sorted``,
 immediately consuming the window for the distance + epsilon threshold. The
 candidate coordinates live only in VMEM.
 
@@ -23,13 +23,14 @@ unfused path could not deliver.
 Per grid step the kernel fuses, per query row:
 
     gather window -> squared distance -> eps threshold -> UNICOMP/self mask
-    -> per-query hit count (accumulated across offsets)
 
-and on the final offset computes the per-tile exclusive scan of the hit
-counts (``slot_base``) -- the slot assignment the fill phase uses, so count
-and fill share ONE distance evaluation per candidate: the driver
-(core/selfjoin.py) sizes the result buffer from ``counts`` and scatters pairs
-from the returned ``hits`` mask without ever recomputing a distance.
+writes the tile's hit rows as one block and adds their per-row totals to
+``counts`` (accumulated across offsets). The per-tile exclusive scan of the
+counts (``slot_base``) -- the slot assignment the fill phase uses -- is one
+XLA op after the launch, shared with the reference lowering. Count and fill
+share ONE distance evaluation per candidate: the driver (core/selfjoin.py)
+sizes the result buffer from ``counts`` and scatters pairs from the
+returned ``hits`` mask without ever recomputing a distance.
 
 Outputs (for a query batch of Q_pad rows, C-slot windows, n_off offsets):
 
@@ -46,7 +47,7 @@ a ``(B, C, n)`` candidate tensor either, and UNICOMP/merged/gid masking is
 the shared ``_mask_hits``. The Pallas kernel is validated against it in
 tests/test_fused_join.py.
 
-Cell-run DMA dedup (DESIGN.md S11, ``run_loop=True``): a scalar-prefetched
+Cell-run DMA dedup (DESIGN.md S11, ``run_loop=True``): an SMEM
 run-ordinal array (``grid.cell_run_plan``) groups each tile's rows into RUNS
 sharing a grid cell; since same-cell rows have identical windows for every
 offset, the window DMA advances once per run (slot = ordinal mod 2, still
@@ -72,8 +73,18 @@ Hardware adaptation notes (honest limits of this port):
     form, DOUBLE-BUFFERED across rows: two VMEM window slots, the copy for
     row r+1 issued before row r's compute (pallas_guide.md "Patterns:
     Double Buffering"). Off-TPU the copies run through the interpreter.
-  * scalar-prefetch arrays are (n_off, Q_pad) int32; at serving scale these
-    are sharded with the query batch (launch/mesh.py 'slab' axis).
+  * Mosaic slices rows out of an (8, 128)-tiled HBM array only at full
+    lane width, so the kernel's copy of the points is 128 lanes wide and
+    each window DMA moves C x 128 words, of which only the padded lane
+    count is read. The window is transposed in VMEM so that candidates run
+    along lanes: every vector in the row body is 2-D (Mosaic has no 1-D
+    layout), and the hit row is stored as 32-bit (int8 rows pack four to a
+    sublane) and converted once per tile.
+  * the per-tile descriptors (window start/count, query positions, run
+    ordinals) arrive as (1, tq) SMEM blocks per grid step, so SMEM holds a
+    tile's worth whatever the launch size.
+  * Mosaic lowers no 64-bit scalar, so the kernel is traced with x64 off;
+    its operands are 32-bit on the chip (f64 points are computed in f32).
 """
 from __future__ import annotations
 
@@ -87,7 +98,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import metric as metric_lib
 
 NP_PAD = 8     # minimum lane padding of the coordinate axis (cell_join.py)
+LANES = 128    # TPU vreg lane width: the kernel's HBM points row width
 TQ_DEFAULT = 128  # query tile rows
+
+
+def on_tpu() -> bool:
+    """True when the kernels compile to Mosaic; elsewhere they run in the
+    Pallas interpreter. Asked per call, never at import."""
+    return jax.default_backend() == "tpu"
 
 
 def pad_width(n_lanes: int) -> int:
@@ -196,15 +214,18 @@ def _mask_hits(hit, cand_pos, q_pos, zero, unicomp: bool,
     """
     if external:
         return hit
+    # ``zero`` is a scalar; it is broadcast as an int and the masks are
+    # combined with logical ops (Mosaic selects no boolean vectors)
+    off_zero = jnp.broadcast_to(zero, hit.shape) == 0
     if gq is not None:
         if unicomp:
             tri = gc > gq
             if ldiff is not None:
                 tri = (ldiff > 0) | ((ldiff == 0) & tri)
-            return hit & jnp.where(zero != 0, tri, True)
+            return hit & (off_zero | tri)
         return hit & (gc != gq)
     if unicomp:
-        return hit & jnp.where(zero != 0, cand_pos > q_pos, True)
+        return hit & (off_zero | (cand_pos > q_pos))
     return hit & (cand_pos != q_pos)
 
 
@@ -212,14 +233,16 @@ def _mask_hits(hit, cand_pos, q_pos, zero, unicomp: bool,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _fused_kernel(ws_ref, wc_ref, iz_ref, qpos_ref, ord_ref, scal_ref, q_ref,
-                  pts_ref, hits_ref, counts_ref, base_ref, win_ref, sem_ref,
+def _fused_kernel(iz_ref, ws_ref, wc_ref, qpos_ref, ord_ref, scal_ref, q_ref,
+                  pts_ref, hits_ref, counts_ref, win_ref, sem_ref, hit_ref,
                   *, c, tq, n_real, unicomp, external, merged, gid_pairs,
                   run_loop, metric, n_feat):
-    i = pl.program_id(0)           # query tile
+    # Index arithmetic is int32 throughout, whatever jax_enable_x64 says:
+    # Mosaic lowers no int64 scalar math. The per-tile descriptor blocks
+    # (ws/wc/qpos/ord) arrive in SMEM, one (1, tq) block per grid step.
     j = pl.program_id(1)           # stencil offset (innermost: q tile resident)
-    n_off = pl.num_programs(1)
-    scal = scal_ref[0, 0]          # metric refine scalar (core.metric)
+    scal = scal_ref[...]           # (1, 1) metric refine scalar (core.metric)
+    np_pad = q_ref.shape[1]        # occupied lanes of the 128-lane windows
     zero = iz_ref[j]
 
     @pl.when(j == 0)
@@ -233,12 +256,14 @@ def _fused_kernel(ws_ref, wc_ref, iz_ref, qpos_ref, ord_ref, scal_ref, q_ref,
     # 3 cells long, which is what makes the overlap worth having.
     def win_dma(r, slot):
         return pltpu.make_async_copy(
-            pts_ref.at[pl.ds(ws_ref[j, i * tq + r], c), :],
+            pts_ref.at[pl.ds(ws_ref[0, r], c), :],
             win_ref.at[slot], sem_ref.at[slot])
 
     win_dma(0, 0).start()
+    slots = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    two = jnp.int32(2)
 
-    def row(r, _):
+    def row(r, carry):
         if run_loop:
             # Cell-run DMA (DESIGN.md S11): rows with equal run ordinals
             # share their window for every offset (grid.cell_run_plan
@@ -246,11 +271,10 @@ def _fused_kernel(ws_ref, wc_ref, iz_ref, qpos_ref, ord_ref, scal_ref, q_ref,
             # mod 2 alternates run to run; the run's LAST row issues the
             # next run's copy (overlapping the remaining compute), the
             # HEAD row waits, interior rows reuse the resident slot.
-            o = ord_ref[i * tq + r]
-            two = jnp.asarray(2, o.dtype)
+            o = ord_ref[0, r]
             slot = jax.lax.rem(o, two)
-            nxt = ord_ref[i * tq + jnp.minimum(r + 1, tq - 1)]
-            prev = ord_ref[i * tq + jnp.maximum(r - 1, 0)]
+            nxt = ord_ref[0, jnp.minimum(r + 1, tq - 1)]
+            prev = ord_ref[0, jnp.maximum(r - 1, 0)]
 
             @pl.when((r + 1 < tq) & (nxt != o))
             def _prefetch():
@@ -260,26 +284,24 @@ def _fused_kernel(ws_ref, wc_ref, iz_ref, qpos_ref, ord_ref, scal_ref, q_ref,
             def _wait():
                 win_dma(r, slot).wait()
         else:
-            slot = jax.lax.rem(r, 2)
+            slot = jax.lax.rem(r, two)
 
             @pl.when(r + 1 < tq)
             def _prefetch():
-                win_dma(r + 1, jax.lax.rem(r + 1, 2)).start()
+                win_dma(r + 1, jax.lax.rem(r + 1, two)).start()
 
             win_dma(r, slot).wait()
-        qg = i * tq + r                       # row in the query batch
-        q_pos = qpos_ref[qg]                  # global sorted position
-        start = ws_ref[j, qg]
-        cnt = wc_ref[j, qg]
-        window = win_ref[slot]                            # (C, NP)
+        q_pos = qpos_ref[0, r]                # global sorted position
+        start = ws_ref[0, r]
+        cnt = wc_ref[0, r]
+        window_t = win_ref[slot, :, :np_pad].T            # (NP, C)
         qrow = q_ref[pl.ds(r, 1), :]                      # (1, NP)
         # metric refine (core.metric, DESIGN.md S12): the predicate skips
         # pad lanes by the static (n_real, n_feat) layout -- with the
         # merged sweep, lane n_real + n_feat carries the last-dimension
         # cell coordinate, not a zero
-        hit = metric_lib.tile_refine_hits(metric, qrow, window, scal,
+        hit = metric_lib.tile_refine_hits(metric, qrow, window_t, scal,
                                           n_real=n_real, n_feat=n_feat)
-        slots = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)[:, 0]
         cand_pos = start + slots
         hit = hit & (slots < cnt)
         ldiff = None
@@ -290,27 +312,32 @@ def _fused_kernel(ws_ref, wc_ref, iz_ref, qpos_ref, ord_ref, scal_ref, q_ref,
             # coordinate+feature lanes as exact integers, so the float
             # compare is exact
             ml = n_real + n_feat
-            ldiff = window[:, ml] - qrow[0, ml]
+            ldiff = window_t[ml:ml + 1, :] - qrow[:, ml:ml + 1]
             hit = hit & (jnp.abs(ldiff) <= 1)
         gq = gc = None
         if gid_pairs:
             # global ids ride the lane after the coordinates/features (and
             # after the merged coordinate lane); exact small ints in float
             gl = n_real + n_feat + (1 if merged else 0)
-            gq, gc = qrow[0, gl], window[:, gl]
+            gq, gc = qrow[:, gl:gl + 1], window_t[gl:gl + 1, :]
         hit = _mask_hits(hit, cand_pos, q_pos, zero, unicomp, external,
                          gq, gc, ldiff if gid_pairs else None)
-        hits_ref[0, r, :] = hit.astype(jnp.int8)
-        counts_ref[r, 0] = counts_ref[r, 0] + jnp.sum(hit).astype(jnp.int32)
-        return 0
+        hit_ref[pl.ds(r, 1), :] = hit.astype(jnp.int32)
+        return carry
 
-    jax.lax.fori_loop(0, tq, row, 0)
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(tq), row, jnp.int32(0))
+    # one whole-block store per grid step: int8 rows are packed four to a
+    # sublane, so the row loop writes 32-bit rows and converts here
+    tile_hits = hit_ref[...]
+    hits_ref[0] = tile_hits.astype(hits_ref.dtype)
+    counts_ref[...] += jnp.sum(tile_hits, axis=1, keepdims=True,
+                               dtype=jnp.int32)
 
-    @pl.when(j == n_off - 1)
-    def _scan():
-        # In-kernel exclusive scan: per-tile fill slot assignment.
-        ctile = counts_ref[...]
-        base_ref[...] = jnp.cumsum(ctile, axis=0) - ctile
+
+def _tile_exclusive_scan(counts, tq: int):
+    """Per-tile exclusive scan of per-row counts: the fill's slot bases."""
+    ctile = counts.reshape(-1, tq)
+    return (jnp.cumsum(ctile, axis=1) - ctile).reshape(-1)
 
 
 @functools.partial(
@@ -321,48 +348,71 @@ def _fused_join_hits_pallas(points_pad, q_batch, win_start, win_count,
                             is_zero, q_pos, run_ord, scal, *, c, tq, n_real,
                             unicomp, external=False, merged=False,
                             gid_pairs=False, keep_hits=True, run_loop=False,
-                            interpret=True, metric="l2", n_feat=0):
+                            interpret=False, metric="l2", n_feat=0):
     n_off, qp = win_start.shape
     np_pad = points_pad.shape[1]   # pad_width: NP_PAD unless feats widen it
     if keep_hits:
-        hits_shape, hits_map = (n_off, qp, c), (lambda i, j, *_: (j, i, 0))
+        hits_shape, hits_map = (n_off, qp, c), (lambda i, j: (j, i, 0))
     else:
         # count-only launch: one revisited (1, tq, c) block per tile serves
         # as scratch, so no O(n_off * Q * C) buffer is ever allocated.
-        hits_shape, hits_map = (1, qp, c), (lambda i, j, *_: (0, i, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(qp // tq, n_off),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, *_: (0, 0)),
-            pl.BlockSpec((tq, np_pad), lambda i, j, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tq, c), hits_map),
-            pl.BlockSpec((tq, 1), lambda i, j, *_: (i, 0)),
-            pl.BlockSpec((tq, 1), lambda i, j, *_: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, c, np_pad), points_pad.dtype),  # double-buffered
-            pltpu.SemaphoreType.DMA((2,)),                 # window DMA slots
-        ],
-    )
-    hits, counts, base = pl.pallas_call(
+        hits_shape, hits_map = (1, qp, c), (lambda i, j: (0, i, 0))
+    smem = pltpu.SMEM
+    # per-tile descriptor rows, one (1, tq) SMEM block per grid step: the
+    # tile axis is split out so the block's last two dims are whole dims
+    tile_row = pl.BlockSpec((None, 1, tq), lambda i, j: (i, 0, 0),
+                            memory_space=smem)
+    off_row = pl.BlockSpec((None, None, 1, tq), lambda i, j: (j, i, 0, 0),
+                           memory_space=smem)
+
+    def rows(a):
+        a = jnp.asarray(a, jnp.int32)
+        return a.reshape(a.shape[:-1] + (qp // tq, 1, tq))
+
+    operands = (jnp.asarray(is_zero, jnp.int32), rows(win_start),
+                rows(win_count), rows(q_pos), rows(run_ord), scal, q_batch,
+                # the window DMA slices rows out of a (8, 128)-tiled HBM
+                # array, which Mosaic only allows at full 128-lane width
+                jnp.pad(points_pad, ((0, 0), (0, LANES - np_pad))))
+    call = pl.pallas_call(
         functools.partial(_fused_kernel, c=c, tq=tq, n_real=n_real,
                           unicomp=unicomp, external=external, merged=merged,
                           gid_pairs=gid_pairs, run_loop=run_loop,
                           metric=metric, n_feat=n_feat),
-        grid_spec=grid_spec,
+        grid=(qp // tq, n_off),
+        in_specs=[
+            pl.BlockSpec(memory_space=smem),               # is_zero
+            off_row, off_row,                              # win start/count
+            tile_row, tile_row,                            # q_pos, run_ord
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),     # refine scalar
+            pl.BlockSpec((tq, np_pad), lambda i, j: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),             # points (HBM)
+        ],
+        out_specs=[
+            pl.BlockSpec((1, tq, c), hits_map),
+            pl.BlockSpec((tq, 1), lambda i, j: (i, 0)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct(hits_shape, jnp.int8),
             jax.ShapeDtypeStruct((qp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((qp, 1), jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, c, LANES), points_pad.dtype),   # double-buffered
+            pltpu.SemaphoreType.DMA((2,)),                 # window DMA slots
+            pltpu.VMEM((tq, c), jnp.int32),                # tile hit rows
         ],
         interpret=interpret,
-    )(win_start, win_count, is_zero, q_pos, run_ord, scal, q_batch,
-      points_pad)
-    return hits, counts[:, 0], base[:, 0]
+    )
+    if interpret:
+        hits, counts = call(*operands)
+    else:
+        # Mosaic lowers no 64-bit scalar: trace the kernel, its index maps
+        # and its ref slices with 32-bit literals, whatever x64 says (the
+        # grid keys stay int64 outside; every operand here is 32-bit)
+        with jax.enable_x64(False):
+            hits, counts = call(*operands)
+    counts = counts[:, 0]
+    return hits, counts, _tile_exclusive_scan(counts, tq)
 
 
 # ---------------------------------------------------------------------------
@@ -435,9 +485,7 @@ def _fused_join_hits_reference(points_pad, q_batch, win_start, win_count,
         per_offset, counts0, (win_start, win_count, is_zero))
     if not keep_hits:
         hits = jnp.zeros((1, qp, c), jnp.int8)
-    ctile = counts.reshape(-1, tq)
-    base = (jnp.cumsum(ctile, axis=1) - ctile).reshape(-1)
-    return hits, counts, base
+    return hits, counts, _tile_exclusive_scan(counts, tq)
 
 
 # ---------------------------------------------------------------------------
@@ -448,7 +496,7 @@ def fused_join_hits(points_pad, q_batch, win_start, win_count, is_zero,
                     q_pos, eps, *, c, n_real, unicomp, external=False,
                     merged=False, gid_pairs=False, tq=TQ_DEFAULT,
                     keep_hits=True, run_ord=None, run_loop=False,
-                    method=None, interpret=True, metric="l2", n_feat=0):
+                    method=None, metric="l2", n_feat=0):
     """Fused gather-refine sweep over all stencil offsets in one launch.
 
     Args:
@@ -468,7 +516,7 @@ def fused_join_hits(points_pad, q_batch, win_start, win_count, is_zero,
                   out-of-grid probes.
       is_zero:    (n_off,) int32, 1 for the o = 0 offset (UNICOMP triangle).
       q_pos:      (Q_pad,) int32 global sorted position of every query row,
-                  prefetched as a scalar array (self-join masking only;
+                  read from SMEM by the kernel (self-join masking only;
                   pass zeros with ``external``). Padding rows may carry any
                   in-range value -- their windows are count-0.
       eps:        scalar refine threshold in the metric's UNsquared form:
@@ -496,14 +544,16 @@ def fused_join_hits(points_pad, q_batch, win_start, win_count, is_zero,
       keep_hits:  static; False = count-only (no O(n_off*Q*C) hits buffer).
       run_ord:    (Q_pad,) int32 per-tile run ordinals from
                   ``grid.cell_run_plan(...).run_ord`` -- required when
-                  ``run_loop`` is True, otherwise optional (prefetched but
+                  ``run_loop`` is True, otherwise optional (read but
                   unused; pass zeros to keep launch shapes identical).
       run_loop:   static; True = cell-run DMA dedup (module docstring): the
                   kernel gathers one window per RUN of equal ordinals. The
                   caller owns the contract that equal ordinals imply equal
                   (win_start, win_count) columns for all offsets
                   (``analysis.contracts.check_run_plan``).
-      method:     'kernel' | 'reference' | None (auto: kernel on TPU).
+      method:     'kernel' | 'reference' | None (auto: kernel on TPU). The
+                  kernel compiles to Mosaic on a TPU backend and runs in
+                  the Pallas interpreter elsewhere, decided per call.
       metric:     static metric tag ('l2' | 'cosine' | 'jaccard'): selects
                   the refine predicate (core.metric) and keys a SEPARATE
                   executable per metric -- no traced branch.
@@ -514,8 +564,9 @@ def fused_join_hits(points_pad, q_batch, win_start, win_count, is_zero,
     Returns (hits, counts, slot_base); hits is (1, Q_pad, c) scratch when
     ``keep_hits`` is False.
     """
+    tpu = on_tpu()
     if method is None:
-        method = "kernel" if jax.default_backend() == "tpu" else "reference"
+        method = "kernel" if tpu else "reference"
     metric_lib.check_metric(metric)
     q_pos = jnp.asarray(q_pos, jnp.int32)
     if run_ord is None:
@@ -530,7 +581,7 @@ def fused_join_hits(points_pad, q_batch, win_start, win_count, is_zero,
             points_pad, q_batch, win_start, win_count, is_zero, q_pos,
             run_ord, scal, c=c, tq=tq, n_real=n_real, unicomp=unicomp,
             external=external, merged=merged, gid_pairs=gid_pairs,
-            keep_hits=keep_hits, run_loop=run_loop, interpret=interpret,
+            keep_hits=keep_hits, run_loop=run_loop, interpret=not tpu,
             metric=metric, n_feat=n_feat)
     if method == "reference":
         return _fused_join_hits_reference(

@@ -1,11 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel body
-runs as traced jnp ops); on a TPU backend they compile to Mosaic. The
-``interpret`` decision is made once at import from the default backend, and
-f64 inputs (the paper's precision, unsupported by the MXU) are computed in
-f32 on TPU -- documented hardware adaptation, validated in tests against the
-f64 oracle with f32 tolerances.
+Off the TPU the kernels execute in interpret mode (the kernel body runs as
+traced jnp ops); on a TPU backend they compile to Mosaic. The decision is
+made at CALL time from the default backend, so importing the library
+neither touches a backend nor fixes the mode. f64 inputs (the paper's
+precision, unsupported by the TPU's vector units) are computed in f32 on
+TPU -- documented hardware adaptation, validated against the f64 oracle
+with f32 tolerances.
 """
 from __future__ import annotations
 
@@ -16,12 +17,11 @@ from repro.analysis import sanitize as _sanitize
 from repro.kernels import cell_join as _cell_join
 from repro.kernels import distance_tile as _distance_tile
 from repro.kernels import fused_join as _fused_join
-
-_INTERPRET = jax.default_backend() != "tpu"
+from repro.kernels.fused_join import on_tpu
 
 
 def _kernel_dtype(dtype):
-    if not _INTERPRET and dtype == jnp.float64:
+    if on_tpu() and dtype == jnp.float64:
         return jnp.float32  # TPU has no f64; paper precision kept on CPU path
     return dtype
 
@@ -30,7 +30,7 @@ def distance_tile_hits(q, pts, eps):
     """Brute-force tile: (TQ,n) x (N,n) -> (TQ,N) bool epsilon-hits."""
     dt = _kernel_dtype(q.dtype)
     return _distance_tile.distance_tile_hits(
-        q.astype(dt), pts.astype(dt), eps, interpret=_INTERPRET
+        q.astype(dt), pts.astype(dt), eps, interpret=not on_tpu()
     )
 
 
@@ -38,7 +38,7 @@ def distance_tile_counts(pts, eps, *, tq: int = 256, tc: int = 256):
     """Fused brute-force per-point neighbor counts (excl. self)."""
     dt = _kernel_dtype(pts.dtype)
     return _distance_tile.distance_tile_counts(
-        pts.astype(dt), eps, tq=tq, tc=tc, interpret=_INTERPRET
+        pts.astype(dt), eps, tq=tq, tc=tc, interpret=not on_tpu()
     )
 
 
@@ -46,7 +46,7 @@ def cell_join_hits(q, cand, valid, eps):
     """Grid-cell refine: (B,n) x (B,C,n) x (B,C) -> (B,C) bool."""
     dt = _kernel_dtype(q.dtype)
     return _cell_join.cell_join_hits(
-        q.astype(dt), cand.astype(dt), valid, eps, interpret=_INTERPRET
+        q.astype(dt), cand.astype(dt), valid, eps, interpret=not on_tpu()
     )
 
 
@@ -81,7 +81,7 @@ def fused_join_hits(points_pad, q_batch, win_start, win_count, is_zero,
         is_zero, q_pos, eps, c=c, n_real=n_real, unicomp=unicomp,
         external=external, merged=merged, gid_pairs=gid_pairs, tq=tq,
         keep_hits=keep_hits, run_ord=run_ord, run_loop=run_loop,
-        method=method, interpret=_INTERPRET, metric=metric, n_feat=n_feat,
+        method=method, metric=metric, n_feat=n_feat,
     )
     if _sanitize.enabled():
         hits, counts, base = out

@@ -152,7 +152,7 @@ def lower_lm_cell(arch: str, cell: ShapeCell, mesh, cfg=None):
 # ---------------------------------------------------------------------------
 
 def _cost_dict(cost) -> dict:
-    """Normalize cost_analysis(): dict on current jax, [dict] on 0.4.x."""
+    """Normalize cost_analysis() to a dict (it may be a one-element list)."""
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}
     return dict(cost) if cost else {}

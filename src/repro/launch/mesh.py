@@ -15,23 +15,17 @@ Self-join:    the paper's workload wants a 1-D spatial slab axis x an
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types (Auto/Explicit sharding modes)
-    from jax.sharding import AxisType
-except ImportError:  # older jax (e.g. 0.4.x): every axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_compat(shape, axes):
-    """Version-portable mesh constructor (tests and subprocess drivers use
-    this instead of touching jax.sharding.AxisType directly)."""
+    """Mesh of Auto axes over the first prod(shape) devices (tests and
+    subprocess drivers build their meshes through this)."""
     return _mk(shape, axes)
 
 

@@ -46,27 +46,29 @@ def fused_join_vmem_bytes(*, c: int, tq: int, np_pad: int = 8,
 
     Mirrors the block/scratch shapes of ``kernels.fused_join
     ._fused_join_hits_pallas``: the pipelined blocks -- query tile
-    (tq, np_pad), hits (1, tq, c) int8, counts + slot_base (tq, 1) int32,
-    the eps scalar -- are counted TWICE (Pallas double-buffers revolving
-    in/out blocks across grid steps), plus the explicitly double-buffered
-    (2, c, np_pad) window scratch. Scalar-prefetch descriptors live in
-    SMEM and are excluded. The contract prover (analysis/contracts.py C6)
-    checks every (class, tile) the occupancy plan can launch against
-    ``VMEM_BYTES``.
+    (tq, np_pad), hits (1, tq, c) int8, counts (tq, 1) int32, the eps
+    scalar -- are counted TWICE (Pallas double-buffers revolving in/out
+    blocks across grid steps), plus the scratch: the explicitly
+    double-buffered (2, c, 128) window (the kernel's DMA moves full
+    128-lane rows) and the (tq, c) int32 hit rows. The (1, tq) SMEM
+    descriptor blocks are excluded. The contract prover
+    (analysis/contracts.py C6) checks every (class, tile) the occupancy
+    plan can launch against ``VMEM_BYTES``.
 
     ``run_loop`` (the cell-run DMA dedup, DESIGN.md S11) does NOT change
-    the footprint: the run plan's ``run_ord`` descriptor rides the
-    scalar-prefetch path (SMEM) like win_start/win_count, and the kernel
-    keeps the same two (c, np_pad) window slots -- only the start/wait
-    SCHEDULE changes (per run instead of per row). The parameter exists
-    so provers state the mode they checked.
+    the footprint: the run plan's ``run_ord`` descriptor rides in SMEM
+    like win_start/win_count, and the kernel keeps the same two window
+    slots -- only the start/wait SCHEDULE changes (per run instead of per
+    row). The parameter exists so provers state the mode they checked.
     """
+    from repro.kernels.fused_join import LANES
+
     del run_loop   # same slots, same blocks; see docstring
     blocks = (tq * np_pad * dtype_bytes   # query tile
               + tq * c                    # int8 hits block
-              + 2 * tq * 4                # counts + slot_base
+              + tq * 4                    # counts
               + dtype_bytes)              # eps2
-    scratch = 2 * c * np_pad * dtype_bytes
+    scratch = 2 * c * LANES * dtype_bytes + tq * c * 4
     return 2 * blocks + scratch
 
 _DTYPE_BYTES = {

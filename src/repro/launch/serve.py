@@ -349,6 +349,10 @@ class ShardedJoinService(_JoinServiceBase):
         self.slab_gids: list[np.ndarray] = []
         self.prepared: list = []
         self.indexes: list = []
+        # slab k lives on local device k (round-robin when there are fewer
+        # devices than slabs): its index, warmup and joins all run there
+        self.devices: list = []
+        devs = jax.local_devices()
         for k in range(n_slabs):
             own = gids[k] >= 0
             if not own.any():
@@ -362,12 +366,15 @@ class ShardedJoinService(_JoinServiceBase):
                     qc.metric, coords[k][own],
                     None if qc.feats is None else qc.feats[sg],
                     qc.n_feat, qc.eps, qc.eps_geom, qc.vocab)
-            idx = build_grid_host(coords[k][own],
-                                  float(self._query_canon.eps_geom
-                                        if canon_k else eps))
+            dev = devs[k % len(devs)]
+            with jax.default_device(dev):
+                idx = build_grid_host(coords[k][own],
+                                      float(self._query_canon.eps_geom
+                                            if canon_k else eps))
+                self.prepared.append(prepare(
+                    idx, merge_last_dim=merge_last_dim, canon=canon_k))
             self.indexes.append(idx)
-            self.prepared.append(prepare(idx, merge_last_dim=merge_last_dim,
-                                         canon=canon_k))
+            self.devices.append(dev)
         self.build_s = time.perf_counter() - t0
 
     def warmup(self, batch_size: int) -> int:
@@ -375,11 +382,16 @@ class ShardedJoinService(_JoinServiceBase):
 
         qp = bucket_rows(batch_size)
         if qp not in self._warm_buckets:
-            for pj in self.prepared:
-                pj.warm(batch_size, return_pairs=self.return_pairs)
+            for pj, dev in zip(self.prepared, self.devices):
+                with jax.default_device(dev):
+                    pj.warm(batch_size, return_pairs=self.return_pairs)
             self._warm_buckets.add(qp)
         self._auto_steady()
         return qp
+
+    def _join_async(self, k: int, queries, **kw):
+        with jax.default_device(self.devices[k]):
+            return self.prepared[k].join_async(queries, **kw)
 
     def _answer(self, queries: np.ndarray, eps: Optional[float] = None):
         # canonicalize raw metric queries ONCE (the pre-canonicalized
@@ -391,10 +403,10 @@ class ShardedJoinService(_JoinServiceBase):
         # dispatch EVERY slab before resolving ANY: the k-th slab's fused
         # sweep executes on device while the (k+1)-th is still being set
         # up on the host (join_async seam, DESIGN.md S8)
-        pendings = [pj.join_async(queries, eps=eps,
-                                  return_pairs=self.return_pairs,
-                                  sort_pairs=False)
-                    for pj in self.prepared]
+        pendings = [self._join_async(k, queries, eps=eps,
+                                     return_pairs=self.return_pairs,
+                                     sort_pairs=False)
+                    for k in range(len(self.prepared))]
         return _merge_slab_results([p.result() for p in pendings],
                                    self.slab_gids, self.return_pairs)
 
@@ -989,4 +1001,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()   # command-line runs only, not in-process calls
     main()

@@ -108,9 +108,7 @@ def swiglu(x, w_gate, w_up, w_down):
 
 
 def shard(x, *spec):
-    """with_sharding_constraint that tolerates running outside a mesh (and
-    inside a 0.4.x fully-manual shard_map body, where compat strips the
-    promoted axes from the spec)."""
+    """with_sharding_constraint that tolerates running outside a mesh."""
     from repro.compat import sharding_constraint
 
     try:

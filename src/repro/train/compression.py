@@ -57,47 +57,5 @@ def compressed_psum_mean(grads, errors, axis: str, n_pods: int):
     return tdef.unflatten([o[0] for o in out]), tdef.unflatten([o[1] for o in out])
 
 
-def compressed_mean_gspmd(pod_grads, errors, n_pods: int):
-    """The same int8 exchange as ``compressed_psum_mean``, expressed over
-    EXPLICIT per-pod gradient operands inside one GSPMD program -- no
-    shard_map.
-
-    The jax 0.4.x line this container ships cannot lower the partial-manual
-    shard_map composition the collective form needs (the SPMD partitioner
-    hard-crashes on manual-subgroup operands; see ``repro.compat``), so the
-    train step there materializes each pod's gradient explicitly and runs
-    the identical quantize -> int32-sum -> dequantize pipeline as plain
-    array math, leaving the cross-pod transfer placement to GSPMD. The
-    wire-format claim is weaker than the collective form (XLA chooses what
-    crosses the DCN), but the *numerics* are the same scheme: shared scale
-    from the max per-pod absmax, int8 rounding per pod, error feedback
-    carrying the MEAN residual (adding the shared residual to every pod's
-    gradient feeds exactly one residual into the reconstructed mean, so the
-    scheme stays unbiased over time like the per-pod form).
-
-    ``pod_grads`` is a list of ``n_pods`` gradient pytrees; returns
-    (mean_grads, new_errors) with ``new_errors`` shaped like ``errors``
-    (one shared copy, matching ``init_error_state``).
-    """
-    flat_e, tdef = jax.tree.flatten(errors)
-    flat_gs = [tdef.flatten_up_to(g) for g in pod_grads]
-
-    def one(e, *gs):
-        g32 = [g.astype(jnp.float32) + e for g in gs]
-        smax = jnp.abs(g32[0]).max()
-        for g in g32[1:]:
-            smax = jnp.maximum(smax, jnp.abs(g).max())
-        scale = jnp.maximum(smax, 1e-12) / 127.0
-        qs = [quantize(g, scale) for g in g32]
-        recon = dequantize(sum(q.astype(jnp.int32) for q in qs), scale)
-        mean = recon / n_pods
-        new_e = (sum(g32) - recon) / n_pods       # mean residual feedback
-        return mean.astype(gs[0].dtype), new_e
-
-    out = [one(e, *(fg[i] for fg in flat_gs)) for i, e in enumerate(flat_e)]
-    return (tdef.unflatten([o[0] for o in out]),
-            tdef.unflatten([o[1] for o in out]))
-
-
 def init_error_state(params):
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)

@@ -67,10 +67,9 @@ def make_train_step(model, opt_cfg: AdamWConfig, *, compress_pods: bool = False,
 
     n_pods = mesh.shape["pod"]
 
-    def pod_partials_shard_map(params, batch, errors):
+    def pod_partials(params, batch, errors):
         """Per-pod grads + exchange via shard_map manual over 'pod' only
-        ('data'/'model' remain GSPMD-auto inside) -- the production form,
-        partial-manual, available on new jax."""
+        ('data'/'model' remain GSPMD-auto inside)."""
 
         def per_pod(params, batch, errors):
             (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -94,47 +93,6 @@ def make_train_step(model, opt_cfg: AdamWConfig, *, compress_pods: bool = False,
             check_vma=False,
             axis_names={"pod"},
         )(params, batch, errors)
-
-    def pod_partials_gspmd(params, batch, errors):
-        """Per-pod grads + exchange as one explicit GSPMD program -- the
-        jax 0.4.x composition (partial-manual shard_map crashes the 0.4.x
-        SPMD partitioner; see repro.compat).
-
-        Each pod's gradient comes from a FULL-shape backward whose loss
-        masks the other pods' rows (labels -1 drop out of the token mask),
-        not a sliced half-batch: the masked backward lowers to the same
-        partitioned program as the plain step's, so the compressed step
-        tracks the uncompressed trajectory to quantization error rather
-        than diverging on reduction-order numerics -- bf16 models are
-        sensitive enough that a differently-sharded backward drifts far
-        beyond the compression error within a few steps. Costs n_pods
-        backward passes; the shard_map form above is the scalable one.
-        """
-        rows = jax.tree_util.tree_leaves(batch)[0].shape[0]
-        if rows % n_pods:
-            # the shard_map form raises on a non-divisible pod shard; the
-            # masked form must not silently drop the remainder rows
-            raise ValueError(
-                f"batch rows {rows} not divisible by n_pods {n_pods}")
-        per = rows // n_pods
-        losses, auxes, pod_grads = [], [], []
-        for p in range(n_pods):
-            keep = (jnp.arange(rows) // per) == p
-            bp = dict(batch)
-            bp["labels"] = jnp.where(keep[:, None], batch["labels"], -1)
-            (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, bp)
-            losses.append(l)
-            auxes.append(aux)
-            pod_grads.append(g)
-        loss = sum(losses) / n_pods
-        aux = jax.tree.map(lambda *xs: sum(xs) / n_pods, *auxes)
-        grads, new_errors = comp.compressed_mean_gspmd(
-            pod_grads, errors, n_pods)
-        return loss, aux, grads, new_errors
-
-    pod_partials = (pod_partials_shard_map if hasattr(jax, "shard_map")
-                    else pod_partials_gspmd)
 
     def step(params, opt_state, batch):
         errors = opt_state["grad_error"]

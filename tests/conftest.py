@@ -10,13 +10,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 def _clear_jax_caches_between_modules():
     """Drop compiled executables between test modules.
 
-    The suite compiles hundreds of distinct programs in one process; on
-    this container's jax 0.4.37 CPU backend the accumulated executable
-    cache eventually segfaults a later XLA compile (reproducible at
-    suite scale, never in an isolated module). Nothing in the suite
-    relies on cross-module executable reuse -- no-retrace tests warm and
-    assert within a single module -- so clearing per module keeps the
-    process-wide cache bounded without changing any test's semantics.
+    The suite compiles hundreds of distinct programs in one process;
+    clearing per module keeps the process-wide executable cache (and the
+    worker's memory) bounded. Nothing in the suite relies on cross-module
+    executable reuse -- no-retrace tests warm and assert within a single
+    module -- so this changes no test's semantics.
     """
     yield
     import jax
