@@ -54,6 +54,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.core import grid as grid_lib
 from repro.core import metric as metric_lib
@@ -71,37 +72,19 @@ _EMIT_CAP_MIN = 1024
 # Trace-time event counters: the body of a jitted function executes only
 # while TRACING, so these increments count compilations, not calls. The
 # serve smoke and the no-retrace tests snapshot this dict across requests.
-#
-# Keys prefixed ``metric:`` are SERVING metrics, not compile events: the
-# continuous-batching service (launch/serve.py) publishes its queue-depth
-# and coalescing counters here so one observability surface carries both.
-# They move on every steady-state request, so every no-retrace freeze/
-# comparison must drop them (``metric_free`` below does).
+# It holds compile events only; serving counters ride the host spans of
+# ``launch/serve.py`` (DESIGN.md S8, "Observability").
 TRACE_EVENTS: collections.Counter = collections.Counter()
-
-METRIC_PREFIX = "metric:"
 
 
 def _bump(name: str) -> None:
     TRACE_EVENTS[name] += 1
 
 
-def note_metric(name: str, inc: int = 1) -> None:
-    """Accumulate a serving metric (``metric:``-prefixed TRACE_EVENTS key)."""
-    TRACE_EVENTS[METRIC_PREFIX + name] += int(inc)
-
-
-def note_metric_peak(name: str, value: int) -> None:
-    """Record the running peak of a serving metric (e.g. queue depth)."""
-    key = METRIC_PREFIX + name
-    TRACE_EVENTS[key] = max(TRACE_EVENTS[key], int(value))
-
-
 def metric_free(trace_events: dict) -> dict:
-    """Drop ``metric:`` keys: the compile-event view of TRACE_EVENTS that
-    no-retrace comparisons must use (metrics move per request by design)."""
-    return {k: v for k, v in trace_events.items()
-            if not k.startswith(METRIC_PREFIX)}
+    """A copy of ``trace_events`` as it is: every key is a compile event.
+    Kept for callers that still filter through it."""
+    return dict(trace_events)
 
 
 def _next_pow2(x: int) -> int:
@@ -351,14 +334,17 @@ class PendingJoin:
         chunks = []
         perm = self._perm
         for ln in self._launches:
-            counts_b = np.asarray(ln.counts)[: ln.n_rows]
+            with span("query.sync"):
+                counts_b = np.asarray(ln.counts)[: ln.n_rows]
             rows = (np.arange(ln.n_rows) if ln.rows is None else ln.rows)
             if perm is not None:
                 rows = perm[rows]   # sorted-batch row -> request row
             counts_np[rows] = counts_b
             if self._return_pairs:
-                p = pj._emit(self._emit, ln.hits, ln.counts, ln.base, ln.ws,
-                             c=ln.c, tq=ln.tile, total=int(counts_b.sum()))
+                with span("query.emit"):
+                    p = pj._emit(self._emit, ln.hits, ln.counts, ln.base,
+                                 ln.ws, c=ln.c, tq=ln.tile,
+                                 total=int(counts_b.sum()))
                 if ln.rows is not None:
                     p[:, 0] = ln.rows[p[:, 0]]   # launch row -> batch row
                 if perm is not None:
@@ -371,7 +357,8 @@ class PendingJoin:
                      else np.empty((0, 2), np.int32))
             assert pairs.shape[0] == int(counts_np.sum())
             if self._sort_pairs:
-                pairs = pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]
+                with span("query.sort"):
+                    pairs = pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]
         cands = (int(np.asarray(self._wc).sum())
                  if self._with_stats else None)
         self._result = QueryJoinResult(
@@ -602,9 +589,11 @@ class PreparedJoin:
             # linearized key could alias distinct out-of-grid cells) --
             # so co-located queries form contiguous runs. Out-of-grid
             # clip collisions are safe: such queries have no live window.
-            qc = np.clip(np.floor((q - self.gmin_np[None, :]) / self.eps),
-                         -(1 << 24), 1 << 24).astype(np.int64)
-            perm = np.lexsort(qc.T)
+            with span("query.order"):
+                qc = np.clip(
+                    np.floor((q - self.gmin_np[None, :]) / self.eps),
+                    -(1 << 24), 1 << 24).astype(np.int64)
+                perm = np.lexsort(qc.T)
             q, qc = q[perm], qc[perm]
             if qf is not None:
                 qf = qf[perm]
@@ -625,20 +614,22 @@ class PreparedJoin:
         launches = []
         if not self.bucketed:
             tile = self.tiles[self.c]
-            ro = (self._launch_run_ord(gid, qp, tile)
-                  if self.run_loop else None)
-            hits, counts, base = ops.fused_join_hits(
-                self.points_pad, q_dev, ws, wc, self.is_zero,
-                self._q_pos(qp), eps, c=self.c, n_real=self.n_dims,
-                unicomp=False, external=True, merged=self.merged, tq=tile,
-                keep_hits=return_pairs, run_ord=ro,
-                run_loop=self.run_loop, method=method,
-                metric=self.metric, n_feat=self.n_feat)
+            with span("query.launch"):
+                ro = (self._launch_run_ord(gid, qp, tile)
+                      if self.run_loop else None)
+                hits, counts, base = ops.fused_join_hits(
+                    self.points_pad, q_dev, ws, wc, self.is_zero,
+                    self._q_pos(qp), eps, c=self.c, n_real=self.n_dims,
+                    unicomp=False, external=True, merged=self.merged,
+                    tq=tile, keep_hits=return_pairs, run_ord=ro,
+                    run_loop=self.run_loop, method=method,
+                    metric=self.metric, n_feat=self.n_feat)
             launches.append(_FusedLaunch(
                 rows=None, n_rows=n_queries, hits=hits, counts=counts,
                 base=base, ws=ws, c=self.c, tile=tile))
         else:
-            caps = np.asarray(_window_caps(wc))[:n_queries]
+            with span("query.sync"):
+                caps = np.asarray(_window_caps(wc))[:n_queries]
             caps_aligned = np.minimum(_round_up(caps, _C_ALIGN), self.c)
             cls = np.searchsorted(np.asarray(self.classes), caps_aligned)
             for k, cb in enumerate(self.classes):
@@ -646,23 +637,24 @@ class PreparedJoin:
                 if not rows.size:
                     continue   # empty class (or all-miss rows: counts stay 0)
                 tile = self.tiles[cb]
-                qp_b = bucket_rows(rows.size, tile)
-                sel = np.zeros(qp_b, np.int32)
-                sel[:rows.size] = rows
-                ws_b, wc_b, q_b = _bucket_select(
-                    ws, wc, q_dev, jnp.asarray(sel),
-                    jnp.asarray(rows.size, jnp.int32))
-                # rows ascend batch order, so equal-cell rows stay
-                # contiguous within the class launch
-                ro = (self._launch_run_ord(gid[rows], qp_b, tile)
-                      if self.run_loop else None)
-                hits, counts, base = ops.fused_join_hits(
-                    self.points_pad, q_b, ws_b, wc_b, self.is_zero,
-                    self._q_pos(qp_b), eps, c=cb, n_real=self.n_dims,
-                    unicomp=False, external=True, merged=self.merged,
-                    tq=tile, keep_hits=return_pairs, run_ord=ro,
-                    run_loop=self.run_loop, method=method,
-                    metric=self.metric, n_feat=self.n_feat)
+                with span("query.launch"):
+                    qp_b = bucket_rows(rows.size, tile)
+                    sel = np.zeros(qp_b, np.int32)
+                    sel[:rows.size] = rows
+                    ws_b, wc_b, q_b = _bucket_select(
+                        ws, wc, q_dev, jnp.asarray(sel),
+                        jnp.asarray(rows.size, jnp.int32))
+                    # rows ascend batch order, so equal-cell rows stay
+                    # contiguous within the class launch
+                    ro = (self._launch_run_ord(gid[rows], qp_b, tile)
+                          if self.run_loop else None)
+                    hits, counts, base = ops.fused_join_hits(
+                        self.points_pad, q_b, ws_b, wc_b, self.is_zero,
+                        self._q_pos(qp_b), eps, c=cb, n_real=self.n_dims,
+                        unicomp=False, external=True, merged=self.merged,
+                        tq=tile, keep_hits=return_pairs, run_ord=ro,
+                        run_loop=self.run_loop, method=method,
+                        metric=self.metric, n_feat=self.n_feat)
                 launches.append(_FusedLaunch(
                     rows=rows, n_rows=rows.size, hits=hits, counts=counts,
                     base=base, ws=ws_b, c=cb, tile=tile))

@@ -51,6 +51,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.core import metric as metric_lib
 from repro.core.grid import (GridIndex, build_grid,
@@ -674,15 +675,22 @@ def _fused_launches(index: GridIndex, *, n_batches: int,
     every launch then becomes an explicit selection. ``gid`` rides the
     per-point global ids in a pad lane of the points copy.
     """
-    from repro.core.grid import (BucketPlan, filter_plan_rows,
+    from repro.core.grid import (CAP_ALIGN, BucketPlan,
+                                 cell_window_caps_cached, filter_plan_rows,
                                  global_window_cap, occupancy_plan)
 
     npts = index.num_points
-    c_glob = global_window_cap(index, merged)
-    n_batches = max(min(int(n_batches), max(npts, 1)), 1)
-    batch_rows = -(-max(npts, 1) // n_batches)  # ceil
     if bucketed is None:
         bucketed = True
+    with span("selfjoin.sync"):
+        # the window capacities the schedule is planned on, cached per
+        # index: a fetch of the index's cell counts, or the host wait on
+        # the caps planner's device program (grid.cell_window_caps)
+        c_glob = global_window_cap(index, merged)
+        if bucketed and c_glob > CAP_ALIGN:
+            cell_window_caps_cached(index, merged=merged)
+    n_batches = max(min(int(n_batches), max(npts, 1)), 1)
+    batch_rows = -(-max(npts, 1) // n_batches)  # ceil
     plan = occupancy_plan(index, merged=merged) if bucketed else None
     if row_ok is not None:
         if plan is None:
@@ -774,76 +782,102 @@ def _self_join_fused(index: GridIndex, *, unicomp: bool, sort_result: bool,
     order), and the kernel scalar when it differs from the index's cell
     width (jaccard). The fill machinery is metric-agnostic -- it only
     consumes the hit mask and window descriptors.
+
+    Host spans (DESIGN.md S8, "Observability"): the whole call runs under
+    ``selfjoin.join``; launch planning under ``selfjoin.plan`` (its
+    ``launches``) and ``selfjoin.run_plan``, each dispatch under
+    ``selfjoin.launch``, every host wait on a device program under
+    ``selfjoin.sync``, the fill under ``selfjoin.emit`` and the result
+    sort under ``selfjoin.sort``.
     """
-    if emit is None:
-        emit = "device" if jax.default_backend() == "tpu" else "host"
-    if run_loop is None:
-        run_loop = _join_run_loop(index)
-    if merged:
-        deltas, is_zero = _merged_offset_tables(index, unicomp)
-    else:
-        deltas, is_zero = _offset_tables(index, unicomp)
-    npts = index.num_points
-    order_np = np.asarray(index.order) if ids is None else np.asarray(ids)
-    ids_dev = index.order if ids is None else jnp.asarray(
-        np.asarray(ids).astype(np.int32))
-    gid = jnp.asarray(order_np.astype(np.int32)) if gid_pairs else None
-    mult = 2 if unicomp else 1
-    launches, points_pad, _ = _fused_launches(
-        index, n_batches=n_batches, bucketed=bucketed, merged=merged,
-        row_ok=row_ok, gid=gid, feats=feats)
-    single = len(launches) == 1
-
-    def finish(run):
-        """Drain one launch: blocks on ITS buffers only, so the next
-        launch's kernel (already dispatched, JAX async) overlaps the
-        transfer -- the paper's SV-A compute/copy overlap, kept on the
-        fused path."""
-        ws, hits, counts, base, q_pos, cap, tile = run
-        if emit == "host":
-            pairs = _emit_from_hits_host(
-                order_np, hits, ws, np.asarray(q_pos), npts, unicomp)
-            assert pairs.shape[0] == mult * int(counts.sum(dtype=jnp.int64))
-            return pairs
-        ordered = mult * int(counts.sum(dtype=jnp.int64))
-        capacity = max(ordered if single else _next_pow2(ordered), 1)
-        keys, vals, cnt = _emit_from_hits(
-            index, ids_dev, hits, counts, base, ws, q_pos,
-            c=cap, tq=tile, unicomp=unicomp, capacity=capacity)
-        assert int(cnt) == ordered, (int(cnt), ordered)
-        return np.stack(
-            [np.asarray(keys)[:ordered], np.asarray(vals)[:ordered]], axis=1)
-
-    chunks = []
-    prev = None
-    for sel, q_start, q_size, qp, cap, tile in launches:
-        plan = (_launch_run_plan(index, sel, q_start, qp=qp, tile=tile)
-                if run_loop else None)
-        if sel is None:
-            ws, _, _, hits, counts, base, q_pos = _fused_batch_run(
-                index, points_pad, deltas, is_zero, q_start, qp=qp,
-                q_size=q_size, c=cap, unicomp=unicomp, keep_hits=True,
-                method=method, tq=tile, merged=merged, gid_pairs=gid_pairs,
-                run_plan=plan, metric=metric, n_feat=n_feat,
-                refine_eps=refine_eps)
+    with span("selfjoin.join"):
+        if emit is None:
+            emit = "device" if jax.default_backend() == "tpu" else "host"
+        if run_loop is None:
+            with span("selfjoin.sync"):
+                run_loop = _join_run_loop(index)
+        if merged:
+            deltas, is_zero = _merged_offset_tables(index, unicomp)
         else:
-            ws, _, _, hits, counts, base, q_pos = _fused_bucket_launch(
-                index, points_pad, deltas, is_zero, sel, qp=qp, c=cap,
-                unicomp=unicomp, keep_hits=True, method=method, tq=tile,
-                merged=merged, gid_pairs=gid_pairs, run_plan=plan,
-                metric=metric, n_feat=n_feat, refine_eps=refine_eps)
+            deltas, is_zero = _offset_tables(index, unicomp)
+        npts = index.num_points
+        with span("selfjoin.sync"):
+            order_np = (np.asarray(index.order) if ids is None
+                        else np.asarray(ids))
+        ids_dev = index.order if ids is None else jnp.asarray(
+            np.asarray(ids).astype(np.int32))
+        gid = jnp.asarray(order_np.astype(np.int32)) if gid_pairs else None
+        mult = 2 if unicomp else 1
+        with span("selfjoin.plan") as sp:
+            launches, points_pad, _ = _fused_launches(
+                index, n_batches=n_batches, bucketed=bucketed,
+                merged=merged, row_ok=row_ok, gid=gid, feats=feats)
+            sp.set_metadata(launches=len(launches))
+        single = len(launches) == 1
+
+        def finish(run):
+            """Drain one launch: blocks on ITS buffers only, so the next
+            launch's kernel (already dispatched, JAX async) overlaps the
+            transfer -- the paper's SV-A compute/copy overlap, kept on the
+            fused path."""
+            ws, hits, counts, base, q_pos, cap, tile = run
+            with span("selfjoin.sync"):
+                ordered = mult * int(counts.sum(dtype=jnp.int64))
+            if emit == "host":
+                with span("selfjoin.emit"):
+                    pairs = _emit_from_hits_host(
+                        order_np, hits, ws, np.asarray(q_pos), npts, unicomp)
+                assert pairs.shape[0] == ordered
+                return pairs
+            capacity = max(ordered if single else _next_pow2(ordered), 1)
+            with span("selfjoin.emit"):
+                keys, vals, cnt = _emit_from_hits(
+                    index, ids_dev, hits, counts, base, ws, q_pos,
+                    c=cap, tq=tile, unicomp=unicomp, capacity=capacity)
+                with span("selfjoin.sync"):
+                    assert int(cnt) == ordered, (int(cnt), ordered)
+                    keys, vals = (np.asarray(keys)[:ordered],
+                                  np.asarray(vals)[:ordered])
+                return np.stack([keys, vals], axis=1)
+
+        chunks = []
+        prev = None
+        for sel, q_start, q_size, qp, cap, tile in launches:
+            plan = None
+            if run_loop:
+                with span("selfjoin.run_plan"):
+                    plan = _launch_run_plan(index, sel, q_start, qp=qp,
+                                            tile=tile)
+            with span("selfjoin.launch"):
+                if sel is None:
+                    ws, _, _, hits, counts, base, q_pos = _fused_batch_run(
+                        index, points_pad, deltas, is_zero, q_start, qp=qp,
+                        q_size=q_size, c=cap, unicomp=unicomp,
+                        keep_hits=True, method=method, tq=tile,
+                        merged=merged, gid_pairs=gid_pairs, run_plan=plan,
+                        metric=metric, n_feat=n_feat, refine_eps=refine_eps)
+                else:
+                    ws, _, _, hits, counts, base, q_pos = \
+                        _fused_bucket_launch(
+                            index, points_pad, deltas, is_zero, sel, qp=qp,
+                            c=cap, unicomp=unicomp, keep_hits=True,
+                            method=method, tq=tile, merged=merged,
+                            gid_pairs=gid_pairs, run_plan=plan,
+                            metric=metric, n_feat=n_feat,
+                            refine_eps=refine_eps)
+            if prev is not None:
+                chunks.append(finish(prev))
+            prev = (ws, hits, counts, base, q_pos, cap, tile)
         if prev is not None:
             chunks.append(finish(prev))
-        prev = (ws, hits, counts, base, q_pos, cap, tile)
-    if prev is not None:
-        chunks.append(finish(prev))
-    from repro.analysis import sanitize
-    sanitize.raise_pending()   # REPRO_SANITIZE: launches already drained
-    out = (np.concatenate(chunks, axis=0) if chunks
-           else np.empty((0, 2), np.int32))
-    if sort_result:
-        out = out[np.lexsort((out[:, 1], out[:, 0]))]
-    return out
+        from repro.analysis import sanitize
+        sanitize.raise_pending()   # REPRO_SANITIZE: launches already drained
+        out = (np.concatenate(chunks, axis=0) if chunks
+               else np.empty((0, 2), np.int32))
+        if sort_result:
+            with span("selfjoin.sort"):
+                out = out[np.lexsort((out[:, 1], out[:, 0]))]
+        return out
 
 
 def _self_join_count_fused(index: GridIndex, *, unicomp: bool,
@@ -1321,17 +1355,19 @@ def _route_features(index: GridIndex, deltas) -> dict:
     clustered data, where a query's probes concentrate in its own (live)
     neighborhood.
     """
-    ncells = max(int(index.num_cells), 1)
+    npts = index.num_points
+    with span("selfjoin.sync"):
+        ncells = max(int(index.num_cells), 1)
+        c = max(int(index.max_per_cell), 1)
+        if npts:
+            keys = np.asarray(index.cell_keys[:ncells])
+            rank = np.asarray(index.point_cell_rank)
     # float prod: a fine 6-D grid overflows int64, and the heuristic only
     # needs a ratio
     volume = max(float(np.prod(np.asarray(index.dims, dtype=np.float64))), 1.0)
     occupancy = ncells / volume
-    c = max(int(index.max_per_cell), 1)
-    npts = index.num_points
     live_frac = 0.0
-    if npts and ncells:
-        keys = np.asarray(index.cell_keys[:ncells])
-        rank = np.asarray(index.point_cell_rank)
+    if npts:
         sample = rank[::-(-npts // 1024)][:1024]   # ceil stride: spans all
                                                    # of sorted key order
         probe = keys[sample][None, :] + np.asarray(deltas)[:, None]
@@ -1636,8 +1672,9 @@ def _join_sweep_merged(index: GridIndex, *, unicomp: bool,
     -- the S7 parity guarantee is what licenses the switch."""
     if not merged:
         return False
-    route = _auto_route(index, unicomp=unicomp, bucketed=bucketed,
-                        merged=True)
+    with span("selfjoin.route"):
+        route = _auto_route(index, unicomp=unicomp, bucketed=bucketed,
+                            merged=True)
     return route != "dense-flat"
 
 

@@ -34,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.configs import get_config
 from repro.launch.mesh import make_smoke_mesh
@@ -125,23 +126,21 @@ class _JoinServiceBase:
         static shape bucketed to powers of two (with a floor), so a
         pair-serving service legitimately compiles O(log max_result) emit
         executables on demand as larger results first appear -- warmup
-        cannot know result sizes in advance. Observability counters
-        (``metric:`` trace events, e.g. the batching service's coalesce
-        counters) are also exempt: they bump per launch without tracing.
-        The prepare-path builders/planners (``grid_build``/``grid_caps``/
-        ``grid_extspan``) are exempt too: they compile during index build
-        and background ``reindex``, never per steady-state request.
+        cannot know result sizes in advance. The prepare-path
+        builders/planners (``grid_build``/``grid_caps``/``grid_extspan``)
+        are exempt too: they compile during index build and background
+        ``reindex``, never per steady-state request.
         The request-path functions (window descriptors, fused sweep) must
         stay frozen; those are what the per-request re-tracing bug
         burned."""
-        from repro.core.query_join import executable_cache_stats, metric_free
+        from repro.core.query_join import executable_cache_stats
 
         def freeze(stats: dict) -> dict:
             out = {k: v for k, v in stats.items()
                    if k not in ("emit_pairs_device", "trace_events",
                                 "grid_build", "grid_caps", "grid_extspan")}
             out["trace_events"] = {
-                k: v for k, v in metric_free(stats["trace_events"]).items()
+                k: v for k, v in stats["trace_events"].items()
                 if k != "emit_pairs_device"}
             return out
 
@@ -511,12 +510,13 @@ class _Sub:
 class _Inflight:
     """A launched coalesced batch whose device results are outstanding."""
 
-    __slots__ = ("pendings", "subs", "bounds")
+    __slots__ = ("pendings", "subs", "bounds", "launch")
 
-    def __init__(self, pendings, subs, bounds):
+    def __init__(self, pendings, subs, bounds, launch):
         self.pendings = pendings      # one PendingJoin per slab
         self.subs = subs
         self.bounds = bounds
+        self.launch = launch          # id shared by its launch/resolve spans
 
 
 class BatchingJoinService(_JoinServiceBase):
@@ -538,6 +538,15 @@ class BatchingJoinService(_JoinServiceBase):
     back out of the coalesced ``QueryJoinResult`` by query-row range
     (``slice_result``) -- bitwise identical to serving the request alone
     (tests/test_serve_batching.py property-tests arbitrary partitions).
+
+    Host spans (DESIGN.md S8, "Observability"): ``serve.submit`` per
+    request, ``serve.launch`` per coalesced batch (``launch`` id,
+    ``rows``, ``parts``, and ``wait_us_sum``, the parts' summed
+    admission-queue wait), and ``serve.resolve`` per batch answered (the
+    same ``launch`` id; ``forced`` 1 when it resolves without its device
+    values known to be down). The counters ride the spans, so a profiler
+    trace reads them over exactly the window it covers; with no profiler
+    running they are not computed.
 
     A request wider than ``max_batch`` splits into parts that ride
     separate launches and concatenate on completion; an empty request
@@ -630,7 +639,11 @@ class BatchingJoinService(_JoinServiceBase):
         """Enqueue one request; returns a ticket that completes once every
         part has been served from a coalesced launch (``pump``/``drain``
         advance the pipeline). Does not block."""
-        from repro.core.query_join import QueryJoinResult, note_metric_peak
+        with span("serve.submit"):
+            return self._submit(queries, eps)
+
+    def _submit(self, queries, eps: Optional[float]) -> BatchTicket:
+        from repro.core.query_join import QueryJoinResult
 
         pj0 = self.prepared[0]
         if self.metric != "l2":
@@ -666,7 +679,6 @@ class BatchingJoinService(_JoinServiceBase):
         for i, p in enumerate(parts):
             self._queue.append(_Sub(p, eps_key, ticket, i))
             self._queued_rows += p.shape[0]
-        note_metric_peak("batch.queue_depth_peak", len(self._queue))
         return ticket
 
     # -- pipeline ----------------------------------------------------------
@@ -700,46 +712,53 @@ class BatchingJoinService(_JoinServiceBase):
         return group
 
     def _launch(self, group: list[_Sub]) -> None:
-        from repro.core.query_join import coalesce_requests, note_metric
+        from repro.core.query_join import coalesce_requests
 
-        qcat, bounds = coalesce_requests([s.queries for s in group])
-        eps = group[0].eps_key
-        single = self.slab_gids is None
-        pj0 = self.prepared[0]
-        if self.metric != "l2":
-            # split the admission-time concatenation back into the
-            # (geometry, features) pair join_async consumes directly
-            qsend = (qcat[:, :pj0.n_dims],
-                     qcat[:, pj0.n_dims:] if pj0.n_feat else None)
-        else:
-            qsend = qcat
-        pendings = [pj.join_async(qsend, eps=eps,
-                                  return_pairs=self.return_pairs,
-                                  sort_pairs=single)
-                    for pj in self.prepared]
-        self._inflight.append(_Inflight(pendings, group, bounds))
-        self.n_launches += 1
-        self.n_coalesced += len(group)
-        self.rows_launched += qcat.shape[0]
-        note_metric("batch.launches")
-        note_metric("batch.coalesced_requests", len(group))
-        note_metric("batch.rows", qcat.shape[0])
+        with span("serve.launch") as sp:
+            if span.is_enabled():
+                now = time.perf_counter()
+                sp.set_metadata(
+                    launch=self.n_launches, parts=len(group),
+                    rows=sum(s.queries.shape[0] for s in group),
+                    wait_us_sum=int(1e6 * sum(now - s.t_arrival
+                                              for s in group)))
+            qcat, bounds = coalesce_requests([s.queries for s in group])
+            eps = group[0].eps_key
+            single = self.slab_gids is None
+            pj0 = self.prepared[0]
+            if self.metric != "l2":
+                # split the admission-time concatenation back into the
+                # (geometry, features) pair join_async consumes directly
+                qsend = (qcat[:, :pj0.n_dims],
+                         qcat[:, pj0.n_dims:] if pj0.n_feat else None)
+            else:
+                qsend = qcat
+            pendings = [pj.join_async(qsend, eps=eps,
+                                      return_pairs=self.return_pairs,
+                                      sort_pairs=single)
+                        for pj in self.prepared]
+            self._inflight.append(_Inflight(pendings, group, bounds,
+                                            self.n_launches))
+            self.n_launches += 1
+            self.n_coalesced += len(group)
+            self.rows_launched += qcat.shape[0]
 
-    def _resolve_oldest(self) -> None:
+    def _resolve_oldest(self, forced: bool) -> None:
         from repro.core.query_join import slice_result
 
         infl = self._inflight.popleft()
-        if self.slab_gids is None:
-            res = infl.pendings[0].result()
-        else:
-            res = _merge_slab_results(
-                [p.result() for p in infl.pendings],
-                self.slab_gids, self.return_pairs)
-        for k, sub in enumerate(infl.subs):
-            part = slice_result(res, int(infl.bounds[k]),
-                                int(infl.bounds[k + 1]))
-            sub.ticket._add_part(sub.part, part)
-            self.total_neighbors += part.total
+        with span("serve.resolve", launch=infl.launch, forced=int(forced)):
+            if self.slab_gids is None:
+                res = infl.pendings[0].result()
+            else:
+                res = _merge_slab_results(
+                    [p.result() for p in infl.pendings],
+                    self.slab_gids, self.return_pairs)
+            for k, sub in enumerate(infl.subs):
+                part = slice_result(res, int(infl.bounds[k]),
+                                    int(infl.bounds[k + 1]))
+                sub.ticket._add_part(sub.part, part)
+                self.total_neighbors += part.total
 
     def pump(self) -> None:
         """Advance the pipeline without blocking on admission: launch
@@ -751,10 +770,12 @@ class BatchingJoinService(_JoinServiceBase):
         now = time.perf_counter()
         while self._flush_due(now):
             self._launch(self._form_group())
-        while self._inflight and (len(self._inflight) > 2
-                                  or all(p.ready() for p
-                                         in self._inflight[0].pendings)):
-            self._resolve_oldest()
+        while self._inflight:
+            forced = len(self._inflight) > 2
+            if not (forced or all(p.ready() for p
+                                  in self._inflight[0].pendings)):
+                break
+            self._resolve_oldest(forced)
 
     def drain(self) -> None:
         """Flush and resolve everything: queued requests launch regardless
@@ -763,7 +784,7 @@ class BatchingJoinService(_JoinServiceBase):
         while self._queue:
             self._launch(self._form_group())
         while self._inflight:
-            self._resolve_oldest()
+            self._resolve_oldest(True)
 
     # -- service interface -------------------------------------------------
 
