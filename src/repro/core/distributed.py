@@ -28,7 +28,7 @@ single-pass count -> fill; ``selfjoin._self_join_fused``) restricted to
 owned query rows, with GLOBAL point ids riding a kernel pad lane
 (``gid_pairs``) so the UNICOMP intra-cell tie-break is device-independent.
 Emits (K, 2) global-id pairs bit-identical to
-``self_join(distance_impl='fused')`` after the lexsort;
+``self_join(distance_impl='fused')`` after the (row, column) sort;
 ``return_pairs=False`` runs the count-only launches.
 
 ``distributed_self_join_count`` -- the legacy jnp offset-sweep counter,
@@ -58,7 +58,8 @@ from repro.core import grid as grid_lib
 from repro.core.grid import (build_grid_with_geometry,
                              build_grid_with_geometry_jit, device_key_dtype,
                              host_grid_geometry, offset_deltas)
-from repro.core.selfjoin import _distance_hits_jnp, _gather_batch, _neighbor_ranks_for_delta
+from repro.core.selfjoin import (_distance_hits_jnp, _gather_batch,
+                                 _neighbor_ranks_for_delta, _sort_pairs)
 from repro.core.stencil import stencil_offsets
 
 
@@ -597,7 +598,7 @@ def distributed_self_join(
 
     The result is the same (K, 2) int32 ordered-pair array as
     ``self_join(distance_impl='fused')`` -- bit-identical after the
-    ``sort_result`` lexsort (asserted across device counts, UNICOMP and
+    ``sort_result`` sort (asserted across device counts, UNICOMP and
     sweep modes in tests/test_distributed.py and the CI bench smoke).
     ``return_pairs=False`` runs the count-only fused sweep (no hit
     buffers) and returns the total ordered-pair count.
@@ -721,5 +722,5 @@ def distributed_self_join(
         return total
     out = np.concatenate(chunks, axis=0) if chunks else empty
     if sort_result:
-        out = out[np.lexsort((out[:, 1], out[:, 0]))]
+        out = _sort_pairs(out)
     return out

@@ -630,6 +630,24 @@ def _next_pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length()
 
 
+def _sort_pairs(pairs: np.ndarray) -> np.ndarray:
+    """The join's canonical result order: ``pairs`` sorted by (row, column).
+
+    ``pairs`` is (K, 2) of non-negative int32 ids. The order equals
+    ``pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]``: for such ids it is
+    the order of the 64-bit key ``row << 32 | column``, which one
+    ``np.sort`` orders without lexsort's two argsort passes and row
+    gather (equal keys are equal rows, so stability does not matter).
+    Returns a fresh C-contiguous (K, 2) int32 array."""
+    key = pairs[:, 0].astype(np.int64) << 32
+    key |= pairs[:, 1]
+    key.sort()
+    out = np.empty((key.shape[0], 2), np.int32)
+    out[:, 0] = key >> 32
+    out[:, 1] = key & 0xFFFFFFFF
+    return out
+
+
 def _emit_from_hits_host(order: np.ndarray, hits, win_start,
                          q_pos: np.ndarray, npts: int,
                          unicomp: bool) -> np.ndarray:
@@ -876,7 +894,7 @@ def _self_join_fused(index: GridIndex, *, unicomp: bool, sort_result: bool,
                else np.empty((0, 2), np.int32))
         if sort_result:
             with span("selfjoin.sort"):
-                out = out[np.lexsort((out[:, 1], out[:, 0]))]
+                out = _sort_pairs(out)
         return out
 
 
@@ -1882,7 +1900,7 @@ def self_join(
     assert int(count) == stats.total_pairs, (int(count), stats.total_pairs)
     pairs = np.stack([np.asarray(keys), np.asarray(vals)], axis=1)[: int(count)]
     if sort_result:  # the paper sorts the key/value result after the kernel
-        pairs = pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]
+        pairs = _sort_pairs(pairs)
     return pairs
 
 
@@ -1966,7 +1984,7 @@ def self_join_batched(
         out[pos : pos + k, 1] = np.asarray(vals)[:k]
         pos += k
     if sort_result:
-        out = out[np.lexsort((out[:, 1], out[:, 0]))]
+        out = _sort_pairs(out)
     return out
 
 

@@ -10,12 +10,14 @@ Hypothesis property tests live in test_selfjoin_properties.py (skipped when
 hypothesis is absent); fused-kernel parity tests in test_fused_join.py.
 """
 import numpy as np
+import pytest
 
 from repro.core.baselines import ego_join, rtree_join
 from repro.core.brute import brute_force_count, brute_force_join
 from repro.core.grid import build_grid, build_grid_host, masks_host
 from repro.core.selfjoin import (
     JoinStats,
+    _sort_pairs,
     per_point_neighbor_counts,
     range_query,
     self_join,
@@ -291,3 +293,47 @@ def test_batched_more_batches_than_points():
             got = self_join_batched(pts, 0.8, n_batches=npts + 4,
                                     distance_impl=impl)
             assert np.array_equal(got, ref), (npts, impl)
+
+
+def _emit_like_chunks(rng):
+    """~200k pairs in three query-major chunks as the emit lays them out:
+    each query's hits in a run, queries in a random order."""
+    n_ids = 50_000
+    chunks = []
+    for _ in range(3):
+        rows = np.sort(rng.integers(0, n_ids, 66_667))
+        q = rng.permutation(n_ids)
+        chunks.append(np.stack([q[rows], rng.integers(0, n_ids, rows.size)],
+                               axis=1))
+    return np.concatenate(chunks).astype(np.int32)
+
+
+_SORT_CASES = {
+    "empty": lambda rng: np.empty((0, 2), np.int32),
+    "one_row": lambda rng: np.array([[7, 3]], np.int32),
+    "emit_chunks": _emit_like_chunks,
+    "max_ids": lambda rng: np.concatenate([
+        rng.integers(0, 2**31, (5_000, 2)),
+        [[2**31 - 1, 2**31 - 1], [2**31 - 1, 0], [0, 2**31 - 1], [0, 0]],
+    ]).astype(np.int32),
+    "shared_first_column": lambda rng: np.stack([
+        rng.integers(0, 4, 20_000), rng.integers(0, 2**31, 20_000)],
+        axis=1).astype(np.int32),
+    "already_sorted": lambda rng: brute_force_join(
+        rng.uniform(0, 10, (400, 2)), 1.0),
+    "reversed": lambda rng: brute_force_join(
+        rng.uniform(0, 10, (400, 2)), 1.0)[::-1],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SORT_CASES))
+def test_sort_pairs_matches_lexsort(case):
+    """The packed-key sort gives the (row, column) lexsort order bit for
+    bit, as a fresh C-contiguous (K, 2) int32 array."""
+    pairs = _SORT_CASES[case](np.random.default_rng(31))
+    ref = pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]
+    got = _sort_pairs(pairs)
+    assert got.dtype == np.int32
+    assert got.shape == pairs.shape
+    assert got.flags.c_contiguous
+    assert np.array_equal(got, ref)
