@@ -243,54 +243,73 @@ def phase_serve(pts, index, rng):
 
 def phase_four_chips(pts):
     """distributed_self_join on a 4-slab mesh vs the single-chip join. The
-    recorders observe where each slab's index and fused launches ran."""
+    recorders observe where each slab's index and fused launches ran, and
+    that every slab's first launch was dispatched before any slab's pairs
+    were emitted (the slabs' stages run in step)."""
     import jax
 
-    from repro.core import distributed
+    from repro.core import distributed, selfjoin
     from repro.core.selfjoin import self_join
     from repro.kernels import ops
     from repro.launch.mesh import make_slab_mesh
 
     mesh = make_slab_mesh(4)
     placed = []
-    build, launch = distributed.build_grid_with_geometry_jit, \
-        ops.fused_join_hits
+    stages, launch, emit = distributed._slab_stages, \
+        ops.fused_join_hits, selfjoin._emit_from_hits
 
-    def build_rec(*a, **kw):
-        index = build(*a, **kw)
+    def stages_rec(index, *a, **kw):
         placed.append(["index", sorted(index.points_sorted.devices(),
                                        key=str)])
-        return index
+        return stages(index, *a, **kw)
 
     def launch_rec(*a, **kw):
         out = launch(*a, **kw)
         placed.append(["launch", sorted(out[1].devices(), key=str)])
         return out
 
-    distributed.build_grid_with_geometry_jit = build_rec
+    def emit_rec(*a, **kw):
+        placed.append(["emit", None])
+        return emit(*a, **kw)
+
+    distributed._slab_stages = stages_rec
     ops.fused_join_hits = launch_rec
+    selfjoin._emit_from_hits = emit_rec
     try:
         t0 = time.perf_counter()
         got = distributed.distributed_self_join(pts, EPS_2D, mesh)
         t_dist = time.perf_counter() - t0
     finally:
-        distributed.build_grid_with_geometry_jit = build
+        distributed._slab_stages = stages
         ops.fused_join_hits = launch
-    # group the records per slab: each index build opens a slab
-    slabs = []
-    for kind, devs in placed:
-        if kind == "index":
-            slabs.append({"index": devs, "launch": set()})
-        else:
-            slabs[-1]["launch"].update(devs)
-    for k, s in enumerate(slabs):
+        selfjoin._emit_from_hits = emit
+    # each slab's index opens a slab; a launch belongs to the slab whose
+    # index lives on its device
+    slabs = {str(devs[0]): {"index": devs, "launch": set(), "first": None}
+             for kind, devs in placed if kind == "index" and len(devs) == 1}
+    first_emit = min((i for i, (kind, _) in enumerate(placed)
+                      if kind == "emit"), default=None)   # None: host emit
+    stray = []
+    for i, (kind, devs) in enumerate(placed):
+        if kind != "launch":
+            continue
+        s = slabs.get(str(devs[0])) if len(devs) == 1 else None
+        if s is None:
+            stray.append(devs)
+            continue
+        s["launch"].update(devs)
+        s["first"] = i if s["first"] is None else s["first"]
+    for k, s in enumerate(slabs.values()):
         log(f"four-chips: slab {k}: index on {s['index']}, fused launches "
-            f"on {sorted(s['launch'], key=str)}")
-    own = [s["index"] for s in slabs]
-    if (len(slabs) != 4 or any(len(d) != 1 for d in own)
-            or len({str(d[0]) for d in own}) != 4
-            or any(s["launch"] != set(s["index"]) for s in slabs)):
+            f"on {sorted(s['launch'], key=str)}, first launch at event "
+            f"{s['first']} (first emit at {first_emit})")
+    if (len(slabs) != 4 or stray
+            or any(s["launch"] != set(s["index"]) for s in slabs.values())):
         fail("four-chips: the slabs did not each run on their own device")
+    if first_emit is not None and any(s["first"] > first_emit
+                                      for s in slabs.values()):
+        fail("four-chips: a slab's first launch waited for another slab's "
+             "emit (the slabs did not run at once)")
     t0 = time.perf_counter()
     with jax.default_device(jax.devices()[0]):
         want = self_join(pts, EPS_2D, distance_impl="fused")

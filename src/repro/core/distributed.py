@@ -24,7 +24,8 @@ Two join paths share that decomposition:
 
 ``distributed_self_join`` -- the fused pair join: per slab, the SAME fast
 path as the single-device join (merged-range sweep, occupancy buckets,
-single-pass count -> fill; ``selfjoin._self_join_fused``) restricted to
+single-pass count -> fill; ``selfjoin._self_join_fused_stages``, the
+slabs driven in step so the chips work at once) restricted to
 owned query rows, with GLOBAL point ids riding a kernel pad lane
 (``gid_pairs``) so the UNICOMP intra-cell tie-break is device-independent.
 Emits (K, 2) global-id pairs bit-identical to
@@ -52,14 +53,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import grid as grid_lib
-from repro.core.grid import (build_grid_with_geometry,
-                             build_grid_with_geometry_jit, device_key_dtype,
-                             host_grid_geometry, offset_deltas)
+from repro.core.grid import (GridIndex, build_grid_with_geometry,
+                             device_key_dtype, host_grid_geometry,
+                             offset_deltas)
 from repro.core.selfjoin import (_distance_hits_jnp, _gather_batch,
-                                 _neighbor_ranks_for_delta, _sort_pairs)
+                                 _neighbor_ranks_for_delta,
+                                 _self_join_count_fused,
+                                 _self_join_fused_stages, _sort_pairs)
 from repro.core.stencil import stencil_offsets
 
 
@@ -164,10 +168,17 @@ def _halo_exchange(x, valid, axis, n_dev, direction, hops: int = 1):
 
 
 def _pack_mask(coords, gids, mask, capacity):
-    """Select masked rows into ``capacity`` slots (validity-flagged)."""
-    order = jnp.argsort(~mask, stable=True)             # masked rows first
-    take = order[:capacity]
-    sent = jnp.take(mask, take)
+    """Select masked rows, in order, into ``capacity`` slots
+    (validity-flagged). Row i scatters to slot (masked rows before i), so
+    the indices never decrease and the compaction needs no sort (an
+    argsort of a slab takes ~20 s to compile for the TPU); unmasked rows
+    carry -1 and combine by max, and slots left empty stay -1."""
+    rows = jnp.arange(mask.shape[0], dtype=jnp.int32)
+    slot = jnp.cumsum(mask) - mask
+    take = jnp.full((capacity,), -1, jnp.int32).at[slot].max(
+        jnp.where(mask, rows, -1), mode="drop", indices_are_sorted=True)
+    sent = take >= 0
+    take = jnp.maximum(take, 0)
     overflow = mask.sum() > capacity
     return coords[take], gids[take], sent, overflow
 
@@ -189,8 +200,7 @@ def _assemble_candidates(coords, gids, eps, *, cfg: "DistJoinConfig",
     received parcels (validity-flagged; overflow against the H-slot parcel
     capacity is detected, never silent). Invalid parcel slots carry the
     slab's anchor coordinate -- harmless for consumers that mask validity;
-    the pair path overwrites them host-side with out-of-volume sentinels
-    before building its grid.
+    the pair path's build step gives them out-of-volume coordinates.
     """
     slab = cfg.slab_axis
     P_loc, H = cfg.pts_per_device, cfg.halo_capacity
@@ -416,10 +426,10 @@ def distributed_self_join_count(
 # count -> fill -- run per slab over the (local + halo) candidate set.
 # ---------------------------------------------------------------------------
 
-# Per-slab grid builds against the global geometry go through THE shared
-# jitted device builder (grid.build_grid_with_geometry_jit): one executable
-# per (slab shape, key dtype); slab blocks share one shape by construction,
-# and the serving build path reuses the same executable.
+# The slab grids build against the global geometry in ONE shard_map
+# program over the mesh (build_slab_grids), not one single-device
+# program per chip: a single-device executable is compiled for its device,
+# so a build per chip would compile the build's large sort once per chip.
 
 _HALO_STEPS: dict = {}
 
@@ -465,6 +475,39 @@ def make_halo_step(mesh: Mesh, cfg: DistJoinConfig):
     )
     _HALO_STEPS[key] = (step, in_shardings)
     return step, in_shardings
+
+
+@partial(jax.jit, static_argnames=("mesh", "slab_axis", "key_dtype"))
+def build_slab_grids(cand_c, cand_v, cand_g, cand_o, eps, gmin, dims, far,
+                     *, mesh: Mesh, slab_axis: str, key_dtype):
+    """The slab grid builds as one ``shard_map`` program: each device
+    builds its slab's grid from its candidate block, against the global
+    geometry (``build_grid_with_geometry``, as ``build_grid``). One
+    program over the mesh compiles once, where a single-device build per
+    chip would compile once per chip.
+
+    Takes the halo step's blocks (sharded over the slab axis) and the
+    replicated geometry, with ``far`` the coordinates that invalid slots
+    take (far outside the volume). Returns the grid fields ``order``,
+    ``points_sorted``, ``cell_keys``, ``cell_start``, ``cell_count``,
+    ``point_cell_rank`` (each slab's block of rows), ``num_cells`` and
+    ``max_per_cell`` (one row per slab), and the slab's global ids and
+    owned-and-valid flags in sorted order."""
+    from repro.compat import shard_map
+
+    def build_fn(cand_c, cand_v, cand_g, cand_o, eps, gmin, dims, far):
+        cc = jnp.where(cand_v[:, None], cand_c, far)
+        index = build_grid_with_geometry(cc, eps, gmin, dims, cand_v,
+                                         key_dtype=key_dtype)
+        return (index.order, index.points_sorted, index.cell_keys,
+                index.cell_start, index.cell_count, index.point_cell_rank,
+                index.num_cells[None], index.max_per_cell[None],
+                cand_g[index.order], (cand_o & cand_v)[index.order])
+
+    rows = P(slab_axis)
+    return shard_map(build_fn, mesh=mesh, in_specs=(rows,) * 4 + (P(),) * 4,
+                     out_specs=(rows,) * 10, check_vma=False)(
+        cand_c, cand_v, cand_g, cand_o, eps, gmin, dims, far)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -607,9 +650,21 @@ def distributed_self_join(
     (``exact_halo_capacity``), making overflow impossible; a smaller
     explicit capacity is CHECKED on-device and raises instead of silently
     dropping candidates.
+
+    The slab grids build in one ``shard_map`` program over the mesh
+    (``build_slab_grids``), so it compiles once and not once per chip.
+    The pairs path then runs the slabs at once (DESIGN.md S3): each slab's
+    join is a generator that yields before every host wait on a device
+    value, and ``drive_stages`` advances them round-robin on the calling
+    thread, so each chip has its work queued while the host waits on
+    another. The count path (``return_pairs=False``) is not staged: its
+    slabs run one after another. The driver is single-threaded by design:
+    program spans are read on the thread of the benchmark window, and the
+    step caches and sanitize checks are module globals. Spans: one
+    ``selfjoin.join`` (``slabs``) around the call, ``selfjoin.partition``,
+    ``selfjoin.halo`` (``halo_rows`` shipped), the slabs' own
+    ``selfjoin.*`` spans, and ``selfjoin.sort`` for the global sort.
     """
-    from repro.core.selfjoin import (_self_join_count_fused,
-                                     _self_join_fused)
     from repro.kernels.fused_join import NP_PAD, resolve_merge_last_dim
 
     # cosine canonicalizes at entry (unit rows + reduced L2 threshold,
@@ -636,42 +691,65 @@ def distributed_self_join(
     merged = resolve_merge_last_dim(n, merge_last_dim, extra_lanes=1)
     slab_axis = mesh.axis_names[0]
     n_slabs = mesh.shape[slab_axis]
-    coords, gids, _ = partition_points_host(pts, n_slabs)
-    mins, maxs = slab_extents(coords, gids)
-    k_hops = halo_reach(mins, maxs, eps)
-    h_need = exact_halo_capacity(coords, gids, mins, maxs, eps, k_hops)
-    # default capacity rounds up to a power of two (capped at the slab
-    # size): the halo step is cached per (mesh, cfg), and the exact
-    # requirement is data-dependent -- same-shaped workloads with fresh
-    # data would otherwise miss the cache and re-trace every call (and
-    # leak one executable per distinct capacity)
-    h_default = min(_next_pow2(h_need), coords.shape[1])
-    cfg = DistJoinConfig(
-        pts_per_device=coords.shape[1],
-        n_dims=n,
-        halo_capacity=(h_default if halo_capacity is None
-                       else int(halo_capacity)),
-        max_per_cell=0,                  # per-slab grids: no global C bound
-        unicomp=unicomp,
-        slab_axis=slab_axis,
-        model_axis=None,
-        k_hops=k_hops,
-    )
+    with span("selfjoin.join") as join_span:
+        join_span.set_metadata(slabs=n_slabs)
+        with span("selfjoin.partition"):
+            coords, gids, _ = partition_points_host(pts, n_slabs)
+            mins, maxs = slab_extents(coords, gids)
+            k_hops = halo_reach(mins, maxs, eps)
+            plan = halo_capacity_plan(coords, gids, mins, maxs, eps, k_hops)
+            worst = worst_halo_parcel(plan)
+            h_need = worst.need if worst is not None else 1
+        # default capacity rounds up to a power of two (capped at the slab
+        # size): the halo step is cached per (mesh, cfg), and the exact
+        # requirement is data-dependent -- same-shaped workloads with fresh
+        # data would otherwise miss the cache and re-trace every call (and
+        # leak one executable per distinct capacity)
+        h_default = min(_next_pow2(h_need), coords.shape[1])
+        cfg = DistJoinConfig(
+            pts_per_device=coords.shape[1],
+            n_dims=n,
+            halo_capacity=(h_default if halo_capacity is None
+                           else int(halo_capacity)),
+            max_per_cell=0,              # per-slab grids: no global C bound
+            unicomp=unicomp,
+            slab_axis=slab_axis,
+            model_axis=None,
+            k_hops=k_hops,
+        )
+        with span("selfjoin.halo") as halo_span:
+            halo_span.set_metadata(halo_rows=sum(p.need for p in plan))
+            slabs = _exchange_halos(mesh, cfg, coords, gids, pts, eps, plan)
+        parts = drive_stages(
+            [_slab_stages(index, ids, owned, return_pairs=return_pairs,
+                          unicomp=unicomp, method=method, emit=emit,
+                          bucketed=bucketed, merged=merged)
+             for index, ids, owned in slabs],
+            [index.points_sorted.device for index, _, _ in slabs])
+        if not return_pairs:
+            return sum(parts)
+        out = np.concatenate(parts, axis=0) if parts else empty
+        if sort_result:
+            with span("selfjoin.sort"):
+                out = _sort_pairs(out)
+        return out
+
+
+def _exchange_halos(mesh: Mesh, cfg: DistJoinConfig, coords, gids, pts,
+                    eps, plan):
+    """The ``shard_map`` halo step, the slab grid builds it feeds (one
+    program over the mesh, ``build_slab_grids``) and the halo
+    overflow check; the candidates stay on the devices. Returns, per
+    slab, (its grid, its global ids and its owned rows in sorted order),
+    each on the slab's device: the slab axis is the mesh's first, so row
+    k of the device grid holds slab k."""
+    n = pts.shape[1]
+    n_slabs = coords.shape[0]
     step, in_sh = make_halo_step(mesh, cfg)
     coords_dev = jax.device_put(coords.reshape(-1, n), in_sh[0])
     gids_dev = jax.device_put(gids.reshape(-1), in_sh[1])
     cand_c, cand_g, cand_v, cand_o, halo_of = step(
         coords_dev, gids_dev, jnp.asarray(eps, pts.dtype))
-    if int(halo_of):
-        raise _halo_overflow_error(
-            cfg.halo_capacity,
-            halo_capacity_plan(coords, gids, mins, maxs, eps, k_hops))
-    pc = cfg.pts_per_device + 2 * cfg.halo_capacity * k_hops
-    cand_c = np.asarray(cand_c).reshape(n_slabs, pc, n)
-    cand_g = np.asarray(cand_g).reshape(n_slabs, pc)
-    cand_v = np.asarray(cand_v).reshape(n_slabs, pc)
-    cand_o = np.asarray(cand_o).reshape(n_slabs, pc)
-
     # global geometry, EXACTLY as build_grid_host derives it (the one shared
     # numpy copy): cell coords (and the UNICOMP cell-pair ownership) agree
     # across slabs AND with the single-device join
@@ -684,43 +762,67 @@ def distributed_self_join(
     # window that reaches the sentinel cell (a top-corner stencil probe can
     # alias its key) evaluates no spurious hits
     far = gmax + 4.0 * max(float(eps), 1.0)
-    gmin_dev = jnp.asarray(gmin)
-    dims_dev = jnp.asarray(dims)
-    eps_dev = jnp.asarray(eps, pts.dtype)
+    eps_np = np.asarray(eps, pts.dtype)
+    built = build_slab_grids(cand_c, cand_v, cand_g, cand_o, eps_np, gmin,
+                             dims, far, mesh=mesh, slab_axis=cfg.slab_axis,
+                             key_dtype=np.dtype(slab_kd))
+    if int(halo_of):
+        raise _halo_overflow_error(cfg.halo_capacity, plan)
+    devices = np.asarray(mesh.devices).reshape(n_slabs, -1)[:, 0]
+    blocks = [{s.device: s.data for s in x.addressable_shards}
+              for x in built]
+    slabs = []
+    for dev in devices:
+        (order, points_sorted, cell_keys, cell_start, cell_count, rank,
+         ncells, max_per_cell, ids, owned) = (b[dev] for b in blocks)
+        index = GridIndex(
+            grid_min=jax.device_put(gmin, dev),
+            eps=jax.device_put(eps_np, dev),
+            dims=jax.device_put(dims, dev),
+            order=order, points_sorted=points_sorted, cell_keys=cell_keys,
+            cell_start=cell_start, cell_count=cell_count,
+            point_cell_rank=rank, num_cells=ncells.reshape(()),
+            max_per_cell=max_per_cell.reshape(()))
+        slabs.append((index, ids, owned))
+    return slabs
 
-    # each slab builds and joins on ITS mesh device: the slab axis is the
-    # mesh's first, so row k of the device grid holds slab k
-    slab_devices = np.asarray(mesh.devices).reshape(n_slabs, -1)[:, 0]
-    chunks = []
-    total = 0
-    for k in range(n_slabs):
-        v = cand_v[k]
-        o = cand_o[k] & v
-        if not o.any():
-            continue
-        cc = cand_c[k].copy()
-        cc[~v] = far
-        with jax.default_device(slab_devices[k]):
-            index = build_grid_with_geometry_jit(
-                jnp.asarray(cc), eps_dev, gmin_dev, dims_dev,
-                jnp.asarray(v), key_dtype=slab_kd)
-            order = np.asarray(index.order)
-            gid_sorted = cand_g[k][order]
-            owned_sorted = o[order]
-            if return_pairs:
-                chunks.append(_self_join_fused(
-                    index, unicomp=unicomp, sort_result=False,
-                    method=method, emit=emit, bucketed=bucketed,
-                    merged=merged, row_ok=owned_sorted, ids=gid_sorted,
-                    gid_pairs=True))
-            else:
-                total += _self_join_count_fused(
-                    index, unicomp=unicomp, method=method,
-                    bucketed=bucketed, merged=merged, row_ok=owned_sorted,
-                    ids=gid_sorted, gid_pairs=True).total_pairs
+
+def _slab_stages(index: GridIndex, ids, owned, *, return_pairs: bool,
+                 unicomp: bool, merged: bool, method, emit, bucketed):
+    """One slab's fused join of the rows it owns, as stages for
+    ``drive_stages`` (run with the slab's device as the default device);
+    returns its (global-id) pairs, or its ordered-pair count. Only the
+    pairs path is staged: the count sweep runs to its end in one turn."""
+    with span("selfjoin.sync"):
+        ids, row_ok = np.asarray(ids), np.asarray(owned)
+    if not row_ok.any():
+        return np.empty((0, 2), np.int32) if return_pairs else 0
     if not return_pairs:
-        return total
-    out = np.concatenate(chunks, axis=0) if chunks else empty
-    if sort_result:
-        out = _sort_pairs(out)
+        return _self_join_count_fused(
+            index, unicomp=unicomp, method=method, bucketed=bucketed,
+            merged=merged, row_ok=row_ok, ids=ids,
+            gid_pairs=True).total_pairs
+    return (yield from _self_join_fused_stages(
+        index, unicomp=unicomp, sort_result=False, method=method, emit=emit,
+        bucketed=bucketed, merged=merged, row_ok=row_ok, ids=ids,
+        gid_pairs=True))
+
+
+def drive_stages(stages: list, devices: list) -> list:
+    """Run the slabs' stage generators (each yields before a host wait on
+    a device value) to their ends, round-robin on the calling thread:
+    each turn advances one stage to its next wait, so every slab has its
+    device work queued before the host blocks on any one of them.
+    ``devices[k]`` is the default device while stage k runs. Returns each
+    stage's return value, in order."""
+    out = [None] * len(stages)
+    live = list(range(len(stages)))
+    while live:
+        for k in list(live):
+            with jax.default_device(devices[k]):
+                try:
+                    next(stages[k])
+                except StopIteration as stop:
+                    out[k] = stop.value
+                    live.remove(k)
     return out

@@ -1021,6 +1021,11 @@ def cell_window_caps(index: GridIndex, merged: bool = False) -> np.ndarray:
     host sync that fixes the static bucket-capacity classes. Bit-equal to
     ``cell_window_caps_host``.
     """
+    return _fetch_window_caps(index, _launch_window_caps(index, merged))
+
+
+def _launch_window_caps(index: GridIndex, merged: bool) -> jax.Array:
+    """Dispatch ``cell_window_caps``' device sweep; no wait."""
     from repro.core.stencil import merged_stencil_offsets, stencil_offsets
 
     strides = np.asarray(row_major_strides(index.dims))
@@ -1030,8 +1035,11 @@ def cell_window_caps(index: GridIndex, merged: bool = False) -> np.ndarray:
     else:
         deltas = stencil_offsets(index.n_dims, unicomp=False) @ strides
     kd = np.dtype(index.cell_keys.dtype)
-    caps = _cell_window_caps_device(
+    return _cell_window_caps_device(
         index, jnp.asarray(deltas.astype(kd)), merged=merged)
+
+
+def _fetch_window_caps(index: GridIndex, caps: jax.Array) -> np.ndarray:
     ncells = int(index.num_cells)
     return np.asarray(caps)[:ncells].astype(np.int32)
 
@@ -1108,7 +1116,17 @@ def cell_window_caps_cached(index: GridIndex,
     feed both ``global_window_cap`` and the occupancy plan build, and a
     6-D pass is 3^(n-1) host searchsorted sweeps worth not repeating."""
     return index_cached(index, f"cellcaps/{merged}",
-                        lambda: cell_window_caps(index, merged=merged))
+                        lambda: _fetch_window_caps(
+                            index, dispatch_window_caps(index, merged)))
+
+
+def dispatch_window_caps(index: GridIndex, merged: bool = False
+                         ) -> jax.Array:
+    """The device sweep behind ``cell_window_caps_cached``, dispatched
+    once per index and not waited on: a driver queues it before it waits
+    on other devices, and the cached fetch then finds it under way."""
+    return index_cached(index, f"cellcaps_dev/{merged}",
+                        lambda: _launch_window_caps(index, merged))
 
 
 def global_window_cap(index: GridIndex, merged: bool = False,

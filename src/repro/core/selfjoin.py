@@ -411,14 +411,23 @@ def _fused_pad(index: GridIndex, *, q_size: int, c: int,
     rides the per-point global id in the next free lane. ``feats``
     (metric feature payload in SORTED point order, DESIGN.md S12) rides
     immediately after the coordinate lanes."""
+    qp = _round_up(max(q_size, 1), tq)
+    tail = max(c, q_start_max + qp - index.num_points)
+    return _pad_sweep_points(index, gid, feats, tail=tail,
+                             merged=merged), qp
+
+
+@partial(jax.jit, static_argnames=("tail", "merged"))
+def _pad_sweep_points(index: GridIndex, gid, feats, *, tail: int,
+                      merged: bool):
+    """``_fused_pad``'s copy as one program: run eagerly, its dozen small
+    ops compiled one by one, per device."""
     from repro.core.grid import point_last_coords
     from repro.kernels.fused_join import pad_points
 
-    qp = _round_up(max(q_size, 1), tq)
-    tail = max(c, q_start_max + qp - index.num_points)
     lc = point_last_coords(index) if merged else None
-    return pad_points(index.points_sorted, tail, last_coord=lc,
-                      gid=gid, feats=feats), qp
+    return pad_points(index.points_sorted, tail, last_coord=lc, gid=gid,
+                      feats=feats)
 
 
 def _host_cell_ranks(index: GridIndex) -> np.ndarray:
@@ -750,17 +759,7 @@ def _join_run_loop(index: GridIndex) -> bool:
     return index.num_points >= 2 * max(int(index.num_cells), 1)
 
 
-def _self_join_fused(index: GridIndex, *, unicomp: bool, sort_result: bool,
-                     n_batches: int = 1, method: Optional[str] = None,
-                     emit: Optional[str] = None,
-                     bucketed: Optional[bool] = None,
-                     merged: bool = True,
-                     row_ok: Optional[np.ndarray] = None,
-                     ids: Optional[np.ndarray] = None,
-                     gid_pairs: bool = False,
-                     run_loop: Optional[bool] = None,
-                     metric: str = "l2", n_feat: int = 0,
-                     feats=None, refine_eps=None):
+def _self_join_fused(index: GridIndex, **kw):
     """Single-pass count -> fill driver for distance_impl='fused'.
 
     Per launch (an occupancy bucket chunk, or a contiguous batch when the
@@ -807,95 +806,139 @@ def _self_join_fused(index: GridIndex, *, unicomp: bool, sort_result: bool,
     ``selfjoin.launch``, every host wait on a device program under
     ``selfjoin.sync``, the fill under ``selfjoin.emit`` and the result
     sort under ``selfjoin.sort``.
+
+    The work itself is ``_self_join_fused_stages``, driven to its end
+    here; the distributed slab join drives one per slab in step.
     """
+    stages = _self_join_fused_stages(index, **kw)
     with span("selfjoin.join"):
-        if emit is None:
-            emit = "device" if jax.default_backend() == "tpu" else "host"
-        if run_loop is None:
-            with span("selfjoin.sync"):
-                run_loop = _join_run_loop(index)
-        if merged:
-            deltas, is_zero = _merged_offset_tables(index, unicomp)
-        else:
-            deltas, is_zero = _offset_tables(index, unicomp)
-        npts = index.num_points
+        while True:
+            try:
+                next(stages)
+            except StopIteration as stop:
+                return stop.value
+
+
+def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
+                            sort_result: bool, n_batches: int = 1,
+                            method: Optional[str] = None,
+                            emit: Optional[str] = None,
+                            bucketed: Optional[bool] = None,
+                            merged: bool = True,
+                            row_ok: Optional[np.ndarray] = None,
+                            ids: Optional[np.ndarray] = None,
+                            gid_pairs: bool = False,
+                            run_loop: Optional[bool] = None,
+                            metric: str = "l2", n_feat: int = 0,
+                            feats=None, refine_eps=None):
+    """``_self_join_fused`` as a generator that yields just before each
+    host wait on a device value (the run-loop decision, the order fetch,
+    the window-capacity fetch, each launch's counts sum, each emit's count
+    check and pairs fetch) and returns the pairs. Driven to its end
+    (``_self_join_fused``) it dispatches and waits in the one-chip join's
+    order. It never yields inside one of its spans, so that the spans of
+    stages driven in step on one thread still nest; the caller opens the
+    ``selfjoin.join`` span around them."""
+    from repro.core.grid import dispatch_window_caps
+
+    if emit is None:
+        emit = "device" if jax.default_backend() == "tpu" else "host"
+    if run_loop is None:
+        yield
         with span("selfjoin.sync"):
-            order_np = (np.asarray(index.order) if ids is None
-                        else np.asarray(ids))
-        ids_dev = index.order if ids is None else jnp.asarray(
-            np.asarray(ids).astype(np.int32))
-        gid = jnp.asarray(order_np.astype(np.int32)) if gid_pairs else None
-        mult = 2 if unicomp else 1
-        with span("selfjoin.plan") as sp:
-            launches, points_pad, _ = _fused_launches(
-                index, n_batches=n_batches, bucketed=bucketed,
-                merged=merged, row_ok=row_ok, gid=gid, feats=feats)
-            sp.set_metadata(launches=len(launches))
-        single = len(launches) == 1
+            run_loop = _join_run_loop(index)
+    if merged:
+        deltas, is_zero = _merged_offset_tables(index, unicomp)
+    else:
+        deltas, is_zero = _offset_tables(index, unicomp)
+    npts = index.num_points
+    if ids is None:
+        yield
+    with span("selfjoin.sync"):
+        order_np = (np.asarray(index.order) if ids is None
+                    else np.asarray(ids))
+    ids_dev = index.order if ids is None else jnp.asarray(
+        np.asarray(ids).astype(np.int32))
+    gid = jnp.asarray(order_np.astype(np.int32)) if gid_pairs else None
+    mult = 2 if unicomp else 1
+    if merged:
+        # the schedule below waits on the caps planner: queue it first
+        dispatch_window_caps(index, merged=True)
+    yield
+    with span("selfjoin.plan") as sp:
+        launches, points_pad, _ = _fused_launches(
+            index, n_batches=n_batches, bucketed=bucketed,
+            merged=merged, row_ok=row_ok, gid=gid, feats=feats)
+        sp.set_metadata(launches=len(launches))
+    single = len(launches) == 1
 
-        def finish(run):
-            """Drain one launch: blocks on ITS buffers only, so the next
-            launch's kernel (already dispatched, JAX async) overlaps the
-            transfer -- the paper's SV-A compute/copy overlap, kept on the
-            fused path."""
-            ws, hits, counts, base, q_pos, cap, tile = run
-            with span("selfjoin.sync"):
-                ordered = mult * int(counts.sum(dtype=jnp.int64))
-            if emit == "host":
-                with span("selfjoin.emit"):
-                    pairs = _emit_from_hits_host(
-                        order_np, hits, ws, np.asarray(q_pos), npts, unicomp)
-                assert pairs.shape[0] == ordered
-                return pairs
-            capacity = max(ordered if single else _next_pow2(ordered), 1)
+    def finish(run):
+        """Drain one launch: blocks on ITS buffers only, so the next
+        launch's kernel (already dispatched, JAX async) overlaps the
+        transfer -- the paper's SV-A compute/copy overlap, kept on the
+        fused path."""
+        ws, hits, counts, base, q_pos, cap, tile = run
+        total = counts.sum(dtype=jnp.int64)
+        yield
+        with span("selfjoin.sync"):
+            ordered = mult * int(total)
+        if emit == "host":
             with span("selfjoin.emit"):
-                keys, vals, cnt = _emit_from_hits(
-                    index, ids_dev, hits, counts, base, ws, q_pos,
-                    c=cap, tq=tile, unicomp=unicomp, capacity=capacity)
-                with span("selfjoin.sync"):
-                    assert int(cnt) == ordered, (int(cnt), ordered)
-                    keys, vals = (np.asarray(keys)[:ordered],
-                                  np.asarray(vals)[:ordered])
-                return np.stack([keys, vals], axis=1)
+                pairs = _emit_from_hits_host(
+                    order_np, hits, ws, np.asarray(q_pos), npts, unicomp)
+            assert pairs.shape[0] == ordered
+            return pairs
+        capacity = max(ordered if single else _next_pow2(ordered), 1)
+        with span("selfjoin.emit"):
+            keys, vals, cnt = _emit_from_hits(
+                index, ids_dev, hits, counts, base, ws, q_pos,
+                c=cap, tq=tile, unicomp=unicomp, capacity=capacity)
+        yield
+        with span("selfjoin.emit"):
+            with span("selfjoin.sync"):
+                assert int(cnt) == ordered, (int(cnt), ordered)
+                keys, vals = (np.asarray(keys)[:ordered],
+                              np.asarray(vals)[:ordered])
+            return np.stack([keys, vals], axis=1)
 
-        chunks = []
-        prev = None
-        for sel, q_start, q_size, qp, cap, tile in launches:
-            plan = None
-            if run_loop:
-                with span("selfjoin.run_plan"):
-                    plan = _launch_run_plan(index, sel, q_start, qp=qp,
-                                            tile=tile)
-            with span("selfjoin.launch"):
-                if sel is None:
-                    ws, _, _, hits, counts, base, q_pos = _fused_batch_run(
-                        index, points_pad, deltas, is_zero, q_start, qp=qp,
-                        q_size=q_size, c=cap, unicomp=unicomp,
-                        keep_hits=True, method=method, tq=tile,
-                        merged=merged, gid_pairs=gid_pairs, run_plan=plan,
-                        metric=metric, n_feat=n_feat, refine_eps=refine_eps)
-                else:
-                    ws, _, _, hits, counts, base, q_pos = \
-                        _fused_bucket_launch(
-                            index, points_pad, deltas, is_zero, sel, qp=qp,
-                            c=cap, unicomp=unicomp, keep_hits=True,
-                            method=method, tq=tile, merged=merged,
-                            gid_pairs=gid_pairs, run_plan=plan,
-                            metric=metric, n_feat=n_feat,
-                            refine_eps=refine_eps)
-            if prev is not None:
-                chunks.append(finish(prev))
-            prev = (ws, hits, counts, base, q_pos, cap, tile)
+    chunks = []
+    prev = None
+    for sel, q_start, q_size, qp, cap, tile in launches:
+        plan = None
+        if run_loop:
+            with span("selfjoin.run_plan"):
+                plan = _launch_run_plan(index, sel, q_start, qp=qp,
+                                        tile=tile)
+        with span("selfjoin.launch"):
+            if sel is None:
+                ws, _, _, hits, counts, base, q_pos = _fused_batch_run(
+                    index, points_pad, deltas, is_zero, q_start, qp=qp,
+                    q_size=q_size, c=cap, unicomp=unicomp,
+                    keep_hits=True, method=method, tq=tile,
+                    merged=merged, gid_pairs=gid_pairs, run_plan=plan,
+                    metric=metric, n_feat=n_feat, refine_eps=refine_eps)
+            else:
+                ws, _, _, hits, counts, base, q_pos = \
+                    _fused_bucket_launch(
+                        index, points_pad, deltas, is_zero, sel, qp=qp,
+                        c=cap, unicomp=unicomp, keep_hits=True,
+                        method=method, tq=tile, merged=merged,
+                        gid_pairs=gid_pairs, run_plan=plan,
+                        metric=metric, n_feat=n_feat,
+                        refine_eps=refine_eps)
         if prev is not None:
-            chunks.append(finish(prev))
-        from repro.analysis import sanitize
-        sanitize.raise_pending()   # REPRO_SANITIZE: launches already drained
-        out = (np.concatenate(chunks, axis=0) if chunks
-               else np.empty((0, 2), np.int32))
-        if sort_result:
-            with span("selfjoin.sort"):
-                out = _sort_pairs(out)
-        return out
+            chunks.append((yield from finish(prev)))
+        prev = (ws, hits, counts, base, q_pos, cap, tile)
+    if prev is not None:
+        chunks.append((yield from finish(prev)))
+    from repro.analysis import sanitize
+    sanitize.raise_pending()   # REPRO_SANITIZE: launches already drained
+    out = (np.concatenate(chunks, axis=0) if chunks
+           else np.empty((0, 2), np.int32))
+    if sort_result:
+        with span("selfjoin.sort"):
+            out = _sort_pairs(out)
+    return out
 
 
 def _self_join_count_fused(index: GridIndex, *, unicomp: bool,

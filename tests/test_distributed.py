@@ -67,6 +67,176 @@ def test_distributed_smoke_two_devices():
     assert "OK" in out
 
 
+def test_overlapped_slabs_match_single_device_four_slabs():
+    """Tier-1: at 4 slabs, with the slabs' stages driven in step, the
+    pairs are bit-identical to the single-device fused join, UNICOMP on
+    and off, on uniform and clustered points."""
+    out = run_sub(textwrap.dedent("""
+        import numpy as np
+        from repro.core.distributed import distributed_self_join
+        from repro.core.selfjoin import self_join
+        from repro.launch.mesh import make_slab_mesh
+        rng = np.random.default_rng(7)
+        uni = rng.uniform(0, 12, size=(1200, 2))
+        k = rng.integers(0, 5, 1200)
+        clus = rng.uniform(0, 12, (5, 2))[k] + rng.normal(0, 0.4, (1200, 2))
+        mesh = make_slab_mesh(4)
+        for name, pts, eps in (('uniform', uni, 0.6),
+                               ('clustered', clus, 0.2)):
+            for unicomp in (True, False):
+                ref = self_join(pts, eps, unicomp=unicomp,
+                                distance_impl='fused')
+                got = distributed_self_join(pts, eps, mesh, unicomp=unicomp)
+                assert np.array_equal(got, ref), (name, unicomp, got.shape,
+                                                  ref.shape)
+        print('OK')
+    """), devices=4)
+    assert "OK" in out
+
+
+def test_every_slab_launches_before_any_pairs_fetch():
+    """Tier-1: a dispatch recorder sees each of the 4 slabs' first fused
+    launch, each on its own device, before the first fetch of any slab's
+    pairs (host emit: its hit-set fetch; device emit: the emit that the
+    pairs fetch waits on), and the spans of the whole join nest."""
+    out = run_sub(textwrap.dedent("""
+        import numpy as np
+        from repro.core import distributed, selfjoin
+        from repro.kernels import ops
+        from repro.launch.mesh import make_slab_mesh
+        events, stack = [], []
+        launch = ops.fused_join_hits
+        real_span = selfjoin.span
+
+        def launch_rec(*a, **kw):
+            out = launch(*a, **kw)
+            events.append(('launch', str(list(out[1].devices())[0])))
+            return out
+
+        def fetch_rec(real):
+            def f(*a, **kw):
+                events.append(('fetch', None))
+                return real(*a, **kw)
+            return f
+
+        class span_rec:
+            def __init__(self, name, **kw):
+                self.name, self.inner = name, real_span(name, **kw)
+            def __enter__(self):
+                stack.append(self.name)
+                return self.inner.__enter__()
+            def __exit__(self, *a):
+                assert stack.pop() == self.name, 'spans do not nest'
+                return self.inner.__exit__(*a)
+
+        ops.fused_join_hits = launch_rec
+        selfjoin._emit_from_hits_host = fetch_rec(
+            selfjoin._emit_from_hits_host)
+        selfjoin._emit_from_hits = fetch_rec(selfjoin._emit_from_hits)
+        selfjoin.span = distributed.span = span_rec
+        rng = np.random.default_rng(2)
+        pts = rng.uniform(0, 12, size=(1600, 2))
+        mesh = make_slab_mesh(4)
+        for emit in ('host', 'device'):
+            events.clear()
+            distributed.distributed_self_join(pts, 0.6, mesh, emit=emit)
+            assert not stack
+            first = {}
+            for i, (kind, dev) in enumerate(events):
+                if kind == 'launch':
+                    first.setdefault(dev, i)
+            fetch = min(i for i, (kind, _) in enumerate(events)
+                        if kind == 'fetch')
+            assert len(first) == 4, (emit, first)
+            assert max(first.values()) < fetch, (emit, first, fetch)
+        print('OK')
+    """), devices=4)
+    assert "OK" in out
+
+
+@pytest.mark.parametrize("capacity", [64, 250, 400])
+def test_pack_mask_keeps_masked_rows_in_order(capacity):
+    """Tier-1, one device: the sort-free halo parcel packing selects the
+    masked rows in row order into ``capacity`` slots, as a stable argsort
+    of the mask would, flags exactly the filled slots, and reports an
+    overflow only past the capacity (under, near and over it here)."""
+    import jax.numpy as jnp
+
+    from repro.core.distributed import _pack_mask
+
+    rng = np.random.default_rng(capacity)
+    coords = rng.uniform(0, 1, (500, 2))
+    gids = rng.permutation(500).astype(np.int32)
+    mask = rng.uniform(0, 1, 500) < 0.5
+    c, g, sent, over = _pack_mask(jnp.asarray(coords), jnp.asarray(gids),
+                                  jnp.asarray(mask), capacity)
+    rows = np.flatnonzero(mask)[:capacity]
+    sent = np.asarray(sent)
+    assert sent.shape == (capacity,)
+    assert sent.sum() == rows.size and sent[:rows.size].all()
+    assert np.array_equal(np.asarray(c)[:rows.size], coords[rows])
+    assert np.array_equal(np.asarray(g)[:rows.size], gids[rows])
+    assert bool(over) == (mask.sum() > capacity)
+
+
+@pytest.mark.parametrize("emit", ["host", "device"])
+def test_staged_one_chip_join_keeps_its_order(monkeypatch, emit):
+    """Tier-1, one device: ``_self_join_fused`` driven through its stages
+    dispatches and waits as the one-chip join always has -- run-loop and
+    order fetches, the caps planner then its fetch, each launch
+    dispatched one ahead of the previous launch's counts fetch, emit and
+    pairs fetch -- and its pairs equal the brute force's."""
+    from repro.core import grid, selfjoin
+    from repro.core.brute import brute_force_join
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(3)
+    pts = np.concatenate([rng.uniform(0, 10, (1500, 2)),
+                          rng.normal(5, 0.3, (700, 2))])
+    index = grid.build_grid(pts, 0.3)
+    log = []
+    real_span = selfjoin.span
+
+    class span_rec:
+        def __init__(self, name, **kw):
+            self.name, self.inner = name, real_span(name, **kw)
+
+        def __enter__(self):
+            if self.name == "selfjoin.sync":
+                log.append("wait")
+            return self.inner.__enter__()
+
+        def __exit__(self, *a):
+            return self.inner.__exit__(*a)
+
+    def rec(real, tag):
+        def f(*a, **kw):
+            log.append(tag)
+            return real(*a, **kw)
+        return f
+
+    monkeypatch.setattr(selfjoin, "span", span_rec)
+    monkeypatch.setattr(ops, "fused_join_hits",
+                        rec(ops.fused_join_hits, "kernel"))
+    monkeypatch.setattr(selfjoin, "_emit_from_hits",
+                        rec(selfjoin._emit_from_hits, "emit"))
+    monkeypatch.setattr(grid, "_cell_window_caps_device",
+                        rec(grid._cell_window_caps_device, "caps"))
+    got = selfjoin._self_join_fused(index, unicomp=True, sort_result=True,
+                                    emit=emit)
+    monkeypatch.undo()
+    n = log.count("kernel")
+    assert n >= 2
+    drain = ["wait"] + (["emit", "wait"] if emit == "device" else [])
+    want = ["wait", "wait", "caps", "wait", "kernel"]
+    for _ in range(n - 1):
+        want += ["kernel"] + drain
+    want += drain
+    assert log == want, log
+    ref = brute_force_join(pts, 0.3)
+    assert np.array_equal(got, ref)
+
+
 @slow
 def test_distributed_pairs_parity_matrix():
     """Acceptance matrix: pair sets bit-identical to the single-device
