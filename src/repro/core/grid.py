@@ -795,7 +795,8 @@ def cell_run_plan(cell_of_row: np.ndarray, tq: int) -> RunPlan:
 
 
 @partial(jax.jit, static_argnames=("merged",))
-def _cell_window_table_device(index: GridIndex, deltas, *, merged: bool):
+def _cell_window_table_device(index: GridIndex, deltas, prefix, *,
+                              merged: bool):
     """Per-CELL window descriptor tables, shape (n_off, num_points).
 
     Column r holds the (win_start, win_count, win_cells) triple of cell
@@ -807,24 +808,36 @@ def _cell_window_table_device(index: GridIndex, deltas, *, merged: bool):
     and gathering per launch removes the per-launch searchsorted over
     (n_off x rows) -- the paper's duplicate-search removal (SIV-C) on the
     descriptor side, feeding the run-loop kernel's DMA-side dedup.
+
+    ``prefix`` (merged only): the index's dense prefix table
+    (``dense_prefix_table``), which answers the merged span's rank and
+    point bounds by one gather a side; None keeps the binary searches.
     """
     npts = index.num_points
     valid = jnp.arange(npts) < index.num_cells
     own_key = jnp.where(valid, index.cell_keys, 0)
     if merged:
-        dtab, lo_off, hi_off = deltas
-        dim_last = index.dims.astype(jnp.int64)[-1]
-        q_last = own_key % dim_last
-        base = own_key[None, :] + dtab[:, None]
-        lo = jnp.maximum(lo_off[:, None], -q_last[None, :])
-        hi = jnp.minimum(hi_off[:, None], dim_last - 1 - q_last[None, :])
-        lo_rank = jnp.searchsorted(index.cell_keys, base + lo,
-                                   side="left").astype(jnp.int32)
-        hi_rank = jnp.searchsorted(index.cell_keys, base + hi,
-                                   side="right").astype(jnp.int32)
+        # int32 probes on the table route (its volume is at most
+        # _LOOKUP_MAX_CELLS); the searches keep their int64 probes
+        kd = jnp.int64 if prefix is None else jnp.int32
+        dtab, lo_off, hi_off = jnp.asarray(deltas).astype(kd)
+        own = own_key.astype(kd)
+        dim_last = index.dims[-1].astype(kd)
+        q_last = own % dim_last
+        base = own[None, :] + dtab[:, None]
+        lo = base + jnp.maximum(lo_off[:, None], -q_last[None, :])
+        hi = base + jnp.minimum(hi_off[:, None],
+                                dim_last - 1 - q_last[None, :])
+        if prefix is None:
+            lo_rank = jnp.searchsorted(index.cell_keys, lo,
+                                       side="left").astype(jnp.int32)
+            hi_rank = jnp.searchsorted(index.cell_keys, hi,
+                                       side="right").astype(jnp.int32)
+            start = _rank_to_point(index, lo_rank)
+            end = _rank_to_point(index, hi_rank)
+        else:
+            (lo_rank, start), (hi_rank, end) = _prefix_span(prefix, lo, hi)
         live = (hi_rank > lo_rank) & valid[None, :]
-        start = _rank_to_point(index, lo_rank)
-        end = _rank_to_point(index, hi_rank)
         ws = jnp.where(live, start, 0).astype(jnp.int32)
         wc = jnp.where(live, end - start, 0).astype(jnp.int32)
         wcells = jnp.where(live, hi_rank - lo_rank, 0).astype(jnp.int32)
@@ -846,11 +859,104 @@ def cell_window_tables(index: GridIndex, deltas, *, merged: bool, tag):
     ``(dtab, lo_off, hi_off)`` triple (merged); ``tag`` disambiguates
     offset tables that share ``merged`` (the drivers pass the unicomp
     flag). Cached per index via ``index_cached`` so repeated sweeps and
-    the run-loop's steady state never recompute the searchsorted plane.
+    the run-loop's steady state never recompute the plane. Merged tables
+    read the dense prefix table where the grid's volume allows it.
     """
+    prefix = dense_prefix_table(index) if merged else None
     return index_cached(
         index, f"wintab/{bool(merged)}/{tag}",
-        lambda: _cell_window_table_device(index, deltas, merged=merged))
+        lambda: _cell_window_table_device(index, deltas, prefix,
+                                          merged=merged))
+
+
+# ---------------------------------------------------------------------------
+# Dense prefix table: where the grid's key space is small, the merged
+# window planners answer "how many non-empty cells (points) lie below key
+# k" by one gather from a table over every key, instead of a binary search
+# of B -- the trade ``selfjoin._sparse_lookup`` makes for the count path,
+# under the same volume budget.
+# ---------------------------------------------------------------------------
+
+# Dense-lookup budget: prod(dims) at or below this many cells (x4 bytes a
+# table column) buys the dense tables; beyond it, binary search (the
+# paper's trade) wins.
+_LOOKUP_MAX_CELLS = 1 << 23   # 32 MB
+
+
+def host_dims(index: GridIndex) -> np.ndarray:
+    """Host copy of ``index.dims``, cached per index: the one fetch the
+    planners take the grid's geometry (strides, volume) from."""
+    return index_cached(index, "dims_np", lambda: np.asarray(index.dims))
+
+
+def grid_volume(index: GridIndex) -> int:
+    """prod(dims), the size of the linear key space (exact python int)."""
+    volume = 1
+    for d in host_dims(index):
+        volume *= int(d)
+    return volume
+
+
+def prefix_table_size(index: GridIndex) -> int:
+    """Rows of the index's dense prefix table -- the power of two past
+    prod(dims) + 1, so that one compiled program serves every grid of
+    that size class -- or 0 where prod(dims) exceeds ``_LOOKUP_MAX_CELLS``
+    and the planners keep their binary searches."""
+    volume = grid_volume(index)
+    if volume > _LOOKUP_MAX_CELLS:
+        return 0
+    return 1 << (volume + 1).bit_length()
+
+
+def _prefix_table(index: GridIndex, size: int) -> jax.Array:
+    """The dense prefix table over keys [0, size) (traced; ``size`` >
+    prod(dims) + 1, from ``prefix_table_size``), (size, 2) int32 -- the
+    two columns side by side, so that one gather reads both:
+
+        [k, 0] -- number of non-empty cells with key < k, i.e. the left
+                  ``searchsorted`` rank of k in B;
+        [k, 1] -- number of points in those cells, i.e. that rank's
+                  ``_rank_to_point``.
+
+    One scatter of the present keys (sorted, so the scatter is in order)
+    and an exclusive cumsum. Every cell key lies in [0, prod(dims)]: real
+    cells below prod(dims), the out-of-set sentinel cell of a padded build
+    (``build_grid_with_geometry`` ``valid``) at it, so the table counts
+    the sentinel cell past prod(dims) exactly as the search does, and its
+    tail holds every cell.
+    """
+    n = index.cell_keys.shape[0]
+    live = jnp.arange(n, dtype=jnp.int32) < index.num_cells
+    slot = jnp.where(live, index.cell_keys, size).astype(jnp.int32)
+    present = jnp.zeros(size, jnp.int32).at[slot].set(
+        1, mode="drop", indices_are_sorted=True)
+    rank_lt = jnp.cumsum(present) - present
+    return jnp.stack([rank_lt, _rank_to_point(index, rank_lt)], axis=1)
+
+
+def _prefix_span(prefix: jax.Array, lo_key: jax.Array, hi_key: jax.Array):
+    """((lo_rank, lo_point), (hi_rank, hi_point)) of merged spans
+    [lo_key, hi_key]: the left rank of lo_key is row lo of the table, the
+    right rank of hi_key row hi + 1, and each row carries its point bound.
+    Probes are clipped into the table: below 0 no cell precedes them, and
+    past prod(dims) + 1 every cell does -- the ranks the binary search
+    gives those probes (dead-lane sentinels and wrapped probes among
+    them)."""
+    top = prefix.shape[0] - 1
+    lo = prefix[jnp.clip(lo_key, 0, top)]
+    hi = prefix[jnp.clip(hi_key + 1, 0, top)]
+    return (lo[..., 0], lo[..., 1]), (hi[..., 0], hi[..., 1])
+
+
+def dense_prefix_table(index: GridIndex):
+    """The index's dense prefix table (``_prefix_table``), or None where
+    the grid's volume keeps the binary searches (``prefix_table_size``).
+    Built once per index, by the merged capacity program that every merged
+    join plans on first (``dispatch_window_caps``), and gathered from by
+    the per-cell window tables."""
+    if not prefix_table_size(index):
+        return None
+    return dispatch_window_caps(index, merged=True)[1]
 
 
 # ---------------------------------------------------------------------------
@@ -955,15 +1061,20 @@ def cell_window_caps_host(index: GridIndex, merged: bool = False) -> np.ndarray:
     return caps.astype(np.int32)
 
 
-@partial(jax.jit, static_argnames=("merged",))
+@partial(jax.jit, static_argnames=("merged", "size"))
 def _cell_window_caps_device(index: GridIndex, deltas: jax.Array,
-                             merged: bool) -> jax.Array:
+                             merged: bool, size: int = 0):
     """Batched device form of the per-cell capacity sweep: ONE searchsorted
     over the (offset x cell) plane per probe side instead of a host loop of
     3^(n-1) sweeps. Operates on the full padded key array; lanes at rank >=
     ``num_cells`` are dead (padding-sentinel probes land on zero-count
-    padding slots). Returns the (npts,) int64 caps; the un-jitted wrapper
-    slices the valid prefix -- the single host sync of the plan.
+    padding slots). Returns the (npts,) int64 caps and the dense prefix
+    table (None unless ``size``); the un-jitted wrapper slices the valid
+    prefix of the caps -- the single host sync of the plan.
+
+    ``size`` (merged only, ``prefix_table_size``): build the dense prefix
+    table (``_prefix_table``) and read the span bounds from it, one
+    gather per probe side in int32, instead of the binary searches.
 
     Probe overflow note: on the int32 key route the host reference promotes
     ``keys + delta`` to int64 while the device add wraps, but a wrapped
@@ -978,6 +1089,19 @@ def _cell_window_caps_device(index: GridIndex, deltas: jax.Array,
     kd = keys.dtype
     n = keys.shape[0]
     is_cell = jnp.arange(n, dtype=jnp.int32) < index.num_cells
+    if merged and size:
+        prefix = _prefix_table(index, size)
+        own = jnp.where(is_cell, keys, 0).astype(jnp.int32)
+        dim_last = index.dims[-1].astype(jnp.int32)
+        last = own % dim_last
+        lo = own + jnp.maximum(-1, -last)
+        hi = own + jnp.minimum(1, dim_last - 1 - last)
+        d32 = deltas.astype(jnp.int32)[:, None]
+        (lo_rank, start), (hi_rank, end) = _prefix_span(
+            prefix, lo[None, :] + d32, hi[None, :] + d32)
+        live = (hi_rank > lo_rank) & is_cell[None, :]
+        hit = jnp.where(live, (end - start).astype(jnp.int64), 0)
+        return jnp.max(hit, axis=0), prefix
     counts = jnp.where(is_cell, index.cell_count, 0).astype(jnp.int64)
     deltas = deltas.astype(kd)[:, None]              # (n_off, 1)
     if not merged:
@@ -985,7 +1109,7 @@ def _cell_window_caps_device(index: GridIndex, deltas: jax.Array,
         pos = jnp.minimum(jnp.searchsorted(keys, probe), n - 1)
         live = keys[pos] == probe
         hit = jnp.where(live, counts[pos], 0)        # (n_off, npts)
-        return jnp.max(hit, axis=0)
+        return jnp.max(hit, axis=0), None
     dim_last = index.dims.astype(kd)[-1]
     last = keys % dim_last
     lo = keys + jnp.maximum(jnp.asarray(-1, kd), -last)
@@ -1001,7 +1125,7 @@ def _cell_window_caps_device(index: GridIndex, deltas: jax.Array,
     span = (_rank_to_point(index, hi_rank)
             - _rank_to_point(index, lo_rank)).astype(jnp.int64)
     hit = jnp.where(hi_rank > lo_rank, span, 0)
-    return jnp.max(hit, axis=0)
+    return jnp.max(hit, axis=0), None
 
 
 def cell_window_caps(index: GridIndex, merged: bool = False) -> np.ndarray:
@@ -1024,11 +1148,14 @@ def cell_window_caps(index: GridIndex, merged: bool = False) -> np.ndarray:
     return _fetch_window_caps(index, _launch_window_caps(index, merged))
 
 
-def _launch_window_caps(index: GridIndex, merged: bool) -> jax.Array:
-    """Dispatch ``cell_window_caps``' device sweep; no wait."""
+def _launch_window_caps(index: GridIndex, merged: bool):
+    """Dispatch ``cell_window_caps``' device sweep; no wait. Returns the
+    program's (caps, dense prefix table or None)."""
     from repro.core.stencil import merged_stencil_offsets, stencil_offsets
 
-    strides = np.asarray(row_major_strides(index.dims))
+    dims = host_dims(index)
+    strides = np.ones(dims.shape[0], np.int64)
+    strides[:-1] = np.cumprod(dims[:0:-1].astype(np.int64))[::-1]
     if merged:
         reduced, _, _ = merged_stencil_offsets(index.n_dims, unicomp=False)
         deltas = reduced @ strides
@@ -1036,12 +1163,13 @@ def _launch_window_caps(index: GridIndex, merged: bool) -> jax.Array:
         deltas = stencil_offsets(index.n_dims, unicomp=False) @ strides
     kd = np.dtype(index.cell_keys.dtype)
     return _cell_window_caps_device(
-        index, jnp.asarray(deltas.astype(kd)), merged=merged)
+        index, jnp.asarray(deltas.astype(kd)), merged=merged,
+        size=prefix_table_size(index) if merged else 0)
 
 
-def _fetch_window_caps(index: GridIndex, caps: jax.Array) -> np.ndarray:
+def _fetch_window_caps(index: GridIndex, launched) -> np.ndarray:
     ncells = int(index.num_cells)
-    return np.asarray(caps)[:ncells].astype(np.int32)
+    return np.asarray(launched[0])[:ncells].astype(np.int32)
 
 
 @jax.jit
@@ -1120,11 +1248,11 @@ def cell_window_caps_cached(index: GridIndex,
                             index, dispatch_window_caps(index, merged)))
 
 
-def dispatch_window_caps(index: GridIndex, merged: bool = False
-                         ) -> jax.Array:
+def dispatch_window_caps(index: GridIndex, merged: bool = False):
     """The device sweep behind ``cell_window_caps_cached``, dispatched
     once per index and not waited on: a driver queues it before it waits
-    on other devices, and the cached fetch then finds it under way."""
+    on other devices, and the cached fetch then finds it under way.
+    Returns the program's (caps, dense prefix table or None)."""
     return index_cached(index, f"cellcaps_dev/{merged}",
                         lambda: _launch_window_caps(index, merged))
 

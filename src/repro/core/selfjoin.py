@@ -802,8 +802,10 @@ def _self_join_fused(index: GridIndex, **kw):
 
     Host spans (DESIGN.md S8, "Observability"): the whole call runs under
     ``selfjoin.join``; launch planning under ``selfjoin.plan`` (its
-    ``launches``) and ``selfjoin.run_plan``, each dispatch under
-    ``selfjoin.launch``, every host wait on a device program under
+    ``launches``, and ``lookup_table``: 1 where the merged window
+    planners read the dense prefix table, ``grid.dense_prefix_table``,
+    0 where they binary-search) and ``selfjoin.run_plan``, each dispatch
+    under ``selfjoin.launch``, every host wait on a device program under
     ``selfjoin.sync``, the fill under ``selfjoin.emit`` and the result
     sort under ``selfjoin.sort``.
 
@@ -839,7 +841,7 @@ def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
     order. It never yields inside one of its spans, so that the spans of
     stages driven in step on one thread still nest; the caller opens the
     ``selfjoin.join`` span around them."""
-    from repro.core.grid import dispatch_window_caps
+    from repro.core.grid import dispatch_window_caps, prefix_table_size
 
     if emit is None:
         emit = "device" if jax.default_backend() == "tpu" else "host"
@@ -869,7 +871,8 @@ def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
         launches, points_pad, _ = _fused_launches(
             index, n_batches=n_batches, bucketed=bucketed,
             merged=merged, row_ok=row_ok, gid=gid, feats=feats)
-        sp.set_metadata(launches=len(launches))
+        sp.set_metadata(launches=len(launches), lookup_table=int(
+            merged and prefix_table_size(index) > 0))
     single = len(launches) == 1
 
     def finish(run):
@@ -1187,11 +1190,6 @@ def _range_plane_table(table, cell_keys, rank_arr, deltas32, lo_off, hi_off,
     return lo_rank, hi_rank
 
 
-# Dense-lookup budget: prod(dims) at or below this many cells (x4 bytes)
-# buys the table path; beyond it, binary search (the paper's trade) wins.
-_LOOKUP_MAX_CELLS = 1 << 23   # 32 MB
-
-
 def _sparse_lookup(index: GridIndex):
     """Cached per index: ('table', dense key->rank table) when the key
     space fits the budget, else ('keys', int32-or-int64 B).
@@ -1201,20 +1199,21 @@ def _sparse_lookup(index: GridIndex):
     preserving sort order and never matching a probe; int32 halves the
     binary search's bandwidth.
     """
+    from repro.core import grid as grid_lib
     from repro.core.grid import index_cached, pad_key_for
 
     def build():
-        volume = float(np.prod(np.asarray(index.dims, dtype=np.float64)))
+        volume = grid_lib.grid_volume(index)
         ncells = int(index.num_cells)
-        if volume <= _LOOKUP_MAX_CELLS:
+        if volume <= grid_lib._LOOKUP_MAX_CELLS:
             keys = np.asarray(index.cell_keys[:ncells])
-            table = np.full(int(volume), -1, np.int32)
+            table = np.full(volume, -1, np.int32)
             # a padded build (build_grid_with_geometry valid=...) carries a
             # sentinel cell with key == prod(dims) (== table length), and
             # out-of-geometry points can produce keys outside [0, volume);
             # keep those cells out of the scatter -- probes to them miss,
             # and padding points were never reachable as candidates anyway
-            ok = (keys >= 0) & (keys < int(volume))
+            ok = (keys >= 0) & (keys < volume)
             table[keys[ok]] = np.arange(ncells, dtype=np.int32)[ok]
             return ("table", jnp.asarray(table))
         if volume < float(1 << 30):
