@@ -1,5 +1,5 @@
-"""Self-join perf trajectory: count/fill across distance_impl variants,
-plus the serving path (--mode serve) and a CI smoke (--smoke).
+"""Self-join perf trajectory: count/fill of the fused sweep, plus the
+serving path (--mode serve) and a CI smoke (--smoke).
 
     PYTHONPATH=src python benchmarks/bench_selfjoin.py [--out BENCH_selfjoin.json]
     PYTHONPATH=src python benchmarks/bench_selfjoin.py --mode serve
@@ -8,18 +8,14 @@ plus the serving path (--mode serve) and a CI smoke (--smoke).
 --mode impl (default) times ``self_join_count`` (count) and ``self_join``
 (count+fill, unsorted -- the paper reports the result sort separately) for
 n in {2, 3, 4, 6} on uniform, clustered, and exponentially skewed
-datasets, across distance_impl in {jnp, pallas, fused}, with the grid
-index prebuilt (index construction is shared by every impl and benchmarked
-in benchmarks/joins.py). The fused impl sweeps the merged-range 3^(n-1)
-stencil by default (--no-merge times the per-cell 3^n oracle; --smoke
-asserts pair-set parity between the two on every workload -- the CI
-parity gate) and runs with autotuning enabled (kernels/autotune.py
-measures tiles and the count route once and persists the winners), records
-the chosen route, the offsets swept (n_offsets_swept), and the per-cell +
-merged window-capacity histograms that drive the occupancy buckets
-(DESIGN.md S6/S7), and ASSERTS the routing floor: fused count must not
-lose to jnp on any workload (the uniform-6d regression this gate pins
-down; --no-assert-floor to disable). The fused entry also records the
+datasets, with the grid index prebuilt (index construction is benchmarked
+in benchmarks/joins.py). The fused sweep runs the merged-range 3^(n-1)
+stencil by default (--no-merge times the per-cell 3^n oracle; the count
+is checked against the other sweep on every workload, and --smoke asserts
+pair-set parity between the two -- the CI parity gate), and the entry
+records the count route, the offsets swept (n_offsets_swept), and the
+per-cell + merged window-capacity histograms that drive the occupancy
+buckets (DESIGN.md S6/S7). It also records the
 cell-run DMA dedup trajectory (DESIGN.md S11): row-loop vs run-loop join
 timings and the per-workload analytic DMA-window ledger + run-length
 histogram (``dma`` section); --smoke additionally gates run-loop vs
@@ -60,17 +56,14 @@ timed against the plain L2 join on its canonical geometry, pinning the
 claim that the metric's steady-state overhead is canonicalization only.
 Records the "metrics" section; ``--mode metrics --smoke`` is the CI gate.
 
---smoke shrinks the impl sweep to one tiny workload (seconds), writes to a
-temp file by default, skips the floor assert (noise at this scale), and
-schema-validates the payload -- wired into scripts/ci.sh so the harness
-and the BENCH schema cannot rot between full runs.
+--smoke shrinks the impl sweep to tiny workloads (seconds), writes to a
+temp file by default, and schema-validates the payload -- wired into
+scripts/ci.sh so the harness and the BENCH schema cannot rot between full
+runs.
 
-On this CPU container the 'pallas' impl runs the cell_join kernel through
-the interpreter and the 'fused' impl runs the reference lowering of
+Off the TPU the fused sweep runs the reference lowering of
 kernels/fused_join.py (same algorithm, same outputs as the Mosaic kernel);
-absolute times are machine-local, the IMPL-vs-IMPL ratios are the claim
-(interpret-mode CPU timing as proxy, ISSUE 1). The headline acceptance
-number is fused-vs-jnp on the 2-D uniform 100k workload.
+CPU times are overhead and correctness checks, not speed claims.
 
 Writes/updates BENCH_selfjoin.json (repo root by default): each mode
 rewrites its own section and preserves the other's, so the file holds the
@@ -93,8 +86,6 @@ sys.path.insert(0, _ROOT)
 from repro.core.grid import build_grid_host                     # noqa: E402
 from repro.core.selfjoin import self_join, self_join_count      # noqa: E402
 from benchmarks.common import syn                               # noqa: E402
-
-IMPLS = ("jnp", "pallas", "fused")
 
 
 def clustered(n_points: int, n_dims: int, seed: int = 3) -> np.ndarray:
@@ -140,27 +131,21 @@ def validate_schema(payload: dict) -> None:
     acceptance gates; --smoke runs this in CI so it cannot rot."""
     for key in ("bench", "backend", "jax", "results"):
         assert key in payload, key
-    assert payload["headline"] is None or {
-        "workload", "n_points", "fused_over_jnp_join",
-        "fused_over_jnp_count"} <= set(payload["headline"])
     for e in payload["results"]:
         for key in ("workload", "n_points", "n_dims", "eps", "total_pairs",
                     "max_per_cell", "window_caps_hist",
                     "merged_window_caps_hist", "impls"):
             assert key in e, (e.get("workload"), key)
-        for impl, t in e["impls"].items():
-            assert {"count_s", "join_s"} <= set(t), (e["workload"], impl)
-        if "fused" in e["impls"]:
-            assert "route" in e["impls"]["fused"], e["workload"]
-            assert "n_offsets_swept" in e["impls"]["fused"], e["workload"]
-            # cell-run DMA dedup trajectory (DESIGN.md S11)
-            assert {"join_row_s", "join_run_s",
-                    "run_over_row_join"} <= set(e["impls"]["fused"]), (
-                e["workload"])
-            assert "dma" in e, e["workload"]
-            assert {"dma_windows_row", "dma_windows_run", "dma_bytes_saved",
-                    "reduction_factor", "mean_cell_occupancy",
-                    "run_length_hist"} <= set(e["dma"]), e["workload"]
+        f = e["impls"]["fused"]
+        assert {"count_s", "join_s", "route", "n_offsets_swept"} <= set(f), (
+            e["workload"])
+        # cell-run DMA dedup trajectory (DESIGN.md S11)
+        assert {"join_row_s", "join_run_s",
+                "run_over_row_join"} <= set(f), e["workload"]
+        assert "dma" in e, e["workload"]
+        assert {"dma_windows_row", "dma_windows_run", "dma_bytes_saved",
+                "reduction_factor", "mean_cell_occupancy",
+                "run_length_hist"} <= set(e["dma"]), e["workload"]
     if "load" in payload:
         validate_load_schema(payload["load"])
     if "index" in payload:
@@ -745,16 +730,6 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny impl sweep + schema validation (CI gate); "
                          "writes to a temp file unless --out is given")
-    ap.add_argument("--assert-floor", dest="assert_floor",
-                    action="store_true", default=None,
-                    help="fail if routed fused count loses to jnp "
-                         "(default: on for full impl runs, off for --smoke)")
-    ap.add_argument("--no-assert-floor", dest="assert_floor",
-                    action="store_false")
-    ap.add_argument("--no-autotune", dest="autotune", action="store_false",
-                    default=True,
-                    help="disable measured tile/route autotuning "
-                         "(kernels/autotune.py) for this run")
     ap.add_argument("--no-merge", action="store_true",
                     help="time the per-cell 3^n sweep instead of the "
                          "merged-range 3^(n-1) sweep (parity oracle, "
@@ -766,8 +741,6 @@ def main(argv=None):
     ap.add_argument("--points-4d", type=int, default=20_000)
     ap.add_argument("--points-6d", type=int, default=10_000)
     ap.add_argument("--trials", type=int, default=3)
-    ap.add_argument("--impls", default=",".join(IMPLS),
-                    help="comma-separated subset of %s" % (IMPLS,))
     # --mode serve: the default serve workload (launch/serve.py defaults)
     ap.add_argument("--serve-points", type=int, default=20_000)
     ap.add_argument("--serve-dims", type=int, default=4)
@@ -795,13 +768,8 @@ def main(argv=None):
                     help="minimum batching-vs-baseline req/s ratio at the "
                          "gate point (full runs only)")
     args = ap.parse_args(argv)
-    if args.assert_floor is None:
-        args.assert_floor = args.mode == "impl" and not args.smoke
     if args.smoke:
         args.trials = 1
-        if args.impls == ",".join(IMPLS):
-            args.impls = "jnp,fused"   # interpreted pallas is minutes even
-    impls = tuple(args.impls.split(","))
     if args.out is None:
         if args.smoke:
             import tempfile
@@ -811,10 +779,6 @@ def main(argv=None):
         else:
             args.out = os.path.join(
                 os.path.dirname(__file__), "..", "BENCH_selfjoin.json")
-    if args.autotune and args.mode == "impl" and not args.smoke:
-        # measured tile + route autotuning: winners persist in the cache
-        # next to kernels/autotune.py (or $REPRO_AUTOTUNE_CACHE)
-        os.environ.setdefault("REPRO_AUTOTUNE", "1")
     out = os.path.abspath(args.out)
     existing = {}
     if os.path.exists(out):
@@ -851,16 +815,16 @@ def main(argv=None):
     results = []
     for name, pts, eps in workloads(args):
         index = build_grid_host(pts, eps)
-        expect = self_join_count(pts, eps, index=index).total_pairs
+        # the other sweep is the timed one's reference
+        expect = self_join_count(pts, eps, index=index,
+                                 merge_last_dim=not merge).total_pairs
         plan = occupancy_plan(index)
         mplan = occupancy_plan(index, merged=True)
         if args.smoke:
             # CI parity oracle (DESIGN.md S7): the merged-range sweep and
             # the per-cell sweep must emit identical sorted pair sets --
             # exercised on every build, not just under pytest. The driver
-            # is called with the sweep PINNED (not through the public
-            # merge_last_dim default) so a measured 'dense-flat' route
-            # verdict can never silently turn this into oracle-vs-oracle.
+            # is called with the sweep pinned on both sides.
             from repro.core.selfjoin import _self_join_fused
 
             pm = _self_join_fused(index, unicomp=True, sort_result=True,
@@ -919,87 +883,53 @@ def main(argv=None):
                                         sorted(mplan.hist.items())},
             "impls": {},
         }
-        for impl in impls:
-            stats = self_join_count(pts, eps, index=index, distance_impl=impl,
-                                    merge_last_dim=merge)
-            assert stats.total_pairs == expect, (name, impl, stats)
-            # the interpreted cell_join kernel is ~100x slower than its
-            # Mosaic build; one timed trial keeps the sweep tractable
-            trials = 1 if impl == "pallas" else args.trials
-            t_count = best_of(
-                lambda: self_join_count(pts, eps, index=index,
-                                        distance_impl=impl,
-                                        merge_last_dim=merge),
-                trials)
-            t_join = best_of(
-                lambda: self_join(pts, eps, index=index, distance_impl=impl,
-                                  sort_result=False, merge_last_dim=merge),
-                trials)
-            entry["impls"][impl] = {"count_s": t_count, "join_s": t_join}
-            if impl == "fused":
-                entry["impls"][impl]["route"] = stats.route
-                entry["impls"][impl]["n_offsets_swept"] = stats.n_offsets
-                # Cell-run DMA dedup trajectory (DESIGN.md S11): row-loop
-                # vs run-loop join through the same fused driver, plus the
-                # analytic per-workload DMA-window ledger + run-length
-                # histogram (the redundancy reduction as a TRACKED number)
-                from repro.core.selfjoin import (_self_join_fused,
-                                                 dma_window_stats)
+        stats = self_join_count(pts, eps, index=index,
+                                merge_last_dim=merge)
+        assert stats.total_pairs == expect, (name, stats)
+        t_count = best_of(
+            lambda: self_join_count(pts, eps, index=index,
+                                    merge_last_dim=merge), args.trials)
+        t_join = best_of(
+            lambda: self_join(pts, eps, index=index, sort_result=False,
+                              merge_last_dim=merge), args.trials)
+        # Cell-run DMA dedup trajectory (DESIGN.md S11): row-loop vs
+        # run-loop join through the same fused driver, plus the analytic
+        # per-workload DMA-window ledger + run-length histogram (the
+        # redundancy reduction as a TRACKED number)
+        from repro.core.selfjoin import _self_join_fused, dma_window_stats
 
-                t_row = best_of(
-                    lambda: _self_join_fused(index, unicomp=True,
-                                             sort_result=False, merged=merge,
-                                             run_loop=False), trials)
-                t_run = best_of(
-                    lambda: _self_join_fused(index, unicomp=True,
-                                             sort_result=False, merged=merge,
-                                             run_loop=True), trials)
-                entry["impls"][impl]["join_row_s"] = t_row
-                entry["impls"][impl]["join_run_s"] = t_run
-                entry["impls"][impl]["run_over_row_join"] = t_row / t_run
-                entry["dma"] = dma_window_stats(index, merged=merge)
-                d = entry["dma"]
-                print(f"[bench] {name:14s} {'dma':6s} "
-                      f"row {t_row*1e3:9.1f} ms   run {t_run*1e3:9.1f} ms  "
-                      f"({t_row / t_run:.2f}x)   windows "
-                      f"{d['dma_windows_row']} -> {d['dma_windows_run']} "
-                      f"({d['reduction_factor']:.2f}x, occ "
-                      f"{d['mean_cell_occupancy']:.2f})", flush=True)
-            print(f"[bench] {name:14s} {impl:6s} "
-                  f"count {t_count*1e3:9.1f} ms   join {t_join*1e3:9.1f} ms"
-                  + (f"   route={stats.route} n_off={stats.n_offsets}"
-                     if impl == "fused" else ""),
-                  flush=True)
-        j = entry["impls"]
-        if "jnp" in j and "fused" in j:
-            entry["speedup_fused_vs_jnp"] = {
-                "count": j["jnp"]["count_s"] / j["fused"]["count_s"],
-                "join": j["jnp"]["join_s"] / j["fused"]["join_s"],
-            }
-            if args.assert_floor:
-                r = entry["speedup_fused_vs_jnp"]["count"]
-                assert r >= 1.0, (
-                    f"routing floor violated on {name}: fused count {r:.2f}x "
-                    f"vs jnp (route={j['fused']['route']}) -- the routing "
-                    f"table must never pin a fused plan that loses to jnp")
+        t_row = best_of(
+            lambda: _self_join_fused(index, unicomp=True, sort_result=False,
+                                     merged=merge, run_loop=False),
+            args.trials)
+        t_run = best_of(
+            lambda: _self_join_fused(index, unicomp=True, sort_result=False,
+                                     merged=merge, run_loop=True),
+            args.trials)
+        entry["impls"]["fused"] = {
+            "count_s": t_count, "join_s": t_join, "route": stats.route,
+            "n_offsets_swept": stats.n_offsets, "join_row_s": t_row,
+            "join_run_s": t_run, "run_over_row_join": t_row / t_run}
+        entry["dma"] = dma_window_stats(index, merged=merge)
+        d = entry["dma"]
+        print(f"[bench] {name:14s} {'dma':6s} "
+              f"row {t_row*1e3:9.1f} ms   run {t_run*1e3:9.1f} ms  "
+              f"({t_row / t_run:.2f}x)   windows "
+              f"{d['dma_windows_row']} -> {d['dma_windows_run']} "
+              f"({d['reduction_factor']:.2f}x, occ "
+              f"{d['mean_cell_occupancy']:.2f})", flush=True)
+        print(f"[bench] {name:14s} fused  "
+              f"count {t_count*1e3:9.1f} ms   join {t_join*1e3:9.1f} ms"
+              f"   route={stats.route} n_off={stats.n_offsets}", flush=True)
         results.append(entry)
 
-    headline = next((e for e in results
-                     if e["workload"] == "uniform-2d"
-                     and "speedup_fused_vs_jnp" in e), None)
     payload = {
         "bench": "selfjoin-distance-impl",
         "backend": jax.default_backend(),
         "jax": jax.__version__,
-        "note": ("CPU proxy timings: 'pallas' via kernel interpreter, "
-                 "'fused' via the reference lowering of the fused kernel "
-                 "(bit-identical outputs to the Mosaic kernel)"),
-        "headline": None if headline is None else {
-            "workload": "uniform-2d",
-            "n_points": headline["n_points"],
-            "fused_over_jnp_join": headline["speedup_fused_vs_jnp"]["join"],
-            "fused_over_jnp_count": headline["speedup_fused_vs_jnp"]["count"],
-        },
+        "note": ("CPU proxy timings: the fused sweep via the reference "
+                 "lowering of the fused kernel (bit-identical outputs to "
+                 "the Mosaic kernel)"),
         "results": results,
     }
     for section in ("serve", "distributed", "load", "index"):  # modes preserve others
@@ -1007,16 +937,9 @@ def main(argv=None):
             payload[section] = existing[section]
     validate_schema(payload)
     if args.smoke:
-        print("[bench] smoke: schema validated "
-              f"({len(results)} workloads, floor assert "
-              f"{'on' if args.assert_floor else 'off'})")
+        print(f"[bench] smoke: schema validated ({len(results)} workloads)")
     with open(out, "w") as f:
         json.dump(payload, f, indent=1)
-    if headline is not None:
-        print(f"[bench] headline: fused over jnp (uniform-2d, "
-              f"{headline['n_points']} pts): "
-              f"join {payload['headline']['fused_over_jnp_join']:.2f}x, "
-              f"count {payload['headline']['fused_over_jnp_count']:.2f}x")
     print(f"[bench] wrote {out}")
     return payload
 

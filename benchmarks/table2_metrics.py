@@ -11,8 +11,8 @@ counters have no TPU meaning; the structural analogues we report are:
                     (the analogue of occupancy loss)
   query-tile reuse  stencil offsets per query tile residency -- how many
                     times the VMEM-resident query tile is reused (the
-                    analogue of the L1 temporal-locality gain, kernel
-                    cell_join.py keeps the tile resident across offsets)
+                    analogue of the L1 temporal-locality gain, the fused
+                    kernel keeps the tile resident across offsets)
 """
 from __future__ import annotations
 
@@ -35,8 +35,11 @@ def run(scale=1.0):
         index = build_grid_host(pts, eps)
         cmax = int(index.max_per_cell)
         cpad = -(-max(cmax, 1) // 8) * 8
-        s_u = self_join_count(pts, eps, unicomp=True, index=index)
-        s_f = self_join_count(pts, eps, unicomp=False, index=index)
+        # the paper's per-cell stencil, at one max_per_cell window a probe
+        s_u = self_join_count(pts, eps, unicomp=True, index=index,
+                              merge_last_dim=False)
+        s_f = self_join_count(pts, eps, unicomp=False, index=index,
+                              merge_last_dim=False)
         valid_frac_u = s_u.candidates_checked / (
             s_u.offsets * pts.shape[0] * cpad)
         rows.append({

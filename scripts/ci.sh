@@ -29,14 +29,14 @@ timeout 180 python -m repro.launch.serve --arch selfjoin --requests 8 \
 echo "[ci] load smoke (fixed offered load: p99 must hold the recorded SLO, coalesce factor must be > 1)"
 timeout 300 python benchmarks/bench_selfjoin.py --mode load --smoke
 
-echo "[ci] bench smoke, merged-range sweep (harness + BENCH schema + merged-vs-unmerged AND run-loop-vs-row-loop pair-set parity + dma_windows_issued decrease on the clustered workload)"
+echo "[ci] bench smoke, merged-range sweep (harness + BENCH schema + merged-vs-unmerged AND run-loop-vs-row-loop pair-set parity + DMA-window decrease on the clustered workload)"
 timeout 300 python benchmarks/bench_selfjoin.py --smoke
 
 echo "[ci] bench smoke, per-cell sweep oracle (--no-merge; parity asserted again)"
 timeout 300 python benchmarks/bench_selfjoin.py --smoke --no-merge
 
 echo "[ci] bench smoke under REPRO_SANITIZE=1 (sanitized kernel mode: invariant checks must stay clean)"
-REPRO_SANITIZE=1 timeout 300 python benchmarks/bench_selfjoin.py --smoke --no-assert-floor
+REPRO_SANITIZE=1 timeout 300 python benchmarks/bench_selfjoin.py --smoke
 
 echo "[ci] distributed bench smoke (2 slabs: pair-set parity vs single-device fused join)"
 XLA_FLAGS="--xla_force_host_platform_device_count=2" \

@@ -300,31 +300,29 @@ def check_device_sentinel(index, tag: str = "index") -> list:
     return []
 
 
-def _plan_tiles(index, plan, metric: str = "l2") -> dict:
-    from repro.kernels import autotune
+def _plan_tiles(plan) -> dict:
+    """Every capacity class launches at the kernel's query tile."""
+    from repro.kernels.fused_join import TQ_DEFAULT
 
-    return {int(cap): autotune.fused_tile(index.n_dims, int(cap),
-                                          metric=metric)
-            for cap in plan.caps}
+    return {int(cap): TQ_DEFAULT for cap in plan.caps}
 
 
 def check_slot_base(index, *, merged: bool, plan=None, tiles=None,
-                    metric: str = "l2", tag: str = "index") -> list:
+                    tag: str = "index") -> list:
     """C5: int32 range of the kernel's counts and per-tile scan.
 
     Per query: count <= n_off * c. Per tile of tq rows: the exclusive
     scan's last base <= (tq - 1) * n_off * c. Both live in int32 inside
     the kernel; prove they cannot wrap for any (class, tile) launch.
     The bound is metric-independent (every metric's refine emits at most
-    one hit per candidate slot), but ``metric`` keys the tile lookup --
-    a jaccard table row may launch a different tq."""
+    one hit per candidate slot)."""
     from repro.core.grid import occupancy_plan
 
     out = []
     if plan is None:
         plan = occupancy_plan(index, merged=merged)
     if tiles is None:
-        tiles = _plan_tiles(index, plan, metric)
+        tiles = _plan_tiles(plan)
     n = index.n_dims
     n_off = 3 ** (n - 1) if merged else 3 ** n   # full stencil bounds UNICOMP
     lim = 2 ** 31 - 1
@@ -348,8 +346,7 @@ def check_slot_base(index, *, merged: bool, plan=None, tiles=None,
 
 
 def check_vmem(index, *, merged: bool, plan=None, tiles=None,
-               metric: str = "l2", n_feat: int = 0,
-               tag: str = "index") -> list:
+               n_feat: int = 0, tag: str = "index") -> list:
     """C6: per-(class, tile) kernel VMEM footprint vs the roofline budget.
 
     Metric-aware (DESIGN.md S12): feature lanes (jaccard token bitmaps)
@@ -367,7 +364,7 @@ def check_vmem(index, *, merged: bool, plan=None, tiles=None,
     if plan is None:
         plan = occupancy_plan(index, merged=merged)
     if tiles is None:
-        tiles = _plan_tiles(index, plan, metric)
+        tiles = _plan_tiles(plan)
     lanes = index.n_dims + int(n_feat) + (1 if merged else 0)
     np_pad = pad_width(lanes)
     for cap in plan.caps:
@@ -448,7 +445,7 @@ def _validate_run_ord(run_ord: np.ndarray, cells: np.ndarray, tq: int,
 
 def check_run_plan(index, *, merged: bool = True, plan=None, tiles=None,
                    run_ord=None, tq: Optional[int] = None,
-                   metric: str = "l2", tag: str = "index") -> list:
+                   tag: str = "index") -> list:
     """C10: cell-run plans are exact partitions (DESIGN.md S11).
 
     Default mode rebuilds every run plan the fused self-join drivers can
@@ -477,7 +474,7 @@ def check_run_plan(index, *, merged: bool = True, plan=None, tiles=None,
     if plan is None:
         plan = occupancy_plan(index, merged=merged)
     if tiles is None:
-        tiles = _plan_tiles(index, plan, metric)
+        tiles = _plan_tiles(plan)
     out = []
     for cap, sel in zip(plan.caps, plan.sel):
         t = int(tiles[int(cap)])
@@ -508,9 +505,9 @@ def prove_index_contracts(index, *, merged: Optional[bool] = None,
     sweep modes; ``plan``/``tiles`` override the planner outputs (the
     mutation harness injects tampered plans through exactly this seam).
     ``metric``/``n_feat`` describe the refine layout the index serves
-    (DESIGN.md S12): they key the autotuned tile lookups and widen the C6
-    VMEM proof by the metric's feature lanes. A jaccard index never runs
-    a merged sweep, so its merged-mode proof is skipped."""
+    (DESIGN.md S12): ``n_feat`` widens the C6 VMEM proof by the metric's
+    feature lanes, and a jaccard index never runs a merged sweep, so its
+    merged-mode proof is skipped."""
     modes = (False, True) if merged is None else (bool(merged),)
     if metric == "jaccard":
         modes = tuple(m for m in modes if not m) or (False,)
@@ -520,11 +517,11 @@ def prove_index_contracts(index, *, merged: Optional[bool] = None,
     for m in modes:
         out += check_window_caps(index, merged=m, plan=plan, tag=tag)
         out += check_slot_base(index, merged=m, plan=plan, tiles=tiles,
-                               metric=metric, tag=tag)
+                               tag=tag)
         out += check_vmem(index, merged=m, plan=plan, tiles=tiles,
-                          metric=metric, n_feat=n_feat, tag=tag)
+                          n_feat=n_feat, tag=tag)
         out += check_run_plan(index, merged=m, plan=plan, tiles=tiles,
-                              metric=metric, tag=tag)
+                              tag=tag)
     return out
 
 

@@ -279,17 +279,16 @@ def fused_launch_keys(pj, size: int, keep: bool) -> set:
     SHAPE space is the pow2 tile ladder bounded by the request bucket --
     the same enumeration ``PreparedJoin.warm``'s ladder loop compiles."""
     from repro.core.query_join import bucket_rows
+    from repro.kernels.fused_join import TQ_DEFAULT as tile
 
     qp = bucket_rows(size)
     keys = set()
     if not pj.bucketed:
-        tile = pj.tiles[pj.c]
         keys.add((pj.c, tile, qp, keep))
         return keys
     for cb in pj.classes:
-        tile = pj.tiles[cb]
         s = tile
-        while s <= bucket_rows(qp, tile):
+        while s <= qp:
             keys.add((cb, tile, s, keep))
             s *= 2
     return keys

@@ -76,7 +76,6 @@ class DistJoinConfig:
     unicomp: bool = True
     slab_axis: str = "slab"
     model_axis: Optional[str] = "model"   # None -> no offset-parallelism
-    distance_impl: str = "jnp"
     # halo reach: a slab narrower than eps (equal-count partition of skewed
     # data at high slab counts) needs points from k>1 slabs away. The driver
     # auto-computes k from the partition boundaries.

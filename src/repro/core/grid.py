@@ -873,8 +873,7 @@ def cell_window_tables(index: GridIndex, deltas, *, merged: bool, tag):
 # Dense prefix table: where the grid's key space is small, the merged
 # window planners answer "how many non-empty cells (points) lie below key
 # k" by one gather from a table over every key, instead of a binary search
-# of B -- the trade ``selfjoin._sparse_lookup`` makes for the count path,
-# under the same volume budget.
+# of B.
 # ---------------------------------------------------------------------------
 
 # Dense-lookup budget: prod(dims) at or below this many cells (x4 bytes a

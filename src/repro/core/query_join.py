@@ -61,8 +61,8 @@ from repro.core import metric as metric_lib
 from repro.core.grid import (GridIndex, build_grid, cell_run_plan,
                              round_up as _round_up)
 from repro.core.stencil import stencil_offsets
+from repro.kernels.fused_join import TQ_DEFAULT
 
-_TQ = 128      # query tile rows (kernel grid unit; bucket shapes are multiples)
 _C_ALIGN = 8   # window capacity alignment (lane unit, matches selfjoin)
 # Device-emit scatter capacity floor: result buffers round up to powers of
 # two with this minimum, so a service compiles O(log max_result) emit
@@ -91,17 +91,16 @@ def _next_pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length()
 
 
-def bucket_rows(n_queries: int, tile: int = _TQ) -> int:
+def bucket_rows(n_queries: int) -> int:
     """Static padded row count for a request of ``n_queries`` queries.
 
-    Tile-multiple buckets growing by powers of two (128, 256, 512, ...), so
-    a service compiles O(log max_batch) executables across all request
-    sizes instead of one per distinct size. ``tile`` is the kernel grid
-    unit the rows must divide (a capacity class's query tile for the
-    occupancy buckets).
+    Multiples of the kernel's query tile ``TQ_DEFAULT`` growing by powers
+    of two (128, 256, 512, ...), so a service compiles O(log max_batch)
+    executables across all request sizes instead of one per distinct
+    size. Occupancy-bucket launches pad their rows the same way.
     """
     n = max(int(n_queries), 1)
-    return tile * _next_pow2(-(-n // tile))
+    return TQ_DEFAULT * _next_pow2(-(-n // TQ_DEFAULT))
 
 
 @jax.jit
@@ -154,9 +153,9 @@ def _bucket_select(ws: jax.Array, wc: jax.Array, q_pad: jax.Array,
     return ws_b, wc_b, q_pad[sel]
 
 
-@partial(jax.jit, static_argnames=("c", "tq", "capacity"))
+@partial(jax.jit, static_argnames=("c", "capacity"))
 def _emit_pairs_device(order, hits, counts, slot_base, win_start, *,
-                       c: int, tq: int, capacity: int):
+                       c: int, capacity: int):
     """Device fill: scatter (query row, point id) pairs from the count
     pass's hit set -- no distances, same single-pass discipline as
     ``selfjoin._emit_from_hits`` minus the self-join masking. Query-major
@@ -170,9 +169,9 @@ def _emit_pairs_device(order, hits, counts, slot_base, win_start, *,
     cand = win_start[:, :, None] + slots[None, None, :]
     cp = jnp.minimum(cand.transpose(1, 0, 2).reshape(qp, n_off * c), npts - 1)
     rank = jnp.cumsum(h, axis=1) - 1              # within-query hit rank
-    tile_tot = counts.reshape(-1, tq).sum(axis=1).astype(jnp.int64)
+    tile_tot = counts.reshape(-1, TQ_DEFAULT).sum(axis=1).astype(jnp.int64)
     tile_base = jnp.cumsum(tile_tot) - tile_tot
-    qbase = jnp.repeat(tile_base, tq) + slot_base.astype(jnp.int64)
+    qbase = jnp.repeat(tile_base, TQ_DEFAULT) + slot_base.astype(jnp.int64)
     pos = qbase[:, None] + rank
     qid = jnp.broadcast_to(jnp.arange(qp, dtype=jnp.int32)[:, None], h.shape)
     cid = order[cp]
@@ -275,7 +274,6 @@ class _FusedLaunch:
     base: jax.Array
     ws: jax.Array
     c: int
-    tile: int
 
 
 class PendingJoin:
@@ -343,7 +341,7 @@ class PendingJoin:
             if self._return_pairs:
                 with span("query.emit"):
                     p = pj._emit(self._emit, ln.hits, ln.counts, ln.base,
-                                 ln.ws, c=ln.c, tq=ln.tile,
+                                 ln.ws, c=ln.c,
                                  total=int(counts_b.sum()))
                 if ln.rows is not None:
                     p[:, 0] = ln.rows[p[:, 0]]   # launch row -> batch row
@@ -401,7 +399,6 @@ class PreparedJoin:
                  canon: Optional[metric_lib.Canonical] = None):
         from repro.core.grid import capacity_classes, external_range_cap
         from repro.core.stencil import merged_stencil_offsets
-        from repro.kernels import autotune
         from repro.kernels.fused_join import (pad_points,
                                               resolve_merge_last_dim)
 
@@ -464,12 +461,6 @@ class PreparedJoin:
         self.dtype = np.dtype(index.points_sorted.dtype)
         self.gmin_np = np.asarray(index.grid_min)
         self.classes = capacity_classes(self.c, _C_ALIGN)
-        # Per-class query tile from the measured table, clamped to the
-        # service's request-padding unit so bucket_rows stays the public
-        # shape contract (multiples of _TQ).
-        self.tiles = {cb: min(autotune.fused_tile(self.n_dims, cb,
-                                                  metric=self.metric), _TQ)
-                      for cb in self.classes}
         self.bucketed = len(self.classes) > 1
         # cell-run batching (DESIGN.md S11): sort request batches by grid
         # cell so the fused kernel gathers each run's window once
@@ -479,10 +470,10 @@ class PreparedJoin:
     def _pad_queries(self, q: np.ndarray,
                      feats: Optional[np.ndarray] = None
                      ) -> tuple[jax.Array, int]:
-        # _TQ is always the request padding unit: class tiles are clamped
-        # to _TQ at construction, so every launch divides it. Lane width
-        # comes from the padded points copy, so queries and candidates
-        # always agree (the kernel derives its statics the same way).
+        # TQ_DEFAULT is the request padding unit and every launch's query
+        # tile. Lane width comes from the padded points copy, so queries
+        # and candidates always agree (the kernel derives its statics the
+        # same way).
         qp = bucket_rows(q.shape[0])
         q_pad = np.zeros((qp, int(self.points_pad.shape[1])), self.dtype)
         q_pad[: q.shape[0], : self.n_dims] = q
@@ -509,8 +500,8 @@ class PreparedJoin:
             self.q_pos0[qp] = z
         return z
 
-    def _launch_run_ord(self, gid: Optional[np.ndarray], qp_b: int,
-                        tile: int) -> jax.Array:
+    def _launch_run_ord(self, gid: Optional[np.ndarray],
+                        qp_b: int) -> jax.Array:
         """run_ord scalar-prefetch for one launch: the launch rows' cell
         group ids padded to the launch shape with the edge id (pad rows
         join the LAST run -- inert, their window counts are zeroed by the
@@ -519,9 +510,9 @@ class PreparedJoin:
             return self._q_pos(qp_b)   # zeros: one run per tile
         ids = np.full(qp_b, gid[-1], np.int64)
         ids[: gid.size] = gid
-        return jnp.asarray(cell_run_plan(ids, tile).run_ord)
+        return jnp.asarray(cell_run_plan(ids, TQ_DEFAULT).run_ord)
 
-    def _emit(self, emit, hits, counts, base, ws, *, c: int, tq: int,
+    def _emit(self, emit, hits, counts, base, ws, *, c: int,
               total: int) -> np.ndarray:
         """One launch's fill: host bitmap compaction or device scatter."""
         if emit == "host":
@@ -531,7 +522,7 @@ class PreparedJoin:
             capacity = max(_next_pow2(total), _EMIT_CAP_MIN)
             keys, vals = _emit_pairs_device(
                 self.index.order, hits, counts, base, ws,
-                c=c, tq=tq, capacity=capacity)
+                c=c, capacity=capacity)
             return np.stack(
                 [np.asarray(keys)[:total], np.asarray(vals)[:total]], axis=1)
         raise ValueError(f"unknown emit backend {emit!r}")
@@ -613,20 +604,19 @@ class PreparedJoin:
             emit = "device" if jax.default_backend() == "tpu" else "host"
         launches = []
         if not self.bucketed:
-            tile = self.tiles[self.c]
             with span("query.launch"):
-                ro = (self._launch_run_ord(gid, qp, tile)
+                ro = (self._launch_run_ord(gid, qp)
                       if self.run_loop else None)
                 hits, counts, base = ops.fused_join_hits(
                     self.points_pad, q_dev, ws, wc, self.is_zero,
                     self._q_pos(qp), eps, c=self.c, n_real=self.n_dims,
                     unicomp=False, external=True, merged=self.merged,
-                    tq=tile, keep_hits=return_pairs, run_ord=ro,
+                    keep_hits=return_pairs, run_ord=ro,
                     run_loop=self.run_loop, method=method,
                     metric=self.metric, n_feat=self.n_feat)
             launches.append(_FusedLaunch(
                 rows=None, n_rows=n_queries, hits=hits, counts=counts,
-                base=base, ws=ws, c=self.c, tile=tile))
+                base=base, ws=ws, c=self.c))
         else:
             with span("query.sync"):
                 caps = np.asarray(_window_caps(wc))[:n_queries]
@@ -636,9 +626,8 @@ class PreparedJoin:
                 rows = np.flatnonzero((cls == k) & (caps > 0))
                 if not rows.size:
                     continue   # empty class (or all-miss rows: counts stay 0)
-                tile = self.tiles[cb]
                 with span("query.launch"):
-                    qp_b = bucket_rows(rows.size, tile)
+                    qp_b = bucket_rows(rows.size)
                     sel = np.zeros(qp_b, np.int32)
                     sel[:rows.size] = rows
                     ws_b, wc_b, q_b = _bucket_select(
@@ -646,18 +635,18 @@ class PreparedJoin:
                         jnp.asarray(rows.size, jnp.int32))
                     # rows ascend batch order, so equal-cell rows stay
                     # contiguous within the class launch
-                    ro = (self._launch_run_ord(gid[rows], qp_b, tile)
+                    ro = (self._launch_run_ord(gid[rows], qp_b)
                           if self.run_loop else None)
                     hits, counts, base = ops.fused_join_hits(
                         self.points_pad, q_b, ws_b, wc_b, self.is_zero,
                         self._q_pos(qp_b), eps, c=cb, n_real=self.n_dims,
                         unicomp=False, external=True, merged=self.merged,
-                        tq=tile, keep_hits=return_pairs, run_ord=ro,
+                        keep_hits=return_pairs, run_ord=ro,
                         run_loop=self.run_loop, method=method,
                         metric=self.metric, n_feat=self.n_feat)
                 launches.append(_FusedLaunch(
                     rows=rows, n_rows=rows.size, hits=hits, counts=counts,
-                    base=base, ws=ws_b, c=cb, tile=tile))
+                    base=base, ws=ws_b, c=cb))
         return PendingJoin(
             self, launches, wc=wc, qp=qp, n_queries=n_queries,
             return_pairs=return_pairs, sort_pairs=sort_pairs, emit=emit,
@@ -737,12 +726,11 @@ class PreparedJoin:
             q_pad = jnp.zeros((qp, int(self.points_pad.shape[1])),
                               self.dtype)
             for cb in self.classes:
-                tile = self.tiles[cb]
-                s = tile
+                s = TQ_DEFAULT
                 # ladder bound: ANY request landing in this request bucket
                 # (up to qp rows, not just n) may put all its rows in one
-                # class, so warm class launches up to bucket_rows(qp, tile)
-                while s <= bucket_rows(qp, tile):
+                # class, so warm class launches up to qp rows
+                while s <= qp:
                     ws_b, wc_b, q_b = _bucket_select(
                         ws, wc, q_pad, jnp.zeros((s,), jnp.int32),
                         jnp.asarray(0, jnp.int32))
@@ -754,7 +742,7 @@ class PreparedJoin:
                             self.points_pad, q_b, ws_b, wc_b, self.is_zero,
                             self._q_pos(s), self.refine, c=cb,
                             n_real=self.n_dims, unicomp=False,
-                            external=True, merged=self.merged, tq=tile,
+                            external=True, merged=self.merged,
                             keep_hits=keep,
                             run_ord=(self._q_pos(s) if self.run_loop
                                      else None),
@@ -762,8 +750,6 @@ class PreparedJoin:
                             metric=self.metric, n_feat=self.n_feat)
                         np.asarray(counts)   # block: compile now, not later
                     s *= 2
-        # single-class requests pad with _TQ too (class tiles are clamped
-        # to _TQ at construction, so _TQ is always the padding unit)
         return bucket_rows(n)
 
 

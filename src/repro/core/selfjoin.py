@@ -12,39 +12,40 @@ atomics, so we restructure the same computation as an **offset sweep**
             candidates = A[start[nbr[rank_i]] : +count]  (padded to C_max slots)
             hits       = ||q_i - cand||^2 <= eps^2       (masked)
 
-The candidate distance evaluation is the compute hot-spot; it is pluggable
-(``distance_impl``):
+The candidate distance evaluation runs in ONE path, the fused sweep of
+kernels/fused_join.py: the gather happens INSIDE the kernel (window
+descriptors via scalar prefetch, HBM->VMEM dynamic slice per window), all
+stencil offsets sweep in ONE launch with the query tile VMEM-resident
+throughout, and count+fill share a single distance evaluation per
+candidate: the kernel returns the masked hit set plus per-query counts and
+the per-tile exclusive-scan slot bases, so the fill phase only scatters
+(DESIGN.md S4). No (B, C, n) intermediate exists. Launches are
+occupancy-bucketed (DESIGN.md S6): query rows partition by
+candidate-capacity class (grid.occupancy_plan) and each bucket sweeps at
+ITS static window capacity, so skewed data stops paying the global
+max_per_cell per row. Every launch uses the kernel's query tile
+``fused_join.TQ_DEFAULT``.
 
-  'jnp'    -- reference: gather the (B, C, n) candidate tensor, evaluate.
-  'pallas' -- kernels/cell_join.py refine over the same gathered tensor.
-  'fused'  -- kernels/fused_join.py: the gather happens INSIDE the kernel
-              (window descriptors via scalar prefetch, HBM->VMEM dynamic
-              slice per window), all stencil offsets sweep in ONE launch
-              with the query tile VMEM-resident throughout, and count+fill
-              share a single distance evaluation per candidate: the kernel
-              returns the masked hit set plus per-query counts and the
-              per-tile exclusive-scan slot bases, so the fill phase only
-              scatters (DESIGN.md S4). No (B, C, n) intermediate exists.
-              Launches are occupancy-bucketed (DESIGN.md S6): query rows
-              partition by candidate-capacity class (grid.occupancy_plan)
-              and each bucket sweeps at ITS static window capacity, so
-              skewed data stops paying the global max_per_cell per row;
-              tiles and the count route come from the measured tables in
-              kernels/autotune.py.
+The join's decisions come from the index and the backend, never from a
+measurement: the sweep (merged 3^(n-1) ranges, or per-cell 3^n where the
+coordinates leave no free pad lane) from ``n_dims`` (``_resolve_merge``),
+the cell-run loop from mean cell occupancy (``_join_run_loop``), and the
+count route ('dense', or 'compact' on the TPU in the empty-neighbour
+regime) from the grid's volume, cell count and largest cell
+(``_count_route``). The ``jnp``
+gather helpers below serve only ``per_point_neighbor_counts`` and the
+distributed count step; ``core/brute.py`` is the independent oracle.
 
-Result emission replaces the paper's atomics with a two-phase
-count -> exclusive-scan -> scatter fill ('jnp'/'pallas'; every distance is
-computed twice) or the fused single-pass count -> fill above. The paper
-sorts the key/value result after the kernel, and we optionally do the same.
-Batching over query points (paper SV-A) bounds both the result buffer and
-the per-batch hit set; the driver ``self_join_batched`` uses >= 3 batches
-like the paper and overlaps device compute with host transfers via JAX
-async dispatch.
+Result emission replaces the paper's atomics with the fused single-pass
+count -> fill above. The paper sorts the key/value result after the
+kernel, and we optionally do the same. Batching over query points (paper
+SV-A) bounds both the result buffer and the per-batch hit set; the driver
+``self_join_batched`` uses >= 3 batches like the paper and overlaps device
+compute with host transfers via JAX async dispatch.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Optional
 
@@ -67,20 +68,11 @@ class JoinStats:
     cells_visited: int        # non-empty adjacent cells evaluated
     candidates_checked: int   # candidate slots with a real point
     offsets: int              # stencil offsets swept
-    # sweep chosen by the routing table (kernels/autotune.py):
+    # count route (``_count_route``):
     #   'dense'     occupancy-bucketed fused sweep (full window per probe)
-    #   'dense-run' fused sweep with cell-run DMA dedup (DESIGN.md S11)
-    #   'compact'   per-offset live-query packing before the gather (TPU)
-    #   'sparse'    probe-compacted counter (empty-neighbor regime, off-TPU)
-    #   'jnp'       reference dense counter (fused plan measured slower)
+    #   'compact'   per-offset live-query packing before the gather (TPU,
+    #               empty-neighbour regime)
     route: str = "dense"
-    # cell-run DMA accounting (DESIGN.md S11): window gathers the fused
-    # sweep issued across all launches and offsets (n_off * runs with the
-    # run loop, n_off * rows without), and the HBM->VMEM traffic the run
-    # loop avoided vs one gather per row. Host-side analytic counters,
-    # exact for the kernel's DMA schedule on any backend.
-    dma_windows_issued: int = 0
-    dma_bytes_saved: int = 0
 
     @property
     def n_offsets(self) -> int:
@@ -147,16 +139,6 @@ def _distance_hits_jnp(q, cand, valid, eps):
     return metric_lib.l2_sq_hits(d2, eps) & valid
 
 
-def _get_distance_impl(name: str):
-    if name == "jnp":
-        return _distance_hits_jnp
-    if name == "pallas":
-        from repro.kernels.ops import cell_join_hits
-
-        return cell_join_hits
-    raise ValueError(f"unknown distance_impl {name!r}")
-
-
 def _gather_batch(index: GridIndex, nbr_rank_cells, q_start, q_size, max_per_cell):
     """Candidate window of each query in the batch under one stencil offset.
 
@@ -180,129 +162,12 @@ def _gather_batch(index: GridIndex, nbr_rank_cells, q_start, q_size, max_per_cel
     return q, cand, cand_pos_c, valid, q_pos_c, q_ok
 
 
-@partial(
-    jax.jit,
-    static_argnames=("q_size", "max_per_cell", "unicomp", "distance_impl"),
-)
-def _count_batch(
-    index: GridIndex,
-    deltas: jax.Array,
-    is_zero: jax.Array,
-    q_start: jax.Array,
-    *,
-    q_size: int,
-    max_per_cell: int,
-    unicomp: bool,
-    distance_impl: str = "jnp",
-):
-    """Count phase: ordered-pair total + work counters for one query batch."""
-    hits_fn = _get_distance_impl(distance_impl)
-    eps = index.eps
-
-    def body(carry, xs):
-        total, cells, cands = carry
-        delta, zero = xs
-        nbr_cells = _neighbor_ranks_for_delta(index, delta)
-        q, cand, cand_pos, valid, q_pos, q_ok = _gather_batch(
-            index, nbr_cells, q_start, q_size, max_per_cell
-        )
-        hits = hits_fn(q, cand, valid, eps)
-        if unicomp:
-            # o = 0: strict upper triangle within the cell; o != 0: all pairs.
-            # Every hit is an unordered pair -> contributes 2 ordered pairs.
-            tri = cand_pos > q_pos[:, None]
-            hits = hits & jnp.where(zero, tri, True)
-            n_ordered = 2 * hits.sum()
-        else:
-            # full stencil: each ordered pair found exactly once; drop self.
-            hits = hits & (cand_pos != q_pos[:, None])
-            n_ordered = hits.sum()
-        # work counters (paper Table II analogue)
-        valid_rank = index.point_cell_rank[
-            jnp.minimum(
-                q_start + jnp.arange(q_size, dtype=jnp.int32), index.num_points - 1
-            )
-        ]
-        visited = (nbr_cells[valid_rank] >= 0) & q_ok
-        return (
-            total + n_ordered,
-            cells + visited.sum(),
-            cands + valid.sum(),
-        ), None
-
-    init = (jnp.zeros((), jnp.int64),) * 3
-    (total, cells, cands), _ = jax.lax.scan(body, init, (deltas, is_zero))
-    return total, cells, cands
-
-
-@partial(
-    jax.jit,
-    static_argnames=("q_size", "max_per_cell", "unicomp", "capacity", "distance_impl"),
-)
-def _fill_batch(
-    index: GridIndex,
-    deltas: jax.Array,
-    is_zero: jax.Array,
-    q_start: jax.Array,
-    *,
-    q_size: int,
-    max_per_cell: int,
-    unicomp: bool,
-    capacity: int,
-    distance_impl: str = "jnp",
-):
-    """Fill phase: emit ordered pairs (original point ids) into a flat buffer.
-
-    The paper's kernel appends through a global atomic and sorts afterwards;
-    we compute each hit's output slot with a cumulative sum (deterministic)
-    and scatter. Returns (keys, vals, count); slots >= count are PAD (-1).
-    """
-    hits_fn = _get_distance_impl(distance_impl)
-    eps = index.eps
-    orig_id = index.order  # sorted position -> original point id
-
-    def body(carry, xs):
-        cursor, keys, vals = carry
-        delta, zero = xs
-        nbr_cells = _neighbor_ranks_for_delta(index, delta)
-        q, cand, cand_pos, valid, q_pos, _ = _gather_batch(
-            index, nbr_cells, q_start, q_size, max_per_cell
-        )
-        hits = hits_fn(q, cand, valid, eps)
-        if unicomp:
-            tri = cand_pos > q_pos[:, None]
-            hits = hits & jnp.where(zero, tri, True)
-        else:
-            hits = hits & (cand_pos != q_pos[:, None])
-        flat = hits.reshape(-1)
-        rel = jnp.cumsum(flat.astype(jnp.int64)) - 1      # position among hits
-        n_hits = jnp.where(flat.shape[0] > 0, rel[-1] + 1, 0)
-        qid = jnp.broadcast_to(orig_id[q_pos][:, None], hits.shape).reshape(-1)
-        cid = orig_id[cand_pos].reshape(-1)
-        if unicomp:
-            pos_fwd = cursor + 2 * rel
-            pos_rev = pos_fwd + 1
-            idx_fwd = jnp.where(flat, pos_fwd, capacity)
-            idx_rev = jnp.where(flat, pos_rev, capacity)
-            keys = keys.at[idx_fwd].set(qid, mode="drop")
-            vals = vals.at[idx_fwd].set(cid, mode="drop")
-            keys = keys.at[idx_rev].set(cid, mode="drop")
-            vals = vals.at[idx_rev].set(qid, mode="drop")
-            cursor = cursor + 2 * n_hits
-        else:
-            pos = cursor + rel
-            idx = jnp.where(flat, pos, capacity)
-            keys = keys.at[idx].set(qid, mode="drop")
-            vals = vals.at[idx].set(cid, mode="drop")
-            cursor = cursor + n_hits
-        return (cursor, keys, vals), None
-
-    keys0 = jnp.full((capacity,), -1, jnp.int32)
-    vals0 = jnp.full((capacity,), -1, jnp.int32)
-    (count, keys, vals), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.int64), keys0, vals0), (deltas, is_zero)
-    )
-    return keys, vals, count
+def _check_impl(distance_impl: str) -> None:
+    """The fused sweep is the join's only path; the keyword stays for
+    callers that name it."""
+    if distance_impl != "fused":
+        raise ValueError(f"unknown distance_impl {distance_impl!r}; the "
+                         f"fused sweep is the only path")
 
 
 def _resolve_index(points, eps, index: Optional[GridIndex]) -> GridIndex:
@@ -313,11 +178,10 @@ def _resolve_index(points, eps, index: Optional[GridIndex]) -> GridIndex:
 
 
 # ---------------------------------------------------------------------------
-# Fused path (distance_impl='fused'): single-pass count -> fill around
-# kernels/fused_join.py. One kernel launch sweeps every stencil offset; the
-# fill reuses the count pass's hit set / per-tile totals, so each candidate
-# distance is evaluated exactly once and the (B, C, n) gathered intermediate
-# of the unfused sweep never exists (DESIGN.md S4).
+# Fused path: single-pass count -> fill around kernels/fused_join.py. One
+# kernel launch sweeps every stencil offset; the fill reuses the count
+# pass's hit set / per-tile totals, so each candidate distance is evaluated
+# exactly once and no (B, C, n) gathered intermediate exists (DESIGN.md S4).
 #
 # Occupancy bucketing (DESIGN.md S6): instead of ONE launch padded to the
 # global max_per_cell, query rows are partitioned by candidate-capacity
@@ -325,15 +189,9 @@ def _resolve_index(points, eps, index: Optional[GridIndex]) -> GridIndex:
 # window capacity -- on skewed data most rows live in the small classes, so
 # the padding-lane distance evaluations of the single-capacity sweep
 # disappear. Per-bucket counts/slot bases compose back into the same
-# single-pass count -> fill contract; the query tile per (backend, n_dims,
-# capacity) class comes from the measured table in kernels/autotune.py.
+# single-pass count -> fill contract. Every launch tiles its query rows by
+# the kernel's ``fused_join.TQ_DEFAULT``.
 # ---------------------------------------------------------------------------
-
-def _fused_tile(index: GridIndex, c: int) -> int:
-    from repro.kernels import autotune
-
-    return autotune.fused_tile(index.n_dims, c)
-
 
 @partial(jax.jit, static_argnames=("qp", "q_limit", "merged"))
 def _fused_prep(index: GridIndex, points_pad: jax.Array, deltas: jax.Array,
@@ -400,7 +258,7 @@ def _fused_bucket_prep(index: GridIndex, points_pad: jax.Array,
 
 
 def _fused_pad(index: GridIndex, *, q_size: int, c: int,
-               q_start_max: int = 0, tq: int = 128, merged: bool = False,
+               q_start_max: int = 0, merged: bool = False,
                gid=None, feats=None):
     """One padded-points copy shared by every batch of a sweep. The tail
     covers the C-slot window reads and the worst batch's rounded-up query
@@ -411,7 +269,9 @@ def _fused_pad(index: GridIndex, *, q_size: int, c: int,
     rides the per-point global id in the next free lane. ``feats``
     (metric feature payload in SORTED point order, DESIGN.md S12) rides
     immediately after the coordinate lanes."""
-    qp = _round_up(max(q_size, 1), tq)
+    from repro.kernels.fused_join import TQ_DEFAULT
+
+    qp = _round_up(max(q_size, 1), TQ_DEFAULT)
     tail = max(c, q_start_max + qp - index.num_points)
     return _pad_sweep_points(index, gid, feats, tail=tail,
                              merged=merged), qp
@@ -440,7 +300,7 @@ def _host_cell_ranks(index: GridIndex) -> np.ndarray:
 
 
 def _launch_run_plan(index: GridIndex, sel: Optional[np.ndarray],
-                     q_start: int, *, qp: int, tile: int):
+                     q_start: int, *, qp: int):
     """Cell-run plan of one fused launch (DESIGN.md S11).
 
     Row identities are the queries' cell RANKS at the same clamped
@@ -451,6 +311,7 @@ def _launch_run_plan(index: GridIndex, sel: Optional[np.ndarray],
     masks every slot of a count-0 window).
     """
     from repro.core.grid import cell_run_plan
+    from repro.kernels.fused_join import TQ_DEFAULT
 
     rank = _host_cell_ranks(index)
     npts = index.num_points
@@ -459,7 +320,7 @@ def _launch_run_plan(index: GridIndex, sel: Optional[np.ndarray],
     else:
         pos = np.zeros(qp, np.int64)
         pos[:sel.shape[0]] = sel
-    return cell_run_plan(rank[np.minimum(pos, npts - 1)], tile)
+    return cell_run_plan(rank[np.minimum(pos, npts - 1)], TQ_DEFAULT)
 
 
 @partial(jax.jit, static_argnames=("qp", "q_limit"))
@@ -507,7 +368,7 @@ def _fused_table_bucket_prep(index: GridIndex, points_pad: jax.Array,
 def _fused_batch_run(index: GridIndex, points_pad, deltas, is_zero, q_start,
                      *, qp: int, q_size: int, c: int, unicomp: bool,
                      keep_hits: bool, method: Optional[str] = None,
-                     tq: int = 128, merged: bool = False,
+                     merged: bool = False,
                      gid_pairs: bool = False, run_plan=None,
                      metric: str = "l2", n_feat: int = 0,
                      refine_eps=None):
@@ -540,7 +401,7 @@ def _fused_batch_run(index: GridIndex, points_pad, deltas, is_zero, q_start,
     hits, counts, base = ops.fused_join_hits(
         points_pad, q_batch, ws, wc, is_zero.astype(jnp.int32), q_pos,
         index.eps if refine_eps is None else refine_eps,
-        c=c, n_real=index.n_dims, unicomp=unicomp, tq=tq,
+        c=c, n_real=index.n_dims, unicomp=unicomp,
         merged=merged, gid_pairs=gid_pairs, keep_hits=keep_hits,
         run_ord=None if run_plan is None else jnp.asarray(run_plan.run_ord),
         run_loop=run_plan is not None, method=method, metric=metric,
@@ -551,7 +412,7 @@ def _fused_batch_run(index: GridIndex, points_pad, deltas, is_zero, q_start,
 def _fused_bucket_launch(index: GridIndex, points_pad, deltas, is_zero,
                          sel: np.ndarray, *, qp: int, c: int, unicomp: bool,
                          keep_hits: bool, method: Optional[str] = None,
-                         tq: int = 128, merged: bool = False,
+                         merged: bool = False,
                          gid_pairs: bool = False, run_plan=None,
                          metric: str = "l2", n_feat: int = 0,
                          refine_eps=None):
@@ -579,7 +440,7 @@ def _fused_bucket_launch(index: GridIndex, points_pad, deltas, is_zero,
     hits, counts, base = ops.fused_join_hits(
         points_pad, q_batch, ws, wc, is_zero.astype(jnp.int32), q_pos,
         index.eps if refine_eps is None else refine_eps,
-        c=c, n_real=index.n_dims, unicomp=unicomp, tq=tq,
+        c=c, n_real=index.n_dims, unicomp=unicomp,
         merged=merged, gid_pairs=gid_pairs, keep_hits=keep_hits,
         run_ord=None if run_plan is None else jnp.asarray(run_plan.run_ord),
         run_loop=run_plan is not None, method=method, metric=metric,
@@ -587,9 +448,9 @@ def _fused_bucket_launch(index: GridIndex, points_pad, deltas, is_zero,
     return ws, wc, wcells, hits, counts, base, q_pos
 
 
-@partial(jax.jit, static_argnames=("c", "tq", "unicomp", "capacity"))
+@partial(jax.jit, static_argnames=("c", "unicomp", "capacity"))
 def _emit_from_hits(index: GridIndex, ids, hits, counts, slot_base,
-                    win_start, q_pos, *, c: int, tq: int, unicomp: bool,
+                    win_start, q_pos, *, c: int, unicomp: bool,
                     capacity: int):
     """Fill phase of the fused path: scatter pairs from the count pass's hit
     set. No distances here -- positions come from the window descriptors and
@@ -599,6 +460,8 @@ def _emit_from_hits(index: GridIndex, ids, hits, counts, slot_base,
     bucket selection); ``ids`` maps sorted positions to emitted point ids
     (``index.order`` for the single-device join, the slab's GLOBAL id
     array for the distributed join)."""
+    from repro.kernels.fused_join import TQ_DEFAULT as tq
+
     n_off, qp, _ = hits.shape
     npts = index.num_points
     orig = ids
@@ -693,7 +556,7 @@ def _fused_launches(index: GridIndex, *, n_batches: int,
     """The launch schedule of one fused sweep: occupancy buckets (each
     chunked to the batching bound), or contiguous batches when the plan is
     a single class. Returns (launches, points_pad, c_max) where every
-    launch is (sel|None, q_start, q_size, qp, c, tile). ``merged``
+    launch is (sel|None, q_start, q_size, qp, c). ``merged``
     schedules against the merged range-window capacities (DESIGN.md S7)
     and pads the points copy with the boundary-mask coordinate lane.
 
@@ -727,23 +590,23 @@ def _fused_launches(index: GridIndex, *, n_batches: int,
     launches = []
     if plan is None or plan.sel[0] is None:
         cap = c_glob if plan is None else plan.caps[0]
-        tile = _fused_tile(index, cap)
         points_pad, qp = _fused_pad(
-            index, q_size=batch_rows, c=c_glob, tq=tile,
+            index, q_size=batch_rows, c=c_glob,
             q_start_max=(n_batches - 1) * batch_rows, merged=merged,
             gid=gid, feats=feats)
         for b in range(n_batches):
             q_size = min(batch_rows, npts - b * batch_rows)
-            launches.append((None, b * batch_rows, q_size, qp, cap, tile))
+            launches.append((None, b * batch_rows, q_size, qp, cap))
         return launches, points_pad, c_glob
+    from repro.kernels.fused_join import TQ_DEFAULT
+
     points_pad, _ = _fused_pad(index, q_size=1, c=c_glob, merged=merged,
                                gid=gid, feats=feats)
     for cap, sel in zip(plan.caps, plan.sel):
-        tile = _fused_tile(index, cap)
         for i in range(0, sel.shape[0], batch_rows):
             piece = sel[i:i + batch_rows]
-            qp = _round_up(piece.shape[0], tile)
-            launches.append((piece, 0, piece.shape[0], qp, cap, tile))
+            qp = _round_up(piece.shape[0], TQ_DEFAULT)
+            launches.append((piece, 0, piece.shape[0], qp, cap))
     return launches, points_pad, c_glob
 
 
@@ -751,16 +614,16 @@ def _join_run_loop(index: GridIndex) -> bool:
     """Default run-loop decision for the pair-emitting fused join
     (DESIGN.md S11): sharing one window gather across a run only saves
     traffic when cells hold >= 2 queries on average -- below that, runs
-    degenerate to rows and the run bookkeeping is pure overhead. The
-    COUNT path instead races 'dense-run' as a measured autotune candidate;
-    bit-parity with the row loop is guaranteed (and CI-gated) either way,
-    so this is purely a performance choice.
+    degenerate to rows and the run bookkeeping is pure overhead. The count
+    path always runs the row loop. Bit-parity with the row loop is
+    guaranteed (and CI-gated) either way, so this is purely a performance
+    choice.
     """
     return index.num_points >= 2 * max(int(index.num_cells), 1)
 
 
 def _self_join_fused(index: GridIndex, **kw):
-    """Single-pass count -> fill driver for distance_impl='fused'.
+    """Single-pass count -> fill driver of the fused sweep.
 
     Per launch (an occupancy bucket chunk, or a contiguous batch when the
     capacity plan collapses to one class): one fused sweep produces the hit
@@ -880,7 +743,7 @@ def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
         launch's kernel (already dispatched, JAX async) overlaps the
         transfer -- the paper's SV-A compute/copy overlap, kept on the
         fused path."""
-        ws, hits, counts, base, q_pos, cap, tile = run
+        ws, hits, counts, base, q_pos, cap = run
         total = counts.sum(dtype=jnp.int64)
         yield
         with span("selfjoin.sync"):
@@ -895,7 +758,7 @@ def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
         with span("selfjoin.emit"):
             keys, vals, cnt = _emit_from_hits(
                 index, ids_dev, hits, counts, base, ws, q_pos,
-                c=cap, tq=tile, unicomp=unicomp, capacity=capacity)
+                c=cap, unicomp=unicomp, capacity=capacity)
         yield
         with span("selfjoin.emit"):
             with span("selfjoin.sync"):
@@ -906,18 +769,17 @@ def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
 
     chunks = []
     prev = None
-    for sel, q_start, q_size, qp, cap, tile in launches:
+    for sel, q_start, q_size, qp, cap in launches:
         plan = None
         if run_loop:
             with span("selfjoin.run_plan"):
-                plan = _launch_run_plan(index, sel, q_start, qp=qp,
-                                        tile=tile)
+                plan = _launch_run_plan(index, sel, q_start, qp=qp)
         with span("selfjoin.launch"):
             if sel is None:
                 ws, _, _, hits, counts, base, q_pos = _fused_batch_run(
                     index, points_pad, deltas, is_zero, q_start, qp=qp,
                     q_size=q_size, c=cap, unicomp=unicomp,
-                    keep_hits=True, method=method, tq=tile,
+                    keep_hits=True, method=method,
                     merged=merged, gid_pairs=gid_pairs, run_plan=plan,
                     metric=metric, n_feat=n_feat, refine_eps=refine_eps)
             else:
@@ -925,13 +787,13 @@ def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
                     _fused_bucket_launch(
                         index, points_pad, deltas, is_zero, sel, qp=qp,
                         c=cap, unicomp=unicomp, keep_hits=True,
-                        method=method, tq=tile, merged=merged,
+                        method=method, merged=merged,
                         gid_pairs=gid_pairs, run_plan=plan,
                         metric=metric, n_feat=n_feat,
                         refine_eps=refine_eps)
         if prev is not None:
             chunks.append((yield from finish(prev)))
-        prev = (ws, hits, counts, base, q_pos, cap, tile)
+        prev = (ws, hits, counts, base, q_pos, cap)
     if prev is not None:
         chunks.append((yield from finish(prev)))
     from repro.analysis import sanitize
@@ -952,7 +814,6 @@ def _self_join_count_fused(index: GridIndex, *, unicomp: bool,
                            row_ok: Optional[np.ndarray] = None,
                            ids: Optional[np.ndarray] = None,
                            gid_pairs: bool = False,
-                           run_loop: bool = False,
                            metric: str = "l2", n_feat: int = 0,
                            feats=None, refine_eps=None) -> JoinStats:
     """Count-only fused sweep (keep_hits=False: no O(n_off*Q*C) buffer).
@@ -966,16 +827,8 @@ def _self_join_count_fused(index: GridIndex, *, unicomp: bool,
     per-cell sweep (a merged window's cell count and length are exactly
     the sum of its constituent per-cell windows'); only ``offsets``
     shrinks to 3^(n-1).
-
-    ``run_loop`` (the 'dense-run' route, DESIGN.md S11) dedups the window
-    DMA per cell run; totals and work counters are bit-identical to the
-    row loop, and the DMA counters in the returned stats record the
-    schedule actually issued (``dma_windows_issued``) plus the gather
-    traffic avoided vs one window per row (``dma_bytes_saved``).
     """
     from repro.core.grid import global_window_cap
-    from repro.kernels.fused_join import LANES
-    from repro.kernels.ops import _kernel_dtype
 
     if merged:
         deltas, is_zero = _merged_offset_tables(index, unicomp)
@@ -989,43 +842,30 @@ def _self_join_count_fused(index: GridIndex, *, unicomp: bool,
            if gid_pairs else None)
     if query_batch:
         c = global_window_cap(index, merged)
-        tile = _fused_tile(index, c)
         q_size = int(query_batch)
         points_pad, qp = _fused_pad(
-            index, q_size=q_size, c=c, tq=tile,
+            index, q_size=q_size, c=c,
             q_start_max=((npts - 1) // q_size) * q_size, merged=merged,
             gid=gid, feats=feats)
-        launches = [(None, q_start, min(q_size, npts - q_start), qp, c, tile)
+        launches = [(None, q_start, min(q_size, npts - q_start), qp, c)
                     for q_start in range(0, npts, q_size)]
     else:
         launches, points_pad, _ = _fused_launches(
             index, n_batches=1, bucketed=bucketed, merged=merged,
             row_ok=row_ok, gid=gid, feats=feats)
     total = cells = cands = 0
-    dma_windows = dma_saved = 0
-    np_pad = LANES                 # each window DMA moves full rows
-    dtype_bytes = np.dtype(_kernel_dtype(points_pad.dtype)).itemsize
-    for sel, q_start, q_size, qp, cap, tile in launches:
-        plan = (_launch_run_plan(index, sel, q_start, qp=qp, tile=tile)
-                if run_loop else None)
-        if plan is None:
-            dma_windows += n_off * qp
-        else:
-            dma_windows += n_off * plan.n_runs
-            dma_saved += (n_off * (qp - plan.n_runs)
-                          * cap * np_pad * dtype_bytes)
+    for sel, q_start, q_size, qp, cap in launches:
         if sel is None:
             _, wc, wcells, _, counts, _, _ = _fused_batch_run(
                 index, points_pad, deltas, is_zero, q_start, qp=qp,
                 q_size=q_size, c=cap, unicomp=unicomp, keep_hits=False,
-                method=method, tq=tile, merged=merged, gid_pairs=gid_pairs,
-                run_plan=plan, metric=metric, n_feat=n_feat,
-                refine_eps=refine_eps)
+                method=method, merged=merged, gid_pairs=gid_pairs,
+                metric=metric, n_feat=n_feat, refine_eps=refine_eps)
         else:
             _, wc, wcells, _, counts, _, _ = _fused_bucket_launch(
                 index, points_pad, deltas, is_zero, sel, qp=qp, c=cap,
-                unicomp=unicomp, keep_hits=False, method=method, tq=tile,
-                merged=merged, gid_pairs=gid_pairs, run_plan=plan,
+                unicomp=unicomp, keep_hits=False, method=method,
+                merged=merged, gid_pairs=gid_pairs,
                 metric=metric, n_feat=n_feat, refine_eps=refine_eps)
         total += mult * int(counts.sum(dtype=jnp.int64))
         cells += int(wcells.sum(dtype=jnp.int64))
@@ -1037,9 +877,6 @@ def _self_join_count_fused(index: GridIndex, *, unicomp: bool,
         cells_visited=cells,
         candidates_checked=cands,
         offsets=n_off,
-        route="dense-run" if run_loop else "dense",
-        dma_windows_issued=dma_windows,
-        dma_bytes_saved=dma_saved,
     )
 
 
@@ -1069,8 +906,8 @@ def dma_window_stats(index: GridIndex, *, unicomp: bool = True,
     dtype_bytes = np.dtype(_kernel_dtype(points_pad.dtype)).itemsize
     rows = runs = saved = 0
     hist: dict = {}
-    for sel, q_start, q_size, qp, cap, tile in launches:
-        plan = _launch_run_plan(index, sel, q_start, qp=qp, tile=tile)
+    for sel, q_start, q_size, qp, cap in launches:
+        plan = _launch_run_plan(index, sel, q_start, qp=qp)
         rows += n_off * qp
         runs += n_off * plan.n_runs
         saved += n_off * (qp - plan.n_runs) * cap * np_pad * dtype_bytes
@@ -1089,378 +926,7 @@ def dma_window_stats(index: GridIndex, *, unicomp: bool = True,
     }
 
 
-@partial(jax.jit, static_argnames=("qp",))
-def _rank_plane_search(keys, rank_arr, deltas, *, qp: int):
-    """(n_off, qp) rank-in-B of every (query, offset) probe; -1 = miss.
-
-    Searchsorted formulation (any key-space size): one batched binary
-    search over the probe plane and NOTHING else -- window start/count
-    gathers are deferred to the packed live probes, so the mostly-dead
-    plane never materializes beyond one int32 rank array.
-    """
-    npts = keys.shape[0]
-    q_pos = jnp.arange(qp, dtype=jnp.int32)
-    q_ok = q_pos < npts
-    own = keys[rank_arr[jnp.minimum(q_pos, npts - 1)]]
-    qk = own[None, :] + deltas[:, None]
-    pos = jnp.minimum(jnp.searchsorted(keys, qk).astype(jnp.int32), npts - 1)
-    hit = (keys[pos] == qk) & q_ok[None, :]
-    return jnp.where(hit, pos, -1)
-
-
-@partial(jax.jit, static_argnames=("qp",))
-def _rank_plane_table(table, cell_keys, rank_arr, deltas32, *, qp: int):
-    """Rank plane via a dense key -> rank lookup table: a pure GATHER.
-
-    The paper binary-searches B precisely to avoid O(prod(dims)) memory;
-    when the key space is small (fine low-volume grids, the uniform-6d
-    bench regime) a dense int32 table costs a few MB and replaces the
-    probe plane's dominant cost -- 3.7M binary searches on uniform-6d --
-    with one gather (measured ~80x faster on this container).
-    """
-    vol = table.shape[0]
-    npts = rank_arr.shape[0]
-    q_pos = jnp.arange(qp, dtype=jnp.int32)
-    own = cell_keys[rank_arr[jnp.minimum(q_pos, npts - 1)]].astype(jnp.int32)
-    own = jnp.where(q_pos < npts, own, -(1 << 30))
-    qk = own[None, :] + deltas32[:, None]
-    ok = (qk >= 0) & (qk < vol)
-    return jnp.where(ok, table[jnp.clip(qk, 0, vol - 1)], -1)
-
-
-@partial(jax.jit, static_argnames=("qp",))
-def _range_plane_search(keys, rank_arr, deltas, lo_off, hi_off, dim_last,
-                        *, qp: int):
-    """(n_off, qp) merged-range rank spans: one searchsorted PAIR per
-    reduced offset over the probe plane (DESIGN.md S7).
-
-    Returns (lo_rank, hi_rank); a probe is live iff hi_rank > lo_rank.
-    The last-dimension span clamps at the grid row exactly like
-    ``grid.range_window_descriptors_at``.
-    """
-    npts = keys.shape[0]
-    q_pos = jnp.arange(qp, dtype=jnp.int32)
-    q_ok = q_pos < npts
-    own = keys[rank_arr[jnp.minimum(q_pos, npts - 1)]]
-    q_last = own % dim_last
-    base = own[None, :] + deltas[:, None]
-    lo = jnp.maximum(lo_off[:, None], -q_last[None, :])
-    hi = jnp.minimum(hi_off[:, None], dim_last - 1 - q_last[None, :])
-    lo_rank = jnp.searchsorted(keys, base + lo, side="left").astype(jnp.int32)
-    hi_rank = jnp.searchsorted(keys, base + hi,
-                               side="right").astype(jnp.int32)
-    hi_rank = jnp.where(q_ok[None, :], hi_rank, lo_rank)   # pad rows dead
-    return lo_rank, hi_rank
-
-
-@partial(jax.jit, static_argnames=("qp",))
-def _range_plane_table(table, cell_keys, rank_arr, deltas32, lo_off, hi_off,
-                       dim_last, *, qp: int):
-    """Merged-range rank spans via the dense key -> rank table: three plane
-    GATHERS (one per last-dimension slot) instead of binary searches.
-
-    Within a merged span the only possible keys are base + {-1, 0, +1}, so
-    the span's rank range is [min present probed rank, max present probed
-    rank + 1] -- contiguity of the span makes the min/max reconstruction
-    exact.
-    """
-    vol = table.shape[0]
-    npts = rank_arr.shape[0]
-    q_pos = jnp.arange(qp, dtype=jnp.int32)
-    own = cell_keys[rank_arr[jnp.minimum(q_pos, npts - 1)]].astype(jnp.int32)
-    q_last = own % dim_last
-    own = jnp.where(q_pos < npts, own, -(1 << 30))
-    base = own[None, :] + deltas32[:, None]
-    big = jnp.asarray(1 << 30, jnp.int32)
-    lo_rank = jnp.full(base.shape, big, jnp.int32)
-    hi_rank = jnp.full(base.shape, -1, jnp.int32)
-    for d in (-1, 0, 1):
-        qk = base + d
-        in_span = ((d >= lo_off[:, None]) & (d <= hi_off[:, None])
-                   & (q_last[None, :] + d >= 0)
-                   & (q_last[None, :] + d < dim_last))
-        ok = in_span & (qk >= 0) & (qk < vol)
-        r = jnp.where(ok, table[jnp.clip(qk, 0, vol - 1)], -1)
-        present = r >= 0
-        lo_rank = jnp.where(present, jnp.minimum(lo_rank, r), lo_rank)
-        hi_rank = jnp.where(present, jnp.maximum(hi_rank, r), hi_rank)
-    live = hi_rank >= 0
-    lo_rank = jnp.where(live, lo_rank, 0)
-    hi_rank = jnp.where(live, hi_rank + 1, 0)
-    return lo_rank, hi_rank
-
-
-def _sparse_lookup(index: GridIndex):
-    """Cached per index: ('table', dense key->rank table) when the key
-    space fits the budget, else ('keys', int32-or-int64 B).
-
-    The int32 downcast of B applies when every probe key ``own + delta``
-    fits (prod(dims) < 2^30): the PAD_KEY sentinel maps to int32 max,
-    preserving sort order and never matching a probe; int32 halves the
-    binary search's bandwidth.
-    """
-    from repro.core import grid as grid_lib
-    from repro.core.grid import index_cached, pad_key_for
-
-    def build():
-        volume = grid_lib.grid_volume(index)
-        ncells = int(index.num_cells)
-        if volume <= grid_lib._LOOKUP_MAX_CELLS:
-            keys = np.asarray(index.cell_keys[:ncells])
-            table = np.full(volume, -1, np.int32)
-            # a padded build (build_grid_with_geometry valid=...) carries a
-            # sentinel cell with key == prod(dims) (== table length), and
-            # out-of-geometry points can produce keys outside [0, volume);
-            # keep those cells out of the scatter -- probes to them miss,
-            # and padding points were never reachable as candidates anyway
-            ok = (keys >= 0) & (keys < volume)
-            table[keys[ok]] = np.arange(ncells, dtype=np.int32)[ok]
-            return ("table", jnp.asarray(table))
-        if volume < float(1 << 30):
-            k = np.asarray(index.cell_keys)
-            if k.dtype == np.int32:
-                # int32 key fast path: B already carries the right sentinel
-                return ("keys", index.cell_keys)
-            k = k.copy()
-            k[k == pad_key_for(k.dtype)] = pad_key_for(np.dtype(np.int32))
-            return ("keys", jnp.asarray(k.astype(np.int32)))
-        return ("keys", index.cell_keys)
-
-    return index_cached(index, "sparse_lookup", build)
-
-
-@partial(jax.jit, static_argnames=("c", "unicomp"))
-def _count_probes_span(points_sorted, eps, p_start, p_count, p_qpos, p_zero,
-                       *, c: int, unicomp: bool):
-    """Distance evaluation over PACKED probes carrying explicit point
-    spans: window start / count arrive precomputed (single-cell windows
-    on the per-cell sparse path, rank spans on the merged path), so the
-    one probe evaluator serves both sweeps. Padding probes carry
-    count 0."""
-    npts = points_sorted.shape[0]
-    slots = jnp.arange(c, dtype=jnp.int32)
-    cand_pos = jnp.minimum(p_start[:, None] + slots[None, :], npts - 1)
-    valid = slots[None, :] < p_count[:, None]
-    q = points_sorted[jnp.minimum(p_qpos, npts - 1)]
-    d2 = jnp.zeros(cand_pos.shape, points_sorted.dtype)
-    for dim in range(points_sorted.shape[1]):
-        cd = jnp.take(points_sorted[:, dim], cand_pos)
-        d2 = d2 + (q[:, dim][:, None] - cd) ** 2
-    hit = metric_lib.l2_sq_hits(d2, eps) & valid
-    if unicomp:
-        tri = cand_pos > p_qpos[:, None]
-        hit = hit & jnp.where(p_zero[:, None] != 0, tri, True)
-    else:
-        hit = hit & (cand_pos != p_qpos[:, None])
-    return hit.sum(dtype=jnp.int64)
-
-
-def _self_join_count_sparse(index: GridIndex, *, unicomp: bool,
-                            method: Optional[str] = None,
-                            merged: bool = True) -> JoinStats:
-    """Probe-compacted counter for the empty-neighbor regime (route
-    'sparse').
-
-    In high dimensionality >90% of (query, offset) probes hit an EMPTY
-    neighbor cell, yet the dense sweep still evaluates a full capacity-C
-    window of padding for each -- the uniform-6d regression (fused count
-    0.67x of jnp before this route existed). Three moves make this route
-    beat even the jnp scan there: the descriptor pass shrinks to a bare
-    rank plane (a dense key->rank lookup table when prod(dims) fits the
-    memory budget -- one gather instead of 3.7M binary searches -- else one
-    batched searchsorted with int32 keys when they fit), the plane is
-    compacted ONCE on the host (``np.nonzero`` -- the count is host-driven
-    anyway), and distances + window gathers run only over the packed live
-    probes, so eval work scales with actual candidate volume. Work
-    counters match the dense sweep's by construction (same probe plane).
-    Unlike 'compact' (per-offset argsort packing, a TPU-only win), the
-    single flat compaction amortizes across the whole stencil.
-
-    ``merged`` (default) compacts the 3^(n-1) merged-range plane
-    (DESIGN.md S7): rank SPANS per probe (searchsorted pair, or three
-    table gathers), each packed probe evaluating one contiguous point
-    span. The plane shrinks 3x in the offset axis and probes get 3x
-    likelier to be live, so the same candidate volume packs into far
-    fewer, longer windows.
-    """
-    del method  # probe evaluation is a jnp op; no kernel variant yet
-    from repro.core.grid import global_window_cap
-
-    npts = index.num_points
-    mult = 2 if unicomp else 1
-    qp = _round_up(max(npts, 1), 128)
-    kind, lookup = _sparse_lookup(index)
-    if merged:
-        dtab, is_zero = _merged_offset_tables(index, unicomp)
-        n_off = int(dtab.shape[1])
-        c = global_window_cap(index, merged=True)
-        dim_last = int(np.asarray(index.dims)[-1])
-        if kind == "table":
-            lo_rank, hi_rank = _range_plane_table(
-                lookup, index.cell_keys, index.point_cell_rank,
-                dtab[0].astype(jnp.int32), dtab[1].astype(jnp.int32),
-                dtab[2].astype(jnp.int32),
-                jnp.asarray(dim_last, jnp.int32), qp=qp)
-        else:
-            dt = lookup.dtype
-            lo_rank, hi_rank = _range_plane_search(
-                lookup, index.point_cell_rank, dtab[0].astype(dt),
-                dtab[1].astype(dt), dtab[2].astype(dt),
-                jnp.asarray(dim_last, dt), qp=qp)
-        from repro.core.grid import starts_ext
-
-        lo_rank, hi_rank = np.asarray(lo_rank), np.asarray(hi_rank)
-        ext = starts_ext(index)
-        off, q = np.nonzero(hi_rank > lo_rank)
-        n_live = off.shape[0]
-        lo_l, hi_l = lo_rank[off, q], hi_rank[off, q]
-        w_start = ext[lo_l]
-        w_count = ext[hi_l] - w_start
-        cells = int((hi_l - lo_l).sum(dtype=np.int64)) if n_live else 0
-        total = 0
-        cands = int(w_count.sum(dtype=np.int64)) if n_live else 0
-        if n_live:
-            from repro.core.grid import capacity_classes
-
-            is_zero_np = np.asarray(is_zero).astype(np.int32)
-            q_np, off_np = q, off
-            # Merged spans vary 1..3 cells, so a single global capacity
-            # would pad every probe to the worst ADJACENT-TRIPLE occupancy
-            # (~3x the per-cell max on clustered data). Class the packed
-            # probes by pow2 window length instead -- the sparse-route
-            # analogue of the occupancy buckets: total padded slots stay
-            # within 2x of the true candidate volume at O(log C) compiles.
-            ladder = np.asarray(capacity_classes(c, 8))
-            cls = np.searchsorted(
-                ladder, np.minimum(_round_up(w_count, 8), int(ladder[-1])))
-            chunk = 1 << 17
-            for k, ccap in enumerate(ladder):
-                rows = np.flatnonzero(cls == k)
-                for i in range(0, rows.shape[0], chunk):
-                    sel = rows[i:i + chunk]
-                    m = sel.shape[0]
-                    cap = min(chunk, max(_next_pow2(m), 128))
-                    p_start = np.zeros(cap, np.int32)
-                    p_count = np.zeros(cap, np.int32)
-                    p_qpos = np.zeros(cap, np.int32)
-                    p_zero = np.zeros(cap, np.int32)
-                    p_start[:m] = w_start[sel]
-                    p_count[:m] = w_count[sel]
-                    p_qpos[:m] = q_np[sel]
-                    p_zero[:m] = is_zero_np[off_np[sel]]
-                    total += int(_count_probes_span(
-                        index.points_sorted, index.eps,
-                        jnp.asarray(p_start), jnp.asarray(p_count),
-                        jnp.asarray(p_qpos), jnp.asarray(p_zero),
-                        c=int(ccap), unicomp=unicomp))
-        return JoinStats(
-            total_pairs=mult * total,
-            cells_visited=cells,
-            candidates_checked=cands,
-            offsets=n_off,
-            route="sparse",
-        )
-    deltas, is_zero = _offset_tables(index, unicomp)
-    c = _round_up(max(int(index.max_per_cell), 1), 8)
-    if kind == "table":
-        nbr = np.asarray(_rank_plane_table(
-            lookup, index.cell_keys, index.point_cell_rank,
-            deltas.astype(jnp.int32), qp=qp))
-    else:
-        nbr = np.asarray(_rank_plane_search(
-            lookup, index.point_cell_rank, deltas.astype(lookup.dtype),
-            qp=qp))
-    off, q = np.nonzero(nbr >= 0)
-    n_live = off.shape[0]
-    cc_np = np.asarray(index.cell_count)
-    cs_np = np.asarray(index.cell_start)
-    total = 0
-    cands = 0
-    if n_live:
-        is_zero_np = np.asarray(is_zero).astype(np.int32)
-        chunk = 1 << 17   # bounds the (P, C) eval; pow2 pads bound compiles
-        for i in range(0, n_live, chunk):
-            o_c, q_c = off[i:i + chunk], q[i:i + chunk]
-            m = o_c.shape[0]
-            cap = min(chunk, max(_next_pow2(m), 128))
-            p_start = np.zeros(cap, np.int32)
-            p_count = np.zeros(cap, np.int32)
-            p_qpos = np.zeros(cap, np.int32)
-            p_zero = np.zeros(cap, np.int32)
-            live_nbr = nbr[o_c, q_c]
-            p_start[:m] = cs_np[live_nbr]
-            p_count[:m] = cc_np[live_nbr]
-            p_qpos[:m] = q_c
-            p_zero[:m] = is_zero_np[o_c]
-            cands += int(cc_np[live_nbr].sum(dtype=np.int64))
-            total += int(_count_probes_span(
-                index.points_sorted, index.eps, jnp.asarray(p_start),
-                jnp.asarray(p_count), jnp.asarray(p_qpos),
-                jnp.asarray(p_zero), c=c, unicomp=unicomp))
-    return JoinStats(
-        total_pairs=mult * total,
-        cells_visited=n_live,
-        candidates_checked=cands,
-        offsets=int(deltas.shape[0]),
-        route="sparse",
-    )
-
-
-def _route_features(index: GridIndex, deltas) -> dict:
-    """Cheap host-side workload features for the routing table.
-
-    ``occupancy`` is the global live-cell fraction (the PR-2 proxy, kept
-    for the TPU rule); ``live_frac`` is the SAMPLED per-query live-probe
-    fraction under the actual stencil -- occupancy is a poor estimator on
-    clustered data, where a query's probes concentrate in its own (live)
-    neighborhood.
-    """
-    npts = index.num_points
-    with span("selfjoin.sync"):
-        ncells = max(int(index.num_cells), 1)
-        c = max(int(index.max_per_cell), 1)
-        if npts:
-            keys = np.asarray(index.cell_keys[:ncells])
-            rank = np.asarray(index.point_cell_rank)
-    # float prod: a fine 6-D grid overflows int64, and the heuristic only
-    # needs a ratio
-    volume = max(float(np.prod(np.asarray(index.dims, dtype=np.float64))), 1.0)
-    occupancy = ncells / volume
-    live_frac = 0.0
-    if npts:
-        sample = rank[::-(-npts // 1024)][:1024]   # ceil stride: spans all
-                                                   # of sorted key order
-        probe = keys[sample][None, :] + np.asarray(deltas)[:, None]
-        pos = np.minimum(np.searchsorted(keys, probe), ncells - 1)
-        live_frac = float((keys[pos] == probe).mean())
-    return {"occupancy": occupancy, "live_frac": live_frac, "c": c}
-
-
-def _fused_count_route(index: GridIndex, n_off: int,
-                       backend: Optional[str] = None, *,
-                       unicomp: bool = True) -> str:
-    """Heuristic route for the fused counter (no cache consulted).
-
-    The measured routing table (kernels/autotune.py, consulted by
-    ``self_join_count``) supersedes this wherever it has been populated;
-    this function is the deterministic fallback and the unit-testable
-    regime detector. See ``autotune.route_heuristic`` for the rules.
-    """
-    from repro.kernels import autotune
-
-    deltas, _ = _offset_tables(index, unicomp)
-    feats = _route_features(index, deltas)
-    if backend is None:
-        backend = jax.default_backend()
-    return autotune.route_heuristic(
-        backend, index.n_dims, n_off, feats["c"], feats["occupancy"],
-        feats["live_frac"])
-
-
-@partial(
-    jax.jit,
-    static_argnames=("cap_q", "max_per_cell", "unicomp", "distance_impl"),
-)
+@partial(jax.jit, static_argnames=("cap_q", "max_per_cell", "unicomp"))
 def _count_compact(
     index: GridIndex,
     deltas: jax.Array,          # o != 0 offsets only
@@ -1468,7 +934,6 @@ def _count_compact(
     cap_q: int,
     max_per_cell: int,
     unicomp: bool,
-    distance_impl: str = "jnp",
 ):
     """Compacted sweep over the non-zero stencil offsets.
 
@@ -1480,17 +945,16 @@ def _count_compact(
     *actual* candidate volume. ``cap_q`` is exact: the driver computes
     max-over-offsets of the live-query count from the host grid, so no
     overflow is possible. The o=0 (own cell) pass stays dense -- every query
-    is live there.
+    is live there. The refine is gather-free: candidate POSITIONS go in,
+    the per-dim coordinate reads stay inside ``ops.fused_window_hits``.
     """
-    fused = distance_impl == "fused"
-    hits_fn = None if fused else _get_distance_impl(distance_impl)
-    eps = index.eps
+    from repro.kernels.ops import fused_window_hits
+
     npts = index.num_points
 
     def body(carry, delta):
         total, slots = carry
         nbr_cells = _neighbor_ranks_for_delta(index, delta)
-        q_pos_all = jnp.arange(npts, dtype=jnp.int32)
         rank = index.point_cell_rank
         nbr_all = nbr_cells[rank]                     # (|D|,)
         live = nbr_all >= 0
@@ -1505,16 +969,8 @@ def _count_compact(
         cand_pos = jnp.minimum(start[:, None] + sl[None, :], npts - 1)
         valid = sl[None, :] < count[:, None]
         q = index.points_sorted[q_pos]
-        if fused:
-            # gather-free refine: candidate POSITIONS go in, the per-dim
-            # coordinate reads stay inside the op (kernels/fused_join.py)
-            from repro.kernels.ops import fused_window_hits
-
-            hits = fused_window_hits(index.points_sorted, q, cand_pos,
-                                     valid, eps)
-        else:
-            cand = index.points_sorted[cand_pos]
-            hits = hits_fn(q, cand, valid, eps)
+        hits = fused_window_hits(index.points_sorted, q, cand_pos, valid,
+                                 index.eps)
         if unicomp:
             n = 2 * hits.sum()
         else:
@@ -1549,40 +1005,63 @@ def self_join_count_compact(
     *,
     unicomp: bool = True,
     index: Optional[GridIndex] = None,
-    distance_impl: str = "jnp",
+    distance_impl: str = "fused",
 ) -> JoinStats:
-    """self_join_count with empty-neighbor compaction (beyond-paper opt)."""
+    """self_join_count with empty-neighbor compaction (beyond-paper opt):
+    a dense fused pass over the own-cell offset, then ``_count_compact``
+    over the others."""
+    _check_impl(distance_impl)
     index = _resolve_index(points, eps, index)
     max_per_cell = _round_up(max(int(index.max_per_cell), 1), 8)
     deltas, is_zero = _offset_tables(index, unicomp)
     cap_q = _round_up(compact_cap(index, unicomp), 128)
     # o = 0 dense pass (every query is live in its own cell)
-    if distance_impl == "fused":
-        tile = _fused_tile(index, max_per_cell)
-        points_pad, qp = _fused_pad(
-            index, q_size=index.num_points, c=max_per_cell, tq=tile)
-        _, wc0, _, _, counts0, _, _ = _fused_batch_run(
-            index, points_pad, deltas[:1], is_zero[:1], 0, qp=qp,
-            q_size=index.num_points, c=max_per_cell, unicomp=unicomp,
-            keep_hits=False, tq=tile)
-        t0 = (2 if unicomp else 1) * int(counts0.sum(dtype=jnp.int64))
-        k0 = int(wc0.sum(dtype=jnp.int64))
-    else:
-        t0, _, k0 = _count_batch(
-            index, deltas[:1], is_zero[:1], jnp.asarray(0, jnp.int32),
-            q_size=index.num_points, max_per_cell=max_per_cell,
-            unicomp=unicomp, distance_impl=distance_impl)
+    points_pad, qp = _fused_pad(index, q_size=index.num_points,
+                                c=max_per_cell)
+    _, wc0, _, _, counts0, _, _ = _fused_batch_run(
+        index, points_pad, deltas[:1], is_zero[:1], 0, qp=qp,
+        q_size=index.num_points, c=max_per_cell, unicomp=unicomp,
+        keep_hits=False)
+    t0 = (2 if unicomp else 1) * int(counts0.sum(dtype=jnp.int64))
+    k0 = int(wc0.sum(dtype=jnp.int64))
     tn, slots = _count_compact(
         index, deltas[1:], cap_q=min(cap_q, index.num_points),
-        max_per_cell=max_per_cell, unicomp=unicomp,
-        distance_impl=distance_impl)
+        max_per_cell=max_per_cell, unicomp=unicomp)
     return JoinStats(
-        total_pairs=int(t0) + int(tn),
+        total_pairs=t0 + int(tn),
         cells_visited=0,
-        candidates_checked=int(k0) + int(slots),
+        candidates_checked=k0 + int(slots),
         offsets=int(deltas.shape[0]),
         route="compact",
     )
+
+
+def _count_route(index: GridIndex, backend: str, *, unicomp: bool,
+                 merged: bool) -> str:
+    """The count sweep's route, read off the index: 'compact' on the TPU
+    where most (query, offset) probes find an empty cell -- the probes a
+    point makes over the per-cell stencil (``vol``) times the grid's
+    occupied share under 3 -- and the dense windows are wide enough to
+    pay for the packing (``vol`` times the largest cell >= 256); 'dense'
+    otherwise, and everywhere off the TPU, where the packing sort costs
+    more than the padding it saves."""
+    if backend != "tpu":
+        return "dense"
+    from repro.core.grid import host_dims
+    from repro.core.stencil import merged_stencil_offsets
+
+    if merged:   # each merged offset spans three per-cell ones
+        vol = 3 * merged_stencil_offsets(index.n_dims, unicomp)[0].shape[0]
+    else:
+        vol = stencil_offsets(index.n_dims, unicomp).shape[0]
+    # float prod: a fine 6-D grid overflows int64, and the rule needs a
+    # ratio only
+    volume = max(float(np.prod(host_dims(index), dtype=np.float64)), 1.0)
+    occupancy = max(int(index.num_cells), 1) / volume
+    c = max(int(index.max_per_cell), 1)
+    if vol * occupancy < 3.0 and vol * c >= 256:
+        return "compact"
+    return "dense"
 
 
 def self_join_count(
@@ -1591,9 +1070,8 @@ def self_join_count(
     *,
     unicomp: bool = True,
     index: Optional[GridIndex] = None,
-    distance_impl: str = "jnp",
+    distance_impl: str = "fused",
     query_batch: Optional[int] = None,
-    route: Optional[str] = None,
     bucketed: Optional[bool] = None,
     merge_last_dim: Optional[bool] = None,
     metric: str = "l2",
@@ -1601,59 +1079,35 @@ def self_join_count(
 ) -> JoinStats:
     """Total ordered-pair count + work counters (no materialized result).
 
-    With ``distance_impl='fused'`` the sweep is auto-routed through the
-    measured routing table (kernels/autotune.py): a cached measured winner
-    for the workload class when one exists, a timed pass over the live
-    candidates when tuning is enabled ($REPRO_AUTOTUNE=1), the occupancy
-    heuristic otherwise. Routes: 'dense' (occupancy-bucketed fused sweep),
-    'dense-run' (the same sweep with cell-run DMA dedup, DESIGN.md S11;
-    measured-only -- the heuristic never picks it), 'compact' (per-offset
-    live-query packing, TPU), 'sparse' (probe-compacted counter for the
-    empty-neighbor regime), 'jnp' (reference dense counter -- the floor:
-    routing can never pin a fused plan that
-    measures slower than the baseline). The chosen path is logged in
-    ``JoinStats.route``; pass ``route=`` to override. 'dense'/'sparse'/
-    'jnp' report identical work counters; 'compact' reports no per-cell
-    visit counter (cells_visited=0) and checks fewer candidate slots by
-    construction. ``bucketed=False`` forces the single-capacity dense
-    sweep (parity/debug knob).
+    One fused count sweep on the route ``_count_route`` reads off the
+    index and the backend, logged in ``JoinStats.route``: 'dense' (the
+    occupancy-bucketed sweep; ``bucketed=False`` forces the
+    single-capacity launch, and an explicit ``query_batch`` runs
+    contiguous batches of that many rows at the global capacity) or, on
+    the TPU in the empty-neighbour regime and without ``query_batch``,
+    'compact' (``self_join_count_compact``: per-offset live-query
+    packing; it reports no per-cell visit counter, cells_visited=0).
 
-    ``merge_last_dim`` (default on) runs the fused 'dense'/'sparse'
-    routes over the 3^(n-1) merged-range stencil (DESIGN.md S7);
-    ``merge_last_dim=False`` keeps the per-cell 3^n sweep as the parity
-    oracle. Totals and cells/candidates counters are identical either
-    way; only ``offsets`` changes. The measured routing table covers the
-    SWEEP axis too: 'dense-flat' / 'sparse-flat' run the per-cell sweep
-    when it measured faster for the workload class (clustered data in low
-    dimensionality, where merged windows pay ~3x capacity padding for
-    only a small offset saving); the heuristic fallback never picks them.
-    'compact' (a TPU per-offset packing) and the 'jnp' reference always
-    sweep per cell.
+    ``merge_last_dim`` (default on) runs the dense sweep over the 3^(n-1)
+    merged-range stencil (DESIGN.md S7); ``merge_last_dim=False`` keeps
+    the per-cell 3^n sweep as the parity oracle. Totals and
+    cells/candidates counters are identical either way; only ``offsets``
+    changes. 'compact' always sweeps per cell.
 
     ``metric`` / ``vocab`` as in ``self_join`` (DESIGN.md S12): cosine
-    canonicalizes onto the unit sphere and counts with the full L2
-    routing machinery; jaccard always runs the fused dense sweep over
-    the 1-D size grid (the only route whose kernel carries the bitmap
-    refine predicate).
+    canonicalizes onto the unit sphere and counts as L2 does; jaccard
+    always runs the dense sweep over the 1-D size grid (the kernel carries
+    the bitmap refine). ``distance_impl`` accepts only 'fused'.
     """
-    routes = (None, "dense", "compact", "sparse", "jnp", "dense-flat",
-              "sparse-flat", "dense-run")
-    if route not in routes:
-        raise ValueError(f"unknown route {route!r}; expected one of "
-                         f"{routes[1:]}")
+    _check_impl(distance_impl)
     metric_lib.check_metric(metric)
     if metric != "l2" or isinstance(points, metric_lib.Canonical):
         canon = _metric_canonical(points, eps, metric, vocab)
         if canon.metric == "jaccard":
-            if route not in (None, "dense", "dense-run"):
-                raise ValueError(
-                    f"route {route!r} does not support metric='jaccard'; "
-                    f"only the fused dense sweep carries the bitmap refine")
             idx = _metric_grid(canon)
             return _self_join_count_fused(
                 idx, unicomp=unicomp, query_batch=query_batch,
-                bucketed=bucketed, merged=False,
-                run_loop=route == "dense-run", metric="jaccard",
+                bucketed=bucketed, merged=False, metric="jaccard",
                 n_feat=canon.n_feat, feats=_metric_feats_sorted(canon, idx),
                 refine_eps=canon.eps)
         if canon.metric == "cosine":
@@ -1661,152 +1115,22 @@ def self_join_count(
         points, eps = canon.geom, canon.eps_geom
     index = _resolve_index(points, eps, index)
     merged = _resolve_merge(index, merge_last_dim)
-    route_label = "dense"
-    if distance_impl == "fused":
-        if route is None:
-            if query_batch is not None:
-                route = "dense"
-            else:
-                route = _auto_route(index, unicomp=unicomp,
-                                    bucketed=bucketed, merged=merged)
-        if route == "compact":
-            return self_join_count_compact(
-                points, eps, unicomp=unicomp, index=index,
-                distance_impl="fused")
-        if route in ("sparse", "sparse-flat"):
-            return dataclasses.replace(
-                _self_join_count_sparse(
-                    index, unicomp=unicomp,
-                    merged=merged and route == "sparse"),
-                route=route)
-        if route in ("dense", "dense-flat", "dense-run"):
-            return dataclasses.replace(
-                _self_join_count_fused(
-                    index, unicomp=unicomp, query_batch=query_batch,
-                    bucketed=bucketed,
-                    merged=merged and route != "dense-flat",
-                    run_loop=route == "dense-run"),
-                route=route)
-        # route == 'jnp': the fused plan measured slower than the reference
-        # dense counter for this workload class -- run that, log the route.
-        distance_impl = "jnp"
-        route_label = "jnp"
-    npts = index.num_points
-    deltas, is_zero = _offset_tables(index, unicomp)
-    max_per_cell = _round_up(max(int(index.max_per_cell), 1), 8)
-    q_size = int(query_batch) if query_batch else npts
-    total = cells = cands = 0
-    for q_start in range(0, npts, q_size):
-        t, c, k = _count_batch(
-            index,
-            deltas,
-            is_zero,
-            jnp.asarray(q_start, jnp.int32),
-            q_size=q_size,
-            max_per_cell=max_per_cell,
-            unicomp=unicomp,
-            distance_impl=distance_impl,
-        )
-        total += int(t)
-        cells += int(c)
-        cands += int(k)
-    return JoinStats(
-        total_pairs=total,
-        cells_visited=cells,
-        candidates_checked=cands,
-        offsets=int(deltas.shape[0]),
-        route=route_label,
-    )
+    if query_batch is None and _count_route(
+            index, jax.default_backend(), unicomp=unicomp,
+            merged=merged) == "compact":
+        return self_join_count_compact(points, eps, unicomp=unicomp,
+                                       index=index)
+    return _self_join_count_fused(
+        index, unicomp=unicomp, query_batch=query_batch, bucketed=bucketed,
+        merged=merged)
 
 
-def _join_sweep_merged(index: GridIndex, *, unicomp: bool,
-                       bucketed: Optional[bool], merged: bool) -> bool:
-    """Sweep choice for the pair-emitting join: follow the measured count
-    route's verdict ONLY when it judged the join's own sweep. The join
-    always runs the dense bucketed sweep, so a measured 'dense-flat'
-    winner (per-cell dense beat merged dense for this workload class)
-    transfers directly; a 'sparse-flat' winner is a verdict about the
-    probe-compacted COUNTER's table-vs-span tradeoff and says nothing
-    about the dense sweep -- the merged default stands there, as it does
-    on the heuristic tier (which never returns '-flat'). Exact either way
-    -- the S7 parity guarantee is what licenses the switch."""
-    if not merged:
-        return False
+def _join_sweep(index: GridIndex, merge_last_dim: Optional[bool]) -> bool:
+    """The pair-emitting join's sweep (merged or per-cell), decided from
+    the index alone under the ``selfjoin.route`` span: no device value is
+    fetched."""
     with span("selfjoin.route"):
-        route = _auto_route(index, unicomp=unicomp, bucketed=bucketed,
-                            merged=True)
-    return route != "dense-flat"
-
-
-def _auto_route(index: GridIndex, *, unicomp: bool,
-                bucketed: Optional[bool] = None,
-                merged: bool = False) -> str:
-    """Consult the routing table; measure the live candidates if tuning is
-    enabled; fall back to the occupancy heuristic. The decision is a pure
-    function of the index + sweep mode, so it is cached per index object:
-    steady-state fused counts pay a dict lookup, not the sampled feature
-    probe."""
-    from repro.core.grid import index_cached
-
-    return index_cached(
-        index, f"route/{unicomp}/{bucketed}/{merged}",
-        lambda: _auto_route_uncached(index, unicomp=unicomp,
-                                     bucketed=bucketed, merged=merged))
-
-
-def _auto_route_uncached(index: GridIndex, *, unicomp: bool,
-                         bucketed: Optional[bool] = None,
-                         merged: bool = False) -> str:
-    from repro.kernels import autotune
-
-    # workload features come from the per-cell stencil either way -- they
-    # describe the data's neighbor regime, not the sweep; the MERGED
-    # sweep's n_off keys a separate table row (its candidates run merged)
-    deltas, _ = _offset_tables(index, unicomp)
-    feats = _route_features(index, deltas)
-    if merged:
-        dtab, _ = _merged_offset_tables(index, unicomp)
-        n_off = int(dtab.shape[1])
-    else:
-        n_off = int(deltas.shape[0])
-    candidates = None
-    if autotune.measure_enabled():
-        candidates = {
-            "dense": lambda: _self_join_count_fused(
-                index, unicomp=unicomp, bucketed=bucketed, merged=merged),
-            "sparse": lambda: _self_join_count_sparse(
-                index, unicomp=unicomp, merged=merged),
-            "jnp": lambda: self_join_count(
-                index.points_sorted, index.eps, unicomp=unicomp,
-                index=index, distance_impl="jnp"),
-        }
-        if merged:
-            # the sweep itself is a measured axis: clustered data in low
-            # dimensionality can pay more in merged-window capacity
-            # padding than the 3x offset reduction saves, so the per-cell
-            # sweep competes for the slot (pair sets are identical either
-            # way -- the S7 parity guarantee is what makes the sweep a
-            # pure routing decision)
-            candidates["dense-flat"] = lambda: _self_join_count_fused(
-                index, unicomp=unicomp, bucketed=bucketed, merged=False)
-            candidates["sparse-flat"] = lambda: _self_join_count_sparse(
-                index, unicomp=unicomp, merged=False)
-            # cell-run DMA dedup (DESIGN.md S11) competes for the same
-            # slot: totals are bit-identical to 'dense', so the run loop
-            # is a pure measured tradeoff (run bookkeeping + per-cell
-            # table gather vs one window DMA per query row)
-            candidates["dense-run"] = lambda: _self_join_count_fused(
-                index, unicomp=unicomp, bucketed=bucketed, merged=True,
-                run_loop=True)
-        if jax.default_backend() == "tpu":
-            candidates["compact"] = lambda: self_join_count_compact(
-                index.points_sorted, index.eps, unicomp=unicomp,
-                index=index, distance_impl="fused")
-    route, _src = autotune.count_route(
-        n_dims=index.n_dims, n_off=n_off, c=feats["c"],
-        occupancy=feats["occupancy"], live_frac=feats["live_frac"],
-        merged=merged, candidates=candidates)
-    return route
+        return _resolve_merge(index, merge_last_dim)
 
 
 def _metric_canonical(points, eps, metric: str,
@@ -1864,12 +1188,9 @@ def _metric_self_join(canon: metric_lib.Canonical, *, unicomp: bool,
             bucketed=bucketed, merged=False, metric="jaccard",
             n_feat=canon.n_feat, feats=_metric_feats_sorted(canon, index),
             refine_eps=canon.eps)
-    merged = _join_sweep_merged(
-        index, unicomp=unicomp, bucketed=bucketed,
-        merged=_resolve_merge(index, None))
     return _self_join_fused(
         index, unicomp=unicomp, sort_result=sort_result, bucketed=bucketed,
-        merged=merged, metric=canon.metric)
+        merged=_join_sweep(index, None), metric=canon.metric)
 
 
 def self_join(
@@ -1878,7 +1199,7 @@ def self_join(
     *,
     unicomp: bool = True,
     index: Optional[GridIndex] = None,
-    distance_impl: str = "jnp",
+    distance_impl: str = "fused",
     sort_result: bool = True,
     bucketed: Optional[bool] = None,
     merge_last_dim: Optional[bool] = None,
@@ -1887,13 +1208,13 @@ def self_join(
 ):
     """Single-batch self-join. Returns (pairs (K,2) int32 np.ndarray).
 
-    Two-phase: exact count, then fill with exactly-sized capacity
-    ('jnp'/'pallas'); single-pass count -> fill for 'fused', occupancy-
-    bucketed by default (``bucketed=False`` forces the single-capacity
-    launch; both produce the same pair set) over the merged-range stencil
-    (``merge_last_dim=False`` keeps the per-cell 3^n sweep as the parity
-    oracle; DESIGN.md S7). For the incremental / overlapped execution the
-    paper uses, see ``self_join_batched``.
+    The fused single-pass count -> fill (``_self_join_fused``),
+    occupancy-bucketed by default (``bucketed=False`` forces the
+    single-capacity launch; both produce the same pair set) over the
+    merged-range stencil (``merge_last_dim=False`` keeps the per-cell 3^n
+    sweep as the parity oracle; DESIGN.md S7). The sweep is decided from
+    the index under the ``selfjoin.route`` span. For the incremental /
+    overlapped execution the paper uses, see ``self_join_batched``.
 
     ``metric`` (DESIGN.md S12): 'l2' (default, ``eps`` is the radius),
     'cosine' (``points`` are raw embeddings, ``eps`` the minimum cosine
@@ -1901,10 +1222,11 @@ def self_join(
     iterables or an (N, V) binary matrix, ``eps`` the minimum Jaccard
     similarity in (0, 1]; ``vocab`` optionally fixes the packed
     vocabulary). ``points`` may also be a pre-built ``metric.Canonical``
-    (then pass ``eps=None``). Non-L2 metrics canonicalize, build their
-    own geometry grid, and always run the fused path; ``index`` /
-    ``distance_impl`` apply to 'l2' only.
+    (then pass ``eps=None``). Non-L2 metrics canonicalize and build their
+    own geometry grid; ``index`` applies to 'l2' only. ``distance_impl``
+    accepts only 'fused'.
     """
+    _check_impl(distance_impl)
     metric_lib.check_metric(metric)
     if metric != "l2" or isinstance(points, metric_lib.Canonical):
         canon = _metric_canonical(points, eps, metric, vocab)
@@ -1915,35 +1237,9 @@ def self_join(
                 canon, unicomp=unicomp, sort_result=sort_result,
                 bucketed=bucketed)
     index = _resolve_index(points, eps, index)
-    if distance_impl == "fused":
-        merged = _join_sweep_merged(
-            index, unicomp=unicomp, bucketed=bucketed,
-            merged=_resolve_merge(index, merge_last_dim))
-        return _self_join_fused(
-            index, unicomp=unicomp, sort_result=sort_result,
-            bucketed=bucketed, merged=merged)
-    stats = self_join_count(
-        points, eps, unicomp=unicomp, index=index, distance_impl=distance_impl
-    )
-    capacity = max(stats.total_pairs, 1)
-    deltas, is_zero = _offset_tables(index, unicomp)
-    max_per_cell = _round_up(max(int(index.max_per_cell), 1), 8)
-    keys, vals, count = _fill_batch(
-        index,
-        deltas,
-        is_zero,
-        jnp.asarray(0, jnp.int32),
-        q_size=index.num_points,
-        max_per_cell=max_per_cell,
-        unicomp=unicomp,
-        capacity=capacity,
-        distance_impl=distance_impl,
-    )
-    assert int(count) == stats.total_pairs, (int(count), stats.total_pairs)
-    pairs = np.stack([np.asarray(keys), np.asarray(vals)], axis=1)[: int(count)]
-    if sort_result:  # the paper sorts the key/value result after the kernel
-        pairs = _sort_pairs(pairs)
-    return pairs
+    return _self_join_fused(
+        index, unicomp=unicomp, sort_result=sort_result, bucketed=bucketed,
+        merged=_join_sweep(index, merge_last_dim))
 
 
 def self_join_batched(
@@ -1953,7 +1249,7 @@ def self_join_batched(
     unicomp: bool = True,
     n_batches: int = 3,
     index: Optional[GridIndex] = None,
-    distance_impl: str = "jnp",
+    distance_impl: str = "fused",
     sort_result: bool = True,
     bucketed: Optional[bool] = None,
     merge_last_dim: Optional[bool] = None,
@@ -1965,69 +1261,14 @@ def self_join_batched(
     Memory high-water is O(|D|/n_batches * C_max) intermediates + one batch
     result, instead of the full result set -- this is what lets result sets
     larger than device memory complete (paper Fig. 1 regime).
+    ``distance_impl`` accepts only 'fused'.
     """
+    _check_impl(distance_impl)
     index = _resolve_index(points, eps, index)
-    if distance_impl == "fused":
-        merged = _join_sweep_merged(
-            index, unicomp=unicomp, bucketed=bucketed,
-            merged=_resolve_merge(index, merge_last_dim))
-        return _self_join_fused(
-            index, unicomp=unicomp, sort_result=sort_result,
-            n_batches=n_batches, bucketed=bucketed, merged=merged)
-    npts = index.num_points
-    # clamp: more batches than points would schedule empty trailing batches
-    # whose rounded-up query slices cover pure padding rows (wasted
-    # launches; one compile per distinct empty shape)
-    n_batches = max(min(int(n_batches), max(npts, 1)), 1)
-    q_size = -(-npts // n_batches)  # ceil
-    deltas, is_zero = _offset_tables(index, unicomp)
-    max_per_cell = _round_up(max(int(index.max_per_cell), 1), 8)
-
-    # Phase 1: per-batch exact counts (cheap; no result materialization).
-    counts = []
-    for b in range(n_batches):
-        t, _, _ = _count_batch(
-            index,
-            deltas,
-            is_zero,
-            jnp.asarray(b * q_size, jnp.int32),
-            q_size=q_size,
-            max_per_cell=max_per_cell,
-            unicomp=unicomp,
-            distance_impl=distance_impl,
-        )
-        counts.append(t)
-    counts = [int(t) for t in counts]  # sync point
-    capacity = max(max(counts), 1)     # one fill compilation reused per batch
-
-    # Phase 2: fill batches; async dispatch overlaps batch b+1 compute with
-    # batch b's D2H transfer (np.asarray blocks only on b's buffers).
-    device_results = []
-    for b in range(n_batches):
-        keys, vals, cnt = _fill_batch(
-            index,
-            deltas,
-            is_zero,
-            jnp.asarray(b * q_size, jnp.int32),
-            q_size=q_size,
-            max_per_cell=max_per_cell,
-            unicomp=unicomp,
-            capacity=capacity,
-            distance_impl=distance_impl,
-        )
-        device_results.append((keys, vals, cnt))
-
-    out = np.empty((sum(counts), 2), dtype=np.int32)
-    pos = 0
-    for b, (keys, vals, cnt) in enumerate(device_results):
-        k = counts[b]
-        assert int(cnt) == k
-        out[pos : pos + k, 0] = np.asarray(keys)[:k]
-        out[pos : pos + k, 1] = np.asarray(vals)[:k]
-        pos += k
-    if sort_result:
-        out = _sort_pairs(out)
-    return out
+    return _self_join_fused(
+        index, unicomp=unicomp, sort_result=sort_result,
+        n_batches=n_batches, bucketed=bucketed,
+        merged=_join_sweep(index, merge_last_dim))
 
 
 def range_query(

@@ -1,14 +1,12 @@
 """Pallas TPU kernels for the join's compute hot-spots.
 
 distance_tile.py -- brute-force / refine tile (MXU formulation), count+hits
-cell_join.py     -- per-cell gathered-candidate refine (VPU formulation)
 fused_join.py    -- fused gather-refine sweep (scalar-prefetch windows,
                     in-kernel HBM->VMEM gather, count + fill slot scan)
 ops.py           -- jit'd wrappers (interpret on CPU, Mosaic on TPU)
 ref.py           -- pure-jnp oracles (tests assert allclose against these)
 """
 from repro.kernels.ops import (
-    cell_join_hits,
     distance_tile_counts,
     distance_tile_hits,
     fused_join_hits,
@@ -16,7 +14,6 @@ from repro.kernels.ops import (
 )
 
 __all__ = [
-    "cell_join_hits",
     "distance_tile_counts",
     "distance_tile_hits",
     "fused_join_hits",

@@ -1,8 +1,7 @@
 """Fused gather-refine Pallas TPU kernel (DESIGN.md S4).
 
-The unfused offset sweep (core/selfjoin.py history, kernels/cell_join.py)
-materializes a ``(B, C, n)`` gathered-candidate tensor in HBM per stencil
-offset and then evaluates distances over it -- the dominant cost is HBM
+An unfused offset sweep (gather, then refine) materializes a
+``(B, C, n)`` gathered-candidate tensor in HBM per stencil offset and then evaluates distances over it -- the dominant cost is HBM
 traffic the paper's shared-memory refine never pays. This kernel removes the
 intermediate: the *positions* of each query's candidate window (``win_start``
 / ``win_count`` from ``core.grid.window_descriptors``) arrive in SMEM, one
@@ -16,9 +15,8 @@ One ``pallas_call`` sweeps the whole stencil: the grid is
     (query tiles, stencil offsets)       -- offsets innermost
 
 so the query tile block (index map depends on the tile index only) stays
-VMEM-resident across all offsets of the sweep -- the locality
-kernels/cell_join.py's docstring promises but the per-offset dispatch of the
-unfused path could not deliver.
+VMEM-resident across all offsets of the sweep -- the locality a per-offset
+dispatch of the unfused sweep could not deliver.
 
 Per grid step the kernel fuses, per query row:
 
@@ -97,7 +95,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import metric as metric_lib
 
-NP_PAD = 8     # minimum lane padding of the coordinate axis (cell_join.py)
+NP_PAD = 8     # minimum lane padding of the coordinate axis
 LANES = 128    # TPU vreg lane width: the kernel's HBM points row width
 TQ_DEFAULT = 128  # query tile rows
 
@@ -660,9 +658,9 @@ def sanitize_errcodes(points_pad, q_batch, win_start, win_count, counts,
 def fused_window_hits(points_sorted, q, cand_pos, valid, eps):
     """Positional drop-in for selfjoin._distance_hits_jnp without the gather.
 
-    (B, n) queries x (B, C) candidate *positions* -> (B, C) bool hits; the
-    compacted sweep (selfjoin._count_compact) uses this so distance_impl=
-    'fused' never materializes the (B, C, n) candidate tensor there either.
+    (B, n) queries x (B, C) candidate *positions* -> (B, C) bool hits: the
+    refine of the 'compact' count route (selfjoin._count_compact), which
+    so never materializes the (B, C, n) candidate tensor either.
     """
     d2 = jnp.zeros(cand_pos.shape, q.dtype)
     for dim in range(q.shape[1]):

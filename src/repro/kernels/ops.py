@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis import sanitize as _sanitize
-from repro.kernels import cell_join as _cell_join
 from repro.kernels import distance_tile as _distance_tile
 from repro.kernels import fused_join as _fused_join
 from repro.kernels.fused_join import on_tpu
@@ -39,14 +38,6 @@ def distance_tile_counts(pts, eps, *, tq: int = 256, tc: int = 256):
     dt = _kernel_dtype(pts.dtype)
     return _distance_tile.distance_tile_counts(
         pts.astype(dt), eps, tq=tq, tc=tc, interpret=not on_tpu()
-    )
-
-
-def cell_join_hits(q, cand, valid, eps):
-    """Grid-cell refine: (B,n) x (B,C,n) x (B,C) -> (B,C) bool."""
-    dt = _kernel_dtype(q.dtype)
-    return _cell_join.cell_join_hits(
-        q.astype(dt), cand.astype(dt), valid, eps, interpret=not on_tpu()
     )
 
 

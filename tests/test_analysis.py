@@ -16,8 +16,10 @@ import pytest
 
 from repro.analysis import contracts, lint, sanitize
 from repro.analysis import findings as F
+from repro.core.brute import brute_force_join
 from repro.core.grid import (BucketPlan, build_grid_host, occupancy_plan,
                              sentinel_margin)
+from repro.kernels.fused_join import TQ_DEFAULT
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -296,7 +298,7 @@ class TestNoRetrace:
         pj = self._prepared()
         n = lint.count_distinct_lowerings(pj, sizes=(1, 32, 256))
         assert 0 < n <= 2 * len(pj.classes) * (
-            1 + max(0, (256 // min(pj.tiles.values())).bit_length()))
+            1 + max(0, (256 // TQ_DEFAULT).bit_length()))
 
 
 # ---------------------------------------------------------------------------
@@ -393,7 +395,7 @@ class TestSanitizer:
         from repro.core import selfjoin
 
         pts, eps = _uniform(n=150)
-        ref = selfjoin.self_join(pts, eps, distance_impl="jnp")
+        ref = brute_force_join(pts, eps)
         got = selfjoin.self_join(pts, eps, distance_impl="fused")
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
         assert sanitize.pending() == 0        # drained by the driver

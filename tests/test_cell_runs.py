@@ -20,8 +20,7 @@ from repro.core.grid import (build_grid_host, cell_run_plan,
                              range_window_descriptors_at, round_up,
                              window_descriptors_at)
 from repro.core.selfjoin import (_merged_offset_tables, _offset_tables,
-                                 _self_join_fused, dma_window_stats,
-                                 self_join_count)
+                                 _self_join_fused, dma_window_stats)
 
 TQ = 128
 
@@ -146,21 +145,6 @@ def test_self_join_run_loop_parity(merged):
                                    merged=merged, method=method,
                                    run_loop=True)
             assert np.array_equal(row, run), (name, merged, method)
-
-
-def test_count_route_dense_run_stats_parity():
-    """route='dense-run' reports the same totals AND work counters as
-    'dense', plus a DMA ledger showing no more window gathers."""
-    for name, pts, eps in datasets():
-        a = self_join_count(pts, eps, distance_impl="fused", route="dense")
-        b = self_join_count(pts, eps, distance_impl="fused",
-                            route="dense-run")
-        assert b.route == "dense-run", name
-        assert a.total_pairs == b.total_pairs, name
-        assert a.cells_visited == b.cells_visited, name
-        assert a.candidates_checked == b.candidates_checked, name
-        assert b.dma_windows_issued <= a.dma_windows_issued, name
-        assert b.dma_bytes_saved >= 0, name
 
 
 def test_dma_window_stats_ledger():

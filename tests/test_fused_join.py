@@ -1,7 +1,9 @@
-"""Parity tests for distance_impl='fused' (kernels/fused_join.py).
+"""Parity tests for the fused sweep (kernels/fused_join.py).
 
 The fused gather-refine path must produce identical pair sets and counts to
-the 'jnp' reference across every driver, including the degenerate grid
+the brute-force oracle (core/brute.py) across every driver, with work
+counters equal to the per-cell (``merge_last_dim=False``) sweep's,
+including the degenerate grid
 shapes (one point per cell, all points in one cell) and the
 empty-neighbor-heavy 6-D regime where most (query, offset) probes miss.
 The Pallas kernel itself (interpret mode off-TPU) is validated against the
@@ -11,6 +13,7 @@ in-kernel exclusive-scan slot bases.
 import numpy as np
 import pytest
 
+from repro.core.brute import brute_force_count, brute_force_join
 from repro.core.grid import build_grid_host
 from repro.core.selfjoin import (
     _fused_batch_run,
@@ -53,7 +56,7 @@ def datasets():
 @pytest.mark.parametrize("unicomp", [True, False])
 def test_fused_join_matches_jnp(unicomp):
     for name, pts, eps in datasets():
-        a = self_join(pts, eps, unicomp=unicomp, distance_impl="jnp")
+        a = brute_force_join(pts, eps)
         b = self_join(pts, eps, unicomp=unicomp, distance_impl="fused")
         assert np.array_equal(a, b), name
 
@@ -62,57 +65,32 @@ def test_fused_count_matches_jnp():
     for name, pts, eps in datasets():
         n = pts.shape[1]
         merged_off = {True: (3 ** (n - 1) + 1) // 2, False: 3 ** (n - 1)}
+        full_off = {True: (3 ** n + 1) // 2, False: 3 ** n}
+        want = brute_force_count(pts, eps)
         for unicomp in (True, False):
-            a = self_join_count(pts, eps, unicomp=unicomp)
+            # the per-cell sweep is the work counters' reference
+            a = self_join_count(pts, eps, unicomp=unicomp,
+                                merge_last_dim=False)
             b = self_join_count(pts, eps, unicomp=unicomp,
                                 distance_impl="fused")
-            assert a.total_pairs == b.total_pairs, name
-            if b.route == "compact":
-                # compacted counter: fewer slots checked by construction,
-                # no per-cell visit counter
-                assert b.candidates_checked <= a.candidates_checked, name
-            else:
-                # 'dense'/'sparse' (merged, measured '-flat' or measured
-                # '-run'), and 'jnp' all report counter-for-counter parity
-                # with the reference
-                assert b.route in ("dense", "sparse", "jnp", "dense-flat",
-                                   "sparse-flat", "dense-run"), \
-                    (name, b.route)
-                assert a.cells_visited == b.cells_visited, name
-                assert a.candidates_checked == b.candidates_checked, name
+            assert a.total_pairs == b.total_pairs == want, name
+            assert a.route == b.route == "dense", name   # off the TPU
+            assert a.cells_visited == b.cells_visited, name
+            assert a.candidates_checked == b.candidates_checked, name
             # the fused sweep defaults to the merged-range stencil: 3^(n-1)
-            # offsets (reduced UNICOMP half); the 'jnp' fallback and the
-            # measured '-flat' routes run per cell and report 3^n
-            if b.route in ("dense", "sparse"):
-                assert b.n_offsets == merged_off[unicomp], (name, b.route)
-            elif b.route.endswith("-flat"):
-                assert b.n_offsets == a.n_offsets, (name, b.route)
-            # every explicit route override agrees on the total; the
-            # counter-parity routes also agree counter-for-counter
-            for route in ("dense", "sparse", "jnp"):
-                d = self_join_count(pts, eps, unicomp=unicomp,
-                                    distance_impl="fused", route=route)
-                assert d.route == route and d.total_pairs == a.total_pairs
-                assert d.cells_visited == a.cells_visited, (name, route)
-                assert d.candidates_checked == a.candidates_checked, \
-                    (name, route)
-                if route in ("dense", "sparse"):
-                    assert d.n_offsets == merged_off[unicomp], (name, route)
-                # the per-cell oracle sweep reports the full 3^n counts
-                u = self_join_count(pts, eps, unicomp=unicomp,
-                                    distance_impl="fused", route=route,
-                                    merge_last_dim=False)
-                assert u.total_pairs == a.total_pairs, (name, route)
-                assert u.cells_visited == a.cells_visited, (name, route)
-                assert u.candidates_checked == a.candidates_checked, \
-                    (name, route)
-                if route in ("dense", "sparse"):
-                    assert u.n_offsets == a.n_offsets, (name, route)
+            # offsets (reduced UNICOMP half); the per-cell sweep 3^n
+            assert b.n_offsets == merged_off[unicomp], name
+            assert a.n_offsets == full_off[unicomp], name
+            # the single-capacity launch reports the same work
+            s = self_join_count(pts, eps, unicomp=unicomp, bucketed=False)
+            assert (s.total_pairs, s.cells_visited, s.candidates_checked) \
+                == (b.total_pairs, b.cells_visited, b.candidates_checked), \
+                name
 
 
 def test_fused_batched_matches_jnp():
     for name, pts, eps in datasets():
-        a = self_join(pts, eps, distance_impl="jnp")
+        a = brute_force_join(pts, eps)
         for nb in (2, 3, 5):
             b = self_join_batched(pts, eps, n_batches=nb,
                                   distance_impl="fused")
@@ -120,19 +98,27 @@ def test_fused_batched_matches_jnp():
 
 
 def test_fused_count_compact_matches_jnp():
+    """The 'compact' route checks exactly the per-cell sweep's live
+    candidate slots, packed, and finds the oracle's total."""
     for name, pts, eps in datasets():
+        want = brute_force_count(pts, eps)
         for unicomp in (True, False):
-            a = self_join_count_compact(pts, eps, unicomp=unicomp)
+            a = self_join_count(pts, eps, unicomp=unicomp,
+                                merge_last_dim=False)
             b = self_join_count_compact(pts, eps, unicomp=unicomp,
                                         distance_impl="fused")
-            assert a.total_pairs == b.total_pairs, (name, unicomp)
-            assert a.candidates_checked == b.candidates_checked, (name, unicomp)
+            assert b.route == "compact" and b.cells_visited == 0
+            assert b.total_pairs == want, (name, unicomp)
+            assert b.candidates_checked == a.candidates_checked, \
+                (name, unicomp)
+            assert b.n_offsets == a.n_offsets, (name, unicomp)
 
 
 def test_fused_count_query_batching():
     rng = np.random.default_rng(3)
     pts = rng.uniform(0, 10, (500, 2))
     a = self_join_count(pts, 0.7)
+    assert a.total_pairs == brute_force_count(pts, 0.7)
     for qb in (64, 130, 500):
         b = self_join_count(pts, 0.7, distance_impl="fused", query_batch=qb)
         assert a.total_pairs == b.total_pairs, qb
@@ -146,14 +132,14 @@ def test_fused_max_per_cell_one_point_per_cell():
     idx = build_grid_host(pts, 1.4)
     assert int(idx.max_per_cell) == 1
     for unicomp in (True, False):
-        a = self_join(pts, 1.4, unicomp=unicomp, distance_impl="jnp")
+        a = brute_force_join(pts, 1.4)
         b = self_join(pts, 1.4, unicomp=unicomp, distance_impl="fused")
         assert np.array_equal(a, b)
     # spacing 3 > eps: no pairs at all
     assert self_join_count(pts, 1.4, distance_impl="fused").total_pairs == 0
     # eps just over the spacing: 4-neighborhood pairs appear
     s = self_join_count(pts, 3.1, distance_impl="fused")
-    assert s.total_pairs == self_join_count(pts, 3.1).total_pairs > 0
+    assert s.total_pairs == brute_force_count(pts, 3.1) > 0
 
 
 def test_fused_max_per_cell_single_cell():
@@ -163,7 +149,7 @@ def test_fused_max_per_cell_single_cell():
     idx = build_grid_host(pts, 1.0)
     assert int(idx.max_per_cell) == 90
     for unicomp in (True, False):
-        a = self_join(pts, 1.0, unicomp=unicomp, distance_impl="jnp")
+        a = brute_force_join(pts, 1.0)
         b = self_join(pts, 1.0, unicomp=unicomp, distance_impl="fused")
         assert np.array_equal(a, b)
         assert a.shape == (90 * 89, 2)  # eps covers the whole cloud
@@ -178,7 +164,7 @@ def test_fused_tiny_and_empty():
     pts = np.array([[0.0, 0.0], [0.5, 0.0], [10.0, 10.0]])
     assert self_join_count(pts, 1.0, distance_impl="fused").total_pairs == 2
     assert np.array_equal(self_join(pts, 1.0, distance_impl="fused"),
-                          self_join(pts, 1.0, distance_impl="jnp"))
+                          brute_force_join(pts, 1.0))
 
 
 def test_fused_emit_host_equals_device():
@@ -228,11 +214,11 @@ def test_pallas_kernel_matches_reference():
 
 
 def test_pallas_kernel_join_end_to_end():
-    """Full join through the Pallas kernel path equals the jnp oracle."""
+    """Full join through the Pallas kernel path equals the oracle."""
     rng = np.random.default_rng(13)
     pts = rng.uniform(0, 10, (260, 2))
     index = build_grid_host(pts, 0.8)
-    a = self_join(pts, 0.8, distance_impl="jnp")
+    a = brute_force_join(pts, 0.8)
     b = _self_join_fused(index, unicomp=True, sort_result=True,
                          method="kernel")
     assert np.array_equal(a, b)
@@ -279,15 +265,14 @@ def test_occupancy_plan_partitions_rows():
 @pytest.mark.parametrize("unicomp", [True, False])
 def test_bucketed_join_bit_identical_to_single_capacity(unicomp):
     """Satellite gate: bucketed and single-capacity fused joins produce
-    bit-identical sorted pair sets (and match the jnp oracle)."""
+    bit-identical sorted pair sets (and match the oracle)."""
     for n_dims, eps in ((2, 0.5), (3, 0.9)):
         pts = skewed(seed=41 + n_dims, n_dims=n_dims)
         index = build_grid_host(pts, eps)
         from repro.core.grid import occupancy_plan
 
         assert occupancy_plan(index).n_buckets > 1
-        a = self_join(pts, eps, unicomp=unicomp, distance_impl="jnp",
-                      index=index)
+        a = brute_force_join(pts, eps)
         b = self_join(pts, eps, unicomp=unicomp, distance_impl="fused",
                       index=index)                      # bucketed (auto)
         s = self_join(pts, eps, unicomp=unicomp, distance_impl="fused",
@@ -296,10 +281,9 @@ def test_bucketed_join_bit_identical_to_single_capacity(unicomp):
         assert np.array_equal(a, b), (n_dims, unicomp)
         # counts: bucketed and single-capacity report identical work
         cb = self_join_count(pts, eps, unicomp=unicomp, index=index,
-                             distance_impl="fused", route="dense")
+                             distance_impl="fused")
         cs = self_join_count(pts, eps, unicomp=unicomp, index=index,
-                             distance_impl="fused", route="dense",
-                             bucketed=False)
+                             distance_impl="fused", bucketed=False)
         assert (cb.total_pairs, cb.cells_visited, cb.candidates_checked) \
             == (cs.total_pairs, cs.cells_visited, cs.candidates_checked)
 
@@ -309,7 +293,7 @@ def test_bucketed_join_batched_and_emits():
     backends."""
     pts = skewed(seed=77)
     index = build_grid_host(pts, 0.5)
-    a = self_join(pts, 0.5, distance_impl="jnp", index=index)
+    a = brute_force_join(pts, 0.5)
     for nb in (2, 4):
         b = self_join_batched(pts, 0.5, n_batches=nb,
                               distance_impl="fused", index=index)
@@ -322,46 +306,6 @@ def test_bucketed_join_batched_and_emits():
     k = _self_join_fused(index, unicomp=True, sort_result=True,
                          method="kernel")
     assert np.array_equal(k, a)
-
-
-def test_autotune_tile_and_route_cache(tmp_path, monkeypatch):
-    """kernels/autotune.py: defaults on a cold cache, measured winners
-    persisted and re-read."""
-    from repro.kernels import autotune
-
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
-                       str(tmp_path / "autotune.json"))
-    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    autotune._CACHE.reset()
-    # cold cache, measurement off: deterministic default
-    assert autotune.fused_tile(2, 16) == autotune.DEFAULT_TQ
-    # measured: winner is a candidate, persisted, and re-read from disk
-    tq = autotune.fused_tile(2, 16, measure=True)
-    assert tq in autotune.TQ_CANDIDATES
-    autotune._CACHE.reset()
-    assert autotune.fused_tile(2, 16) == tq
-    import json
-
-    data = json.loads((tmp_path / "autotune.json").read_text())
-    assert any(k.startswith("tile/") and k.endswith("/2d/c16")
-               for k in data)
-    # route: heuristic fallback, measured winner cached under the class key
-    route, src = autotune.count_route(
-        n_dims=6, n_off=365, c=3, occupancy=0.005, live_frac=0.005,
-        backend="cpu")
-    assert (route, src) == ("sparse", "heuristic")
-    calls = []
-    cands = {"dense": lambda: calls.append("dense"),
-             "jnp": lambda: calls.append("jnp")}
-    route, src = autotune.count_route(
-        n_dims=6, n_off=365, c=3, occupancy=0.005, live_frac=0.005,
-        backend="cpu", candidates=cands, measure=True)
-    assert src == "measured" and route in cands and calls
-    cached, src = autotune.count_route(
-        n_dims=6, n_off=365, c=3, occupancy=0.005, live_frac=0.005,
-        backend="cpu")
-    assert (cached, src) == (route, "cache")
-    autotune._CACHE.reset()
 
 
 @pytest.mark.parametrize("merged", [False, True])
@@ -402,6 +346,69 @@ def test_gid_pairs_kernel_matches_reference(merged, unicomp):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     counts = np.asarray(outs["reference"][4])
     mult = 2 if unicomp else 1
-    expect = self_join_count(pts, eps, index=index, unicomp=unicomp,
-                             distance_impl="jnp").total_pairs
+    expect = brute_force_count(pts, eps)
     assert mult * int(counts.sum()) == expect
+
+
+def test_join_route_span_holds_no_sync(monkeypatch):
+    """A one-chip join decides its sweep from the index alone: the
+    top-level ``selfjoin.route`` span opens once per join and no host wait
+    on a device value (``selfjoin.sync``) runs inside it."""
+    import contextlib
+
+    from repro.core import selfjoin
+
+    opened, stack = [], []
+    real = selfjoin.span
+
+    @contextlib.contextmanager
+    def recording(name):
+        opened.append((name, tuple(stack)))
+        stack.append(name)
+        try:
+            with real(name) as sp:
+                yield sp
+        finally:
+            stack.pop()
+
+    monkeypatch.setattr(selfjoin, "span", recording)
+    pts = skewed(seed=5)
+    self_join(pts, 0.5, index=build_grid_host(pts, 0.5))
+    self_join_batched(pts, 0.5, n_batches=3)
+    routes = [outer for name, outer in opened if name == "selfjoin.route"]
+    assert routes == [(), ()]
+    assert any(name == "selfjoin.sync" for name, _ in opened)
+    assert not [name for name, outer in opened
+                if "selfjoin.route" in outer]
+
+
+def test_every_launch_tiles_by_tq_default(monkeypatch):
+    """Every fused launch -- the join's buckets and batches, the count
+    sweeps, and each capacity class of a ``PreparedJoin`` -- runs the
+    kernel at ``fused_join.TQ_DEFAULT`` rows a tile."""
+    from repro.core.query_join import bucket_rows, prepare
+    from repro.kernels import fused_join
+
+    tiles = []
+    real = fused_join.fused_join_hits
+
+    def recording(*args, **kw):
+        tiles.append(kw["tq"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(fused_join, "fused_join_hits", recording)
+    pts = skewed(seed=9)
+    index = build_grid_host(pts, 0.5)
+    self_join(pts, 0.5, index=index)
+    self_join_batched(pts, 0.5, index=index, n_batches=3)
+    self_join(pts, 0.5, index=index, bucketed=False)
+    self_join_count(pts, 0.5, index=index)
+    self_join_count(pts, 0.5, index=index, query_batch=200)
+    self_join_count_compact(pts, 0.5, index=index)
+    n_join = len(tiles)
+    pj = prepare(index)
+    assert pj.bucketed
+    pj.join(pts[:300])
+    assert pj.warm(256) == bucket_rows(256)
+    assert n_join > 6 and len(tiles) > n_join
+    assert set(tiles) == {fused_join.TQ_DEFAULT}

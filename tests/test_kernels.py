@@ -3,7 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import cell_join, distance_tile, ref
+from repro.kernels import distance_tile, ops, ref
 
 
 DIMS = [2, 3, 4, 5, 6]
@@ -72,24 +72,31 @@ def test_distance_tile_bf16_close():
 @pytest.mark.parametrize("n", DIMS)
 @pytest.mark.parametrize("dt", DTYPES)
 @pytest.mark.parametrize("b,c", [(1, 8), (57, 24), (512, 8), (600, 40)])
-def test_cell_join_hits(n, dt, b, c):
+def test_fused_window_hits(n, dt, b, c):
+    """``ops.fused_window_hits``, the 'compact' count route's refine: the
+    candidates arrive as positions into the points array, and the hits
+    equal the oracle's over the gathered windows."""
     rng = np.random.default_rng(b * 7 + c)
+    points = rng.uniform(0, 10, (b + c, n)).astype(dt)
     q = rng.uniform(0, 10, (b, n)).astype(dt)
-    cand = rng.uniform(0, 10, (b, c, n)).astype(dt)
+    cand_pos = rng.integers(0, b + c, (b, c)).astype(np.int32)
     valid = rng.random((b, c)) < 0.7
     eps = 1.1
-    got = cell_join.cell_join_hits(jnp.asarray(q), jnp.asarray(cand),
-                                   jnp.asarray(valid), eps, interpret=True)
-    want = ref.cell_join_hits_ref(jnp.asarray(q), jnp.asarray(cand),
+    got = ops.fused_window_hits(jnp.asarray(points), jnp.asarray(q),
+                                jnp.asarray(cand_pos), jnp.asarray(valid),
+                                eps)
+    want = ref.cell_join_hits_ref(jnp.asarray(q),
+                                  jnp.asarray(points[cand_pos]),
                                   jnp.asarray(valid), eps)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_cell_join_all_invalid():
+    points = jnp.zeros((8, 3))
     q = jnp.zeros((16, 3))
-    cand = jnp.zeros((16, 8, 3))
+    cand_pos = jnp.zeros((16, 8), jnp.int32)
     valid = jnp.zeros((16, 8), bool)
-    got = cell_join.cell_join_hits(q, cand, valid, 1.0, interpret=True)
+    got = ops.fused_window_hits(points, q, cand_pos, valid, 1.0)
     assert not np.asarray(got).any()
 
 

@@ -2,7 +2,7 @@
 stencil merging.
 
 The parity oracle is the retained per-cell sweep (``merge_last_dim=False``)
-and the 'jnp' reference: pair SETS must be identical (sorted), work
+and the brute-force join: pair SETS must be identical (sorted), work
 counters (cells_visited / candidates_checked) must match counter-for-
 counter, and only ``JoinStats.n_offsets`` may shrink. Boundary-heavy
 grids -- points on the dataset edge, a collapsed (3-cell) dimension,
@@ -16,6 +16,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from repro.core.brute import brute_force_join
 from repro.core.grid import (
     build_grid_host,
     build_grid_with_geometry,
@@ -157,8 +158,7 @@ def boundary_datasets():
 def test_merged_pair_set_identical_to_oracle(unicomp):
     for name, pts, eps in boundary_datasets():
         index = build_grid_host(pts, eps)
-        a = self_join(pts, eps, unicomp=unicomp, index=index,
-                      distance_impl="jnp")
+        a = brute_force_join(pts, eps)
         m = self_join(pts, eps, unicomp=unicomp, index=index,
                       distance_impl="fused", merge_last_dim=True)
         u = self_join(pts, eps, unicomp=unicomp, index=index,
@@ -177,19 +177,17 @@ def test_merged_counters_and_n_offsets():
         for unicomp, n_red in ((True, (3 ** (n - 1) - 1) // 2 + 1),
                                (False, 3 ** (n - 1))):
             m = self_join_count(pts, eps, unicomp=unicomp, index=index,
-                                distance_impl="fused", route="dense",
-                                merge_last_dim=True)
+                                distance_impl="fused", merge_last_dim=True)
             u = self_join_count(pts, eps, unicomp=unicomp, index=index,
-                                distance_impl="fused", route="dense",
-                                merge_last_dim=False)
+                                distance_impl="fused", merge_last_dim=False)
             assert m.n_offsets == n_red, (name, unicomp)
             assert u.n_offsets == ((3 ** n + 1) // 2 if unicomp else 3 ** n)
             assert m.total_pairs == u.total_pairs, name
             assert m.cells_visited == u.cells_visited, name
             assert m.candidates_checked == u.candidates_checked, name
             s = self_join_count(pts, eps, unicomp=unicomp, index=index,
-                                distance_impl="fused", route="sparse",
-                                merge_last_dim=True)
+                                distance_impl="fused", merge_last_dim=True,
+                                bucketed=False)
             assert (s.total_pairs, s.cells_visited, s.candidates_checked,
                     s.n_offsets) == (m.total_pairs, m.cells_visited,
                                      m.candidates_checked, n_red), name
@@ -219,7 +217,7 @@ def test_merged_batched_and_bucketed():
     pts = np.concatenate([bg, cl])
     index = build_grid_host(pts, 0.5)
     assert occupancy_plan(index, merged=True).n_buckets > 1
-    a = self_join(pts, 0.5, index=index, distance_impl="jnp")
+    a = brute_force_join(pts, 0.5)
     for nb in (2, 4):
         b = self_join_batched(pts, 0.5, n_batches=nb, index=index,
                               distance_impl="fused", merge_last_dim=True)
@@ -380,44 +378,6 @@ def test_merged_serving_parity_and_no_retrace():
         assert np.array_equal(res.counts, b)
     svc.assert_no_retrace()
     assert "external_range_windows" in executable_cache_stats()
-
-
-def test_flat_route_overrides_and_join_sweep_verdict():
-    """The routing table's sweep axis: '-flat' routes run the per-cell
-    sweep (identical totals/counters, 3^n offsets), and the join driver
-    follows a cached '-flat' verdict for its own sweep."""
-    from repro.core.grid import index_cached
-    from repro.core.selfjoin import _join_sweep_merged
-
-    rng = np.random.default_rng(71)
-    pts = rng.uniform(0, 10, (400, 2))
-    index = build_grid_host(pts, 0.6)
-    a = self_join_count(pts, 0.6, index=index, unicomp=False)
-    for route, n_off in (("dense-flat", 9), ("sparse-flat", 9),
-                         ("dense", 3), ("sparse", 3)):
-        s = self_join_count(pts, 0.6, index=index, distance_impl="fused",
-                            route=route, unicomp=False)
-        assert s.route == route
-        assert s.n_offsets == n_off, route
-        assert (s.total_pairs, s.cells_visited, s.candidates_checked) == \
-            (a.total_pairs, a.cells_visited, a.candidates_checked), route
-    # no measurements cached: the heuristic tier keeps the join merged
-    assert _join_sweep_merged(index, unicomp=True, bucketed=None,
-                              merged=True)
-    # a measured 'dense-flat' verdict flips the join's sweep (pre-seed the
-    # per-index route cache the way _auto_route would after measuring);
-    # 'sparse-flat' judges only the counter and leaves the join merged
-    index2 = build_grid_host(pts[:300], 0.6)
-    index_cached(index2, "route/True/None/True", lambda: "dense-flat")
-    assert not _join_sweep_merged(index2, unicomp=True, bucketed=None,
-                                  merged=True)
-    assert np.array_equal(
-        self_join(pts[:300], 0.6, index=index2, distance_impl="fused"),
-        self_join(pts[:300], 0.6, index=index2, distance_impl="jnp"))
-    index3 = build_grid_host(pts[:300], 0.6)
-    index_cached(index3, "route/True/None/True", lambda: "sparse-flat")
-    assert _join_sweep_merged(index3, unicomp=True, bucketed=None,
-                              merged=True)
 
 
 def test_merged_external_1d():

@@ -212,55 +212,33 @@ def test_join_service_steady_state():
     assert svc.requests == 5
 
 
-def test_fused_count_auto_route(tmp_path, monkeypatch):
-    """Satellite: self_join_count(distance_impl='fused') routes through
-    the autotune table (measured winner when cached, occupancy heuristic
-    otherwise), logging the choice in JoinStats.route.
+@pytest.mark.parametrize("case", ["tpu-sparse", "tpu-dense", "cpu"])
+def test_count_route_rule(case):
+    """self_join_count's route is read off the index and the backend:
+    'compact' on the TPU in the empty-neighbour regime (6-D uniform,
+    nearly every probe misses), 'dense' on the TPU elsewhere and
+    everywhere off the TPU. The route taken counts the oracle's total
+    and logs itself in JoinStats.route."""
+    from repro.core import selfjoin
+    from repro.core.brute import brute_force_count
 
-    The repo ships a measured cache (kernels/autotune_cache.json); this
-    test pins the HEURISTIC tier, so it isolates itself from any cache.
-
-    The heuristic regimes: TPU routes the empty-neighbor regime to the
-    compacted counter (window-DMA traffic binds); off-TPU that regime goes
-    to the probe-compacted 'sparse' counter (the per-offset packing sort
-    of 'compact' measured slower everywhere off-TPU, EXPERIMENTS.md),
-    while dense neighborhoods stay on the bucketed dense sweep."""
-    from repro.core.selfjoin import _fused_count_route
-    from repro.core.stencil import stencil_offsets
-    from repro.kernels import autotune
-
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "empty.json"))
-    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    autotune._CACHE.reset()
     rng = np.random.default_rng(21)
-    dense_pts = rng.uniform(0, 10, (400, 2))
-    sparse_pts = rng.uniform(0, 60, (250, 6))
-    dense_idx = build_grid_host(dense_pts, 0.6)
-    sparse_idx = build_grid_host(sparse_pts, 7.0)
-    n_off2 = stencil_offsets(2, True).shape[0]
-    n_off6 = stencil_offsets(6, True).shape[0]
-    # the regime detection (forced onto the TPU branch)
-    assert _fused_count_route(sparse_idx, n_off6, backend="tpu") == "compact"
-    assert _fused_count_route(dense_idx, n_off2, backend="tpu") == "dense"
-    # off-TPU: the empty-neighbor regime routes to the flat probe
-    # compaction once the dense slot volume is large enough (full stencil
-    # guarantees it here), never to the per-offset packing sort
-    assert _fused_count_route(dense_idx, n_off2, backend="cpu") == "dense"
-    assert _fused_count_route(
-        sparse_idx, 3 ** 6, backend="cpu", unicomp=False) == "sparse"
-    a = self_join_count(dense_pts, 0.6, distance_impl="fused")
-    assert a.route == "dense"
-    expect = self_join_count(sparse_pts, 7.0)
-    assert expect.route == "dense"   # non-fused impls never reroute
-    # explicit overrides run the named counter and log it
-    for route in ("compact", "dense", "sparse", "jnp"):
-        b = self_join_count(sparse_pts, 7.0, distance_impl="fused",
-                            route=route)
-        assert b.route == route
-        assert b.total_pairs == expect.total_pairs, route
-    with pytest.raises(ValueError):
-        self_join_count(sparse_pts, 7.0, distance_impl="fused",
-                        route="nope")
+    if case == "tpu-dense":
+        pts, eps = rng.uniform(0, 10, (400, 2)), 0.6
+    else:
+        pts, eps = rng.uniform(0, 60, (250, 6)), 7.0
+    backend = "cpu" if case == "cpu" else "tpu"
+    want = "compact" if case == "tpu-sparse" else "dense"
+    index = build_grid_host(pts, eps)
+    for unicomp in (True, False):
+        for merged in (True, False):
+            assert selfjoin._count_route(index, backend, unicomp=unicomp,
+                                         merged=merged) == want
+    run = (selfjoin.self_join_count_compact if want == "compact"
+           else self_join_count)
+    stats = run(pts, eps, index=index)
+    assert stats.route == want
+    assert stats.total_pairs == brute_force_count(pts, eps)
 
 
 def test_epsilon_join_empty_query_batch():

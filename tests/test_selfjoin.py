@@ -97,8 +97,9 @@ def test_unicomp_halves_work():
     """
     rng = np.random.default_rng(3)
     pts = rng.uniform(0, 10, (4000, 3))
-    s_uni = self_join_count(pts, 1.0, unicomp=True)
-    s_full = self_join_count(pts, 1.0, unicomp=False)
+    # the paper's per-cell stencil
+    s_uni = self_join_count(pts, 1.0, unicomp=True, merge_last_dim=False)
+    s_full = self_join_count(pts, 1.0, unicomp=False, merge_last_dim=False)
     assert s_uni.total_pairs == s_full.total_pairs
     # offsets: (3^n+1)/2 vs 3^n
     assert s_uni.offsets == (3**3 + 1) // 2
@@ -198,6 +199,7 @@ def test_compact_sweep_matches_dense():
     for n, eps in ((2, 0.5), (4, 3.0), (5, 6.0)):
         pts = rng.uniform(0, 60, (3000, n))
         dense = self_join_count(pts, eps, unicomp=True)
+        assert dense.total_pairs == brute_force_count(pts, eps), n
         comp = self_join_count_compact(pts, eps, unicomp=True)
         assert comp.total_pairs == dense.total_pairs, n
         comp_f = self_join_count_compact(pts, eps, unicomp=False)
@@ -265,12 +267,24 @@ def test_build_grid_requires_int64_keys():
     assert np.asarray(g[1]).dtype == np.int64
 
 
-def test_pallas_impl_through_join():
-    rng = np.random.default_rng(17)
-    pts = rng.uniform(0, 10, (300, 2))
-    a = self_join(pts, 0.7, distance_impl="jnp")
-    b = self_join(pts, 0.7, distance_impl="pallas")
-    assert np.array_equal(a, b)
+@pytest.mark.parametrize("entry", ["self_join", "self_join_batched",
+                                   "self_join_count",
+                                   "self_join_count_compact"])
+def test_distance_impl_accepts_only_fused(entry):
+    """The fused sweep is the only join path: each entry point runs it
+    by default and by name, and refuses any other ``distance_impl``."""
+    from repro.core import selfjoin
+
+    fn = getattr(selfjoin, entry)
+    pts = np.random.default_rng(19).uniform(0, 4, (60, 2))
+    want = brute_force_count(pts, 0.6)
+    for impl in ({}, {"distance_impl": "fused"}):
+        got = fn(pts, 0.6, **impl)
+        n = got.shape[0] if isinstance(got, np.ndarray) else got.total_pairs
+        assert n == want, impl
+    for bad in ("jnp", "pallas", "nope"):
+        with pytest.raises(ValueError, match="distance_impl"):
+            fn(pts, 0.6, distance_impl=bad)
 
 
 def test_empty_and_tiny():
@@ -284,15 +298,12 @@ def test_empty_and_tiny():
 def test_batched_more_batches_than_points():
     """n_batches > npts: the batch count clamps to the point count, so no
     empty trailing batch ever schedules a rounded-up query slice over pure
-    padding rows. Pair sets match the unbatched join for every impl."""
+    padding rows. Pair sets match the oracle."""
     rng = np.random.default_rng(23)
     for npts in (1, 2, 3, 5):
         pts = rng.uniform(0, 2, (npts, 2))
-        ref = self_join(pts, 0.8, distance_impl="jnp")
-        for impl in ("jnp", "fused"):
-            got = self_join_batched(pts, 0.8, n_batches=npts + 4,
-                                    distance_impl=impl)
-            assert np.array_equal(got, ref), (npts, impl)
+        got = self_join_batched(pts, 0.8, n_batches=npts + 4)
+        assert np.array_equal(got, oracle_pairs(pts, 0.8)), npts
 
 
 def _emit_like_chunks(rng):

@@ -44,12 +44,15 @@ def point_sets(draw):
     return pts, eps
 
 
-@settings(max_examples=30, deadline=None)
+@pytest.mark.parametrize("unicomp", [True, False])
+@settings(max_examples=20, deadline=None)
 @given(point_sets())
-def test_join_matches_oracle(data):
+def test_join_matches_oracle(unicomp, data):
+    """The fused gather-refine join against the O(N^2) oracle, on the
+    UNICOMP half stencil and on the full one."""
     pts, eps = data
     expect = oracle_pairs(pts, eps)
-    got = self_join(pts, eps, unicomp=True)
+    got = self_join(pts, eps, unicomp=unicomp)
     assert np.array_equal(got, expect)
 
 
@@ -69,16 +72,6 @@ def test_batched_invariant_to_batch_count(data, nb):
     a = self_join_batched(pts, eps, n_batches=nb)
     b = self_join(pts, eps)
     assert np.array_equal(a, b)
-
-
-@settings(max_examples=10, deadline=None)
-@given(point_sets())
-def test_fused_matches_oracle(data):
-    """The fused gather-refine path against the O(N^2) oracle."""
-    pts, eps = data
-    expect = oracle_pairs(pts, eps)
-    got = self_join(pts, eps, unicomp=True, distance_impl="fused")
-    assert np.array_equal(got, expect)
 
 
 @settings(max_examples=10, deadline=None)
