@@ -18,7 +18,7 @@ descriptors via scalar prefetch, HBM->VMEM dynamic slice per window), all
 stencil offsets sweep in ONE launch with the query tile VMEM-resident
 throughout, and count+fill share a single distance evaluation per
 candidate: the kernel returns the masked hit set plus per-query counts and
-the per-tile exclusive-scan slot bases, so the fill phase only scatters
+the per-tile exclusive-scan slot bases, so the fill phase only compacts
 (DESIGN.md S4). No (B, C, n) intermediate exists. Launches are
 occupancy-bucketed (DESIGN.md S6): query rows partition by
 candidate-capacity class (grid.occupancy_plan) and each bucket sweeps at
@@ -452,50 +452,62 @@ def _fused_bucket_launch(index: GridIndex, points_pad, deltas, is_zero,
 def _emit_from_hits(index: GridIndex, ids, hits, counts, slot_base,
                     win_start, q_pos, *, c: int, unicomp: bool,
                     capacity: int):
-    """Fill phase of the fused path: scatter pairs from the count pass's hit
-    set. No distances here -- positions come from the window descriptors and
-    output slots from the kernel's per-tile exclusive scan (``slot_base``)
-    offset by the exclusive scan of the per-tile totals. ``q_pos`` is the
-    launch's per-row sorted-position array (contiguous batch or occupancy
-    bucket selection); ``ids`` maps sorted positions to emitted point ids
-    (``index.order`` for the single-device join, the slab's GLOBAL id
-    array for the distributed join)."""
+    """Fill phase of the fused path: compact the count pass's hit set into
+    ``capacity`` pair rows (keys, vals), -1 past the ordered count, and
+    that count. No distances here -- positions come from the window
+    descriptors and output slots from the kernel's per-tile exclusive scan
+    (``slot_base``) offset by the exclusive scan of the per-tile totals.
+    Hits have distinct slots below the hit count, so one sort keyed on the
+    slot (misses keyed past the end) lays the pairs out in query-major
+    order: no scatter. ``q_pos`` is the launch's per-row sorted-position
+    array (contiguous batch or occupancy bucket selection); ``ids`` maps
+    sorted positions to emitted point ids (``index.order`` for the
+    single-device join, the slab's GLOBAL id array for the distributed
+    join)."""
     from repro.kernels.fused_join import TQ_DEFAULT as tq
 
-    n_off, qp, _ = hits.shape
     npts = index.num_points
-    orig = ids
-    q_pos_c = jnp.minimum(q_pos, npts - 1)
-    slots = jnp.arange(c, dtype=jnp.int32)
-    cand_pos = win_start[:, :, None] + slots[None, None, :]
-    # query-major flattening: a query's hits are contiguous in slot order
-    h = hits.astype(bool).transpose(1, 0, 2).reshape(qp, n_off * c)
-    cp = jnp.minimum(cand_pos.transpose(1, 0, 2).reshape(qp, n_off * c),
-                     npts - 1)
-    rank = jnp.cumsum(h, axis=1) - 1              # within-query hit rank
-    tile_tot = counts.reshape(-1, tq).sum(axis=1).astype(jnp.int64)
+    orig = ids.astype(jnp.int32)
+    # (n_off, c, Q): query rows on lanes, ranks scanned down the slots.
+    # int32 throughout: a hit's slot is below the hit count, which the
+    # int32 result rows bound
+    h = hits.astype(bool).transpose(0, 2, 1)
+    within = jnp.cumsum(h, axis=1, dtype=jnp.int32)
+    off_tot = within[:, -1, :]
+    off_base = jnp.cumsum(off_tot, axis=0) - off_tot
+    tile_tot = counts.reshape(-1, tq).sum(axis=1, dtype=jnp.int32)
     tile_base = jnp.cumsum(tile_tot) - tile_tot
-    qbase = jnp.repeat(tile_base, tq) + slot_base.astype(jnp.int64)
-    pos = qbase[:, None] + rank
-    qid = jnp.broadcast_to(orig[q_pos_c][:, None], h.shape)
-    cid = orig[cp]
-    keys = jnp.full((capacity,), -1, jnp.int32)
-    vals = jnp.full((capacity,), -1, jnp.int32)
+    qbase = jnp.repeat(tile_base, tq) + slot_base.astype(jnp.int32)
+    # query-major: per query, offsets in sweep order, slots in window order
+    pos = qbase + off_base[:, None, :] + within - 1
+    mult = 2 if unicomp else 1
+    end = 2 * (capacity // mult)      # two sorted rows per hit that fits
+    # hit p: its query's id keyed 2p, its candidate's position 2p+1
+    key = jnp.where(h, 2 * pos, end).reshape(-1)
+    key = jnp.concatenate([key, jnp.where(key < end, key + 1, end)])
+    qid = orig[jnp.minimum(q_pos, npts - 1)]
+    cand = win_start[:, None, :] + jnp.arange(c, dtype=jnp.int32)[:, None]
+    val = jnp.concatenate([jnp.broadcast_to(qid, h.shape).reshape(-1),
+                           cand.reshape(-1)])
+    if key.shape[0] < end:            # a pow2 capacity past the slots
+        key = jnp.pad(key, (0, end - key.shape[0]), constant_values=end)
+        val = jnp.pad(val, (0, end - val.shape[0]))
+    key, val = jax.lax.sort((key, val), num_keys=1, is_stable=False)
+    key, val = key[:end], val[:end]
+    # [q0, c0, q1, c1, ...]: candidate ids gathered for the kept rows only
+    odd = jnp.arange(end, dtype=jnp.int32) % 2 == 1
+    val = jnp.where(odd, orig[jnp.clip(val, 0, npts - 1)], val)
+    rows = jnp.where(key == end, -1, val)
     if unicomp:
         # every hit is an unordered pair -> two ordered result rows
-        idx_fwd = jnp.where(h, 2 * pos, capacity)
-        idx_rev = jnp.where(h, 2 * pos + 1, capacity)
-        keys = keys.at[idx_fwd].set(qid, mode="drop")
-        vals = vals.at[idx_fwd].set(cid, mode="drop")
-        keys = keys.at[idx_rev].set(cid, mode="drop")
-        vals = vals.at[idx_rev].set(qid, mode="drop")
-        total = 2 * counts.sum(dtype=jnp.int64)
+        keys = rows
+        vals = jnp.where(odd, jnp.roll(rows, 1), jnp.roll(rows, -1))
     else:
-        idx = jnp.where(h, pos, capacity)
-        keys = keys.at[idx].set(qid, mode="drop")
-        vals = vals.at[idx].set(cid, mode="drop")
-        total = counts.sum(dtype=jnp.int64)
-    return keys, vals, total
+        keys, vals = rows[0::2], rows[1::2]
+    tail = capacity - keys.shape[0]   # 1 for a pairless unicomp launch
+    keys = jnp.pad(keys, (0, tail), constant_values=-1)
+    vals = jnp.pad(vals, (0, tail), constant_values=-1)
+    return keys, vals, mult * counts.sum(dtype=jnp.int64)
 
 
 def _next_pow2(x: int) -> int:
@@ -524,11 +536,11 @@ def _emit_from_hits_host(order: np.ndarray, hits, win_start,
                          q_pos: np.ndarray, npts: int,
                          unicomp: bool) -> np.ndarray:
     """Host-side fill from the count pass's hit set (no distances, no device
-    scatter). The result is host-bound anyway (the paper copies each batch
+    program). The result is host-bound anyway (the paper copies each batch
     off-device, SV-A), and compacting the (n_off, Q, C) hit bitmap with one
-    ``np.nonzero`` beats an XLA scatter of mostly-dropped updates by orders
-    of magnitude off-TPU; on TPU the device path ``_emit_from_hits`` keeps
-    the scatter close to the data. ``q_pos`` maps launch rows to sorted
+    ``np.nonzero`` beats an XLA sort of every slot by orders of magnitude
+    off-TPU; on TPU the device path ``_emit_from_hits`` keeps the
+    compaction close to the data. ``q_pos`` maps launch rows to sorted
     positions (contiguous batch or occupancy bucket selection)."""
     # query-major like the device emit, so both backends produce the SAME
     # row order (per query: offsets in sweep order, slots in window order)
@@ -628,13 +640,13 @@ def _self_join_fused(index: GridIndex, **kw):
     Per launch (an occupancy bucket chunk, or a contiguous batch when the
     capacity plan collapses to one class): one fused sweep produces the hit
     set + per-query counts; the exact result size follows from the counts
-    (sync point), and the fill is a pure compaction/scatter over the same
-    hit set -- no second distance pass. ``emit`` selects the fill backend:
-    'device' (scatter sized by the counts, with the kernel's per-tile slot
-    bases; default on TPU) or 'host' (np.nonzero compaction of the hit
-    bitmap; default elsewhere). Device capacities round to powers of two
-    across launches so the emit scatter compiles O(log) times, not per
-    launch. Bucketed and single-capacity schedules emit the same pair SET
+    (sync point), and the fill is a pure compaction over the same hit set
+    -- no second distance pass. ``emit`` selects the fill backend: 'device'
+    (one sort keyed on each hit's slot, from the kernel's per-tile slot
+    bases, into a buffer sized by the counts; default on TPU) or 'host'
+    (np.nonzero compaction of the hit bitmap; default elsewhere). Device
+    capacities round to powers of two across launches so the emit
+    compiles O(log) times, not per launch. Bucketed and single-capacity schedules emit the same pair SET
     (row order differs across buckets; ``sort_result`` canonicalizes).
 
     ``merged`` (default) sweeps the 3^(n-1) merged-range stencil
@@ -755,7 +767,8 @@ def _self_join_fused_stages(index: GridIndex, *, unicomp: bool,
             assert pairs.shape[0] == ordered
             return pairs
         capacity = max(ordered if single else _next_pow2(ordered), 1)
-        with span("selfjoin.emit"):
+        with span("selfjoin.emit") as sp:
+            sp.set_metadata(slots=hits.size, pairs=ordered)
             keys, vals, cnt = _emit_from_hits(
                 index, ids_dev, hits, counts, base, ws, q_pos,
                 c=cap, unicomp=unicomp, capacity=capacity)

@@ -27,7 +27,7 @@ writes the tile's hit rows as one block and adds their per-row totals to
 counts (``slot_base``) -- the slot assignment the fill phase uses -- is one
 XLA op after the launch, shared with the reference lowering. Count and fill
 share ONE distance evaluation per candidate: the driver (core/selfjoin.py)
-sizes the result buffer from ``counts`` and scatters pairs from the
+sizes the result buffer from ``counts`` and compacts pairs from the
 returned ``hits`` mask without ever recomputing a distance.
 
 Outputs (for a query batch of Q_pad rows, C-slot windows, n_off offsets):

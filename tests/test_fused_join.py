@@ -167,27 +167,83 @@ def test_fused_tiny_and_empty():
                           brute_force_join(pts, 1.0))
 
 
-def test_fused_emit_host_equals_device():
-    """Both fill backends consume the same hit set and must agree."""
+def _emit_set(name):
+    """``uniform3d``: sparse hits; ``dense1d``: every pair of 384 points in
+    one window, so three launches fill every slot and the first one's pow2
+    capacity passes its slot count (the device emit pads before its sort)."""
     rng = np.random.default_rng(11)
-    pts = rng.uniform(0, 10, (350, 3))
-    index = build_grid_host(pts, 0.9)
+    if name == "uniform3d":
+        return build_grid_host(rng.uniform(0, 10, (350, 3)), 0.9)
+    return build_grid_host(rng.uniform(0, 1, (384, 1)), 2.0)
+
+
+def _record_emits(monkeypatch):
+    """Wrap the device emit; returns the list of (args, kw, keys, vals,
+    total) of each launch it served."""
+    from repro.core import selfjoin
+
+    seen = []
+    emit = selfjoin._emit_from_hits
+
+    def rec(*args, **kw):
+        keys, vals, total = emit(*args, **kw)
+        seen.append((args, kw, np.asarray(keys), np.asarray(vals),
+                     int(total)))
+        return keys, vals, total
+
+    monkeypatch.setattr(selfjoin, "_emit_from_hits", rec)
+    return seen
+
+
+@pytest.mark.parametrize("name,unicomp,n_batches", [
+    ("uniform3d", True, 1), ("uniform3d", False, 1),
+    ("uniform3d", True, 3), ("uniform3d", False, 3),
+    ("dense1d", True, 3), ("dense1d", False, 3)])
+def test_fused_emit_host_equals_device(monkeypatch, name, unicomp,
+                                       n_batches):
+    """Both fill backends consume the same hit set and must agree, pairs
+    AND row order (both emit query-major: per query, offsets in sweep
+    order, slots in window order); the device emit's rows past the ordered
+    count are -1."""
+    index = _emit_set(name)
+    opts = dict(unicomp=unicomp, n_batches=n_batches)
+    seen = _record_emits(monkeypatch)
+    for sort_result in (True, False):
+        h = _self_join_fused(index, sort_result=sort_result, emit="host",
+                             **opts)
+        d = _self_join_fused(index, sort_result=sort_result,
+                             emit="device", **opts)
+        assert h.shape[0] > 0
+        assert np.array_equal(h, d), sort_result
+    mult = 2 if unicomp else 1
+    assert seen
+    for _, kw, keys, vals, ordered in seen:
+        assert keys.shape == vals.shape == (kw["capacity"],)
+        assert (keys[:ordered] >= 0).all() and (vals[:ordered] >= 0).all()
+        assert (keys[ordered:] == -1).all() and (vals[ordered:] == -1).all()
+    # n_batches 3 emits at pow2 capacities; the dense set passes its slots
+    assert (n_batches == 1) == all(
+        kw["capacity"] == o for _, kw, _, _, o in seen)
+    assert (name == "dense1d") == any(
+        kw["capacity"] // mult > args[2].size for args, kw, *_ in seen)
+
+
+def test_emit_lowers_to_one_sort_no_scatter(monkeypatch):
+    """The device emit compacts the hit grid with one sort keyed on each
+    hit's output slot: its program holds no scatter."""
+    from repro.core.selfjoin import _emit_from_hits
+
+    calls = _record_emits(monkeypatch)
+    rng = np.random.default_rng(2)
+    index = build_grid_host(rng.uniform(0, 10, (200, 2)), 0.8)
     for unicomp in (True, False):
-        h = _self_join_fused(index, unicomp=unicomp, sort_result=True,
-                             emit="host")
-        d = _self_join_fused(index, unicomp=unicomp, sort_result=True,
-                             emit="device")
-        assert np.array_equal(h, d), unicomp
-        # both backends emit query-major: identical row order even UNSORTED
-        hu = _self_join_fused(index, unicomp=unicomp, sort_result=False,
-                              emit="host")
-        du = _self_join_fused(index, unicomp=unicomp, sort_result=False,
-                              emit="device")
-        assert np.array_equal(hu, du), unicomp
-        # multi-batch device emission exercises the pow2 capacity path
-        d3 = _self_join_fused(index, unicomp=unicomp, sort_result=True,
-                              emit="device", n_batches=3)
-        assert np.array_equal(h, d3), unicomp
+        _self_join_fused(index, unicomp=unicomp, sort_result=False,
+                         emit="device")
+    assert {kw["unicomp"] for _, kw, *_ in calls} == {True, False}
+    for args, kw, *_ in calls:
+        text = _emit_from_hits.lower(*args, **kw).as_text()
+        assert "scatter" not in text, kw
+        assert text.count("stablehlo.sort") == 1, kw
 
 
 def test_pallas_kernel_matches_reference():
